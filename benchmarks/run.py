@@ -68,6 +68,9 @@ def main() -> None:
                          "after, with --tune) the run")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.impl:
         from repro.core.dispatch import set_default_impl
 

@@ -59,6 +59,33 @@ _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 _PROFILER = None
 
 
+# Open count_fallbacks() scopes: dicts label -> count.
+_FALLBACK_SCOPES: list = []
+
+
+@contextlib.contextmanager
+def count_fallbacks():
+    """Scope yielding a dict (label -> count) of kernel-impl calls that ran
+    an XLA formulation instead because no kernel covers the case. Calls are
+    counted where they are traced, so a jitted program counts once per
+    compilation, not per run."""
+    counts: Dict[str, int] = {}
+    _FALLBACK_SCOPES.append(counts)
+    try:
+        yield counts
+    finally:
+        _FALLBACK_SCOPES.remove(counts)
+
+
+def note_fallback(label: str) -> None:
+    """Record that a kernel-impl call fell back to XLA (``label`` names the
+    op and the case), for the op profiler and every open scope."""
+    if _PROFILER is not None:
+        _PROFILER.on_fallback(label)
+    for counts in _FALLBACK_SCOPES:
+        counts[label] = counts.get(label, 0) + 1
+
+
 def set_profiler(profiler):
     """Install (or clear, with None) the dispatch-level op profiler.
     Returns the previous profiler so scopes nest."""
@@ -171,6 +198,7 @@ def _dense_xla(x, w, formulation):
 @register("dense", "kernel")
 def _dense_kernel(x, w, formulation):
     if formulation not in ("srm", "var"):
+        note_fallback(f"dense:{formulation}")
         return _dense_xla(x, w, formulation)
     ops = _kernel_ops()
     dtype = _out_dtype(x, w)
@@ -281,10 +309,8 @@ def _einsum_kernel(subscripts, x, w, formulation):
         # R-batched matvec instead of falling back to XLA.
         return _depthwise_kernel(x, w, formulation)
     # General contractions have no blocked kernel; the XLA formulation is
-    # the registered fallback — counted so silent fallbacks surface in
-    # the per-op profile.
-    if _PROFILER is not None:
-        _PROFILER.on_fallback(f"einsum:{spec}:{formulation}")
+    # the registered fallback — counted so silent fallbacks surface.
+    note_fallback(f"einsum:{spec}:{formulation}")
     return _einsum_xla(subscripts, x, w, formulation)
 
 
@@ -334,6 +360,7 @@ def _dense_batched_xla(x, w, formulation):
 @register("dense_batched", "kernel")
 def _dense_batched_kernel(x, w, formulation):
     if formulation not in ("srm", "var"):
+        note_fallback(f"dense_batched:{formulation}")
         return _dense_batched_xla(x, w, formulation)
     ops = _kernel_ops()
     dtype = _out_dtype(x, w)
@@ -879,6 +906,7 @@ def pfp_residual(x, y, impl: Optional[str] = None) -> GaussianTensor:
 __all__ = [
     "IMPLS", "set_default_impl", "get_default_impl", "resolve_impl",
     "register", "get_op", "registered_ops", "set_profiler", "get_profiler",
+    "count_fallbacks", "note_fallback",
     "set_fusion", "get_fusion", "fusion",
     "pfp_dense", "pfp_dense_batched", "pfp_einsum", "pfp_conv2d_im2col",
     "pfp_activation",

@@ -12,7 +12,7 @@ Paper (TVM/ARM)                      ->  here (Pallas/TPU)
                                           (SRM gated product)
 
 `ops.py` holds the jit'd public wrappers (shape plumbing, padding,
-interpret-mode fallback off-TPU); `ref.py` the pure-jnp oracles every
+interpret mode on the CPU backend only); `ref.py` the pure-jnp oracles every
 kernel is validated against.
 
 Models do NOT import this package directly: every PFP op resolves through

@@ -5,7 +5,7 @@ Responsibilities:
     slice results back (padding along K contributes exact zeros to all
     accumulators, so results are unaffected);
   * dispatch — ``impl='kernel'`` runs the Pallas kernel (interpret=True
-    automatically off-TPU), ``impl='xla'`` runs the pure-jnp oracle from
+    on the CPU, compiled everywhere else), ``impl='xla'`` runs the pure-jnp oracle from
     ``ref.py`` (what the pjit'd production graphs use — XLA already fuses
     the joint-operator structure there; the Pallas kernels are the
     TPU-core-level statement of the same schedule);
@@ -37,6 +37,7 @@ from repro.kernels.pfp_moe import (pfp_dense_batched_pallas,
 from repro.kernels.pfp_maxpool import pfp_maxpool2d_pallas
 from repro.kernels.pfp_norms import pfp_layernorm_pallas, pfp_rmsnorm_pallas
 from repro.tuning.schedules import AXIS_DEFAULTS, Schedule
+from repro.tuning.search import cost_summary
 
 Impl = Literal["kernel", "xla"]
 
@@ -80,7 +81,9 @@ def get_default_impl() -> Impl:
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    # Interpret mode exists for tests on the CPU. Anywhere else the kernels
+    # compile for the device or fail loudly; they never fall back quietly.
+    return jax.default_backend() == "cpu"
 
 
 def _pad_to(a, mult, axis):
@@ -377,14 +380,26 @@ def pfp_attention_paged(q_mu, k_pages, v_pages, vv_pages, page_table,
     )
 
 
-def _norm_2d(mu, second, *, block_rows: int, schedule=None):
+def _norm_rows_that_fit(op: str, rows: int, d: int, block_rows: int) -> int:
+    """The largest row block <= ``block_rows`` whose working set (the whole
+    padded feature axis stays resident) fits the tuner's VMEM model — a
+    fixed row block runs out of VMEM at wide ``d`` (256 x 4096 does)."""
+    bm = block_rows
+    while bm > 8 and not cost_summary(
+            op, (rows, d), Schedule.make(op, block_rows=bm)).fits_vmem:
+        bm //= 2
+    return max(bm, 8)
+
+
+def _norm_2d(op, mu, second, *, block_rows: int, schedule=None):
     """Flatten to (rows, d), pad rows to a block multiple and cols to lanes."""
     d = mu.shape[-1]
     mu2 = mu.reshape(-1, d)
     sec2 = second.reshape(-1, d)
     rows = mu2.shape[0]
+    fit = _norm_rows_that_fit(op, rows, d, block_rows)
     bm = _block(schedule, "block_rows",
-                min(block_rows, _ceil_mult(rows, 8)), rows, 8)
+                min(fit, _ceil_mult(rows, 8)), rows, 8)
     mu2 = _pad_to(mu2, bm, 0)
     sec2 = _pad_to(sec2, bm, 0)
     mu2 = _pad_to(mu2, 128, 1)
@@ -414,7 +429,8 @@ def pfp_rmsnorm(mu, second, gain, *, rep: str = "var", eps: float = 1e-6,
             m, v = pfp_activation(m, v, kind=act, impl="xla")
         return m.reshape(shape), v.reshape(shape)
     shape = mu.shape
-    mu2, sec2, rows, d, bm = _norm_2d(mu, second, block_rows=block_rows,
+    mu2, sec2, rows, d, bm = _norm_2d("rmsnorm", mu, second,
+                                      block_rows=block_rows,
                                       schedule=schedule)
     # epilogue='split' runs the same MOMENT_FNS epilogue as a standalone
     # activation kernel pass over the normalized fp32 moments instead of
@@ -448,7 +464,8 @@ def pfp_layernorm(mu, second, gain, bias=None, *, rep: str = "var",
             m, v = pfp_activation(m, v, kind=act, impl="xla")
         return m.reshape(shape), v.reshape(shape)
     shape = mu.shape
-    mu2, sec2, rows, d, bm = _norm_2d(mu, second, block_rows=block_rows,
+    mu2, sec2, rows, d, bm = _norm_2d("layernorm", mu, second,
+                                      block_rows=block_rows,
                                       schedule=schedule)
     cols = mu2.shape[1]
     split = act is not None and _axis(schedule, "epilogue") == "split"

@@ -25,11 +25,37 @@ from repro.core.gaussian import VAR_EPS
 _SQRT_2 = math.sqrt(2.0)
 _SQRT_2PI = math.sqrt(2.0 * math.pi)
 
+# erf(x) ~= x * P(x^2) / Q(x^2) on [-4, 4], the fp32 rational approximation
+# XLA and Eigen use for ``erf`` (within a few ulp; |x| > 4 rounds to +-1 in
+# fp32). Mosaic has no lowering for ``lax.erf``, so the kernels evaluate
+# this polynomial pair themselves: only mul/add/div/clip, all VPU ops.
+_ERF_ALPHA = (-2.72614225801306e-10, 2.77068142495902e-08,
+              -2.10102402082508e-06, -5.69250639462346e-05,
+              -7.34990630326855e-04, -2.95459980854025e-03,
+              -1.60960333262415e-02)
+_ERF_BETA = (-1.45660718464996e-05, -2.13374055278905e-04,
+             -1.68282697438203e-03, -7.37332916720468e-03,
+             -1.42647390514189e-02)
+
+
+def erf(x):
+    """fp32 erf from operations every Pallas TPU kernel can lower."""
+    x = jnp.clip(x, -4.0, 4.0)
+    x2 = x * x
+    p = jnp.full_like(x2, _ERF_ALPHA[0])
+    for c in _ERF_ALPHA[1:]:
+        p = p * x2 + c
+    q = jnp.full_like(x2, _ERF_BETA[0])
+    for c in _ERF_BETA[1:]:
+        q = q * x2 + c
+    # At the clamp the ratio overshoots 1 by an ulp: keep erf in [-1, 1].
+    return jnp.clip(x * p / q, -1.0, 1.0)
+
 
 def _relu_moments(mu, var):
     safe_var = jnp.maximum(var, VAR_EPS)
     std = jnp.sqrt(safe_var)
-    cdf = 0.5 * (1.0 + jax.lax.erf(mu / (std * _SQRT_2)))
+    cdf = 0.5 * (1.0 + erf(mu / (std * _SQRT_2)))
     pdf = std * jnp.exp(-0.5 * jnp.square(mu) / safe_var) / _SQRT_2PI
     mean_out = mu * cdf + pdf                                   # Eq. (8)
     srm_out = (safe_var + jnp.square(mu)) * cdf + mu * pdf      # Eq. (9)

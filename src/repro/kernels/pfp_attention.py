@@ -58,17 +58,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.masking import NEG_INF, attention_valid_mask, mask_scores
 from repro.kernels.pfp_dense import _compiler_params
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
 
 _LANES = 128
 
@@ -228,10 +221,8 @@ def pfp_attention_pallas(
         ],
         scratch_shapes=_attn_scratch(bq, d),
         interpret=interpret,
+        compiler_params=_compiler_params((dims, dims, "arbitrary")),
     )
-    params = _compiler_params((dims, dims, "arbitrary"))
-    if params is not None and not interpret:
-        common["compiler_params"] = params
     fn = pl.pallas_call(kernel, **common)
     out_mu, out_var = fn(q_mu, k_mu, v_mu, v_var)
     out_mu = out_mu.reshape(b, h, tq_p, d)[:, :, :tq]
@@ -325,9 +316,6 @@ def _paged_attn_kernel(q_start_ref, kv_len_ref, table_ref, q_ref, *rest,
 
 
 def _grid_spec(num_scalars, grid, in_specs, out_specs, bq, d):
-    if pltpu is None:  # pragma: no cover
-        raise RuntimeError("pallas TPU grid specs unavailable "
-                           "(jax.experimental.pallas.tpu missing)")
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=num_scalars,
         grid=grid,
@@ -400,10 +388,8 @@ def pfp_attention_cache_pallas(
             jax.ShapeDtypeStruct((bh, tq_p, d), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_compiler_params((dims, dims, "arbitrary")),
     )
-    params = _compiler_params((dims, dims, "arbitrary"))
-    if params is not None and not interpret:
-        common["compiler_params"] = params
     fn = pl.pallas_call(kernel, **common)
     out_mu, out_var = fn(q_start.astype(jnp.int32), kv_len.astype(jnp.int32),
                          q_mu, k_mu, v_mu, v_var)
@@ -490,10 +476,8 @@ def pfp_attention_paged_pallas(
             jax.ShapeDtypeStruct((bh, tq_p, d), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_compiler_params((dims, dims, "arbitrary")),
     )
-    params = _compiler_params((dims, dims, "arbitrary"))
-    if params is not None and not interpret:
-        common["compiler_params"] = params
     fn = pl.pallas_call(kernel, **common)
     kv_args = ([k_pages] * depth + [v_pages] * depth + [vv_pages] * depth)
     out_mu, out_var = fn(q_start.astype(jnp.int32), kv_len.astype(jnp.int32),
@@ -512,15 +496,5 @@ def _pad_t(a, t_to):
 
 
 def _attn_scratch(bq, d):
-    return [
-        _scratch((bq, _LANES)),
-        _scratch((bq, _LANES)),
-        _scratch((bq, d)),
-        _scratch((bq, d)),
-    ]
-
-
-def _scratch(shape):
-    if _VMEM is not None:
-        return _VMEM(shape, jnp.float32)
-    return pl.MemoryRef(shape, jnp.float32)  # pragma: no cover
+    return [pltpu.VMEM(shape, jnp.float32)
+            for shape in ((bq, _LANES), (bq, _LANES), (bq, d), (bq, d))]

@@ -39,14 +39,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # TPU compiler params are versioned; interpret mode ignores them.
-    from jax.experimental.pallas import tpu as pltpu
-
-    _VMEM = pltpu.VMEM
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _VMEM = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _dense_kernel(mu_x_ref, srm_x_ref, mu_w_ref, srm_w_ref,
@@ -192,19 +185,8 @@ def _var_formulation_kernel_unrolled(mu_x_ref, var_x_ref, mu_w_ref,
 
 def _compiler_params(dims=("parallel", "parallel", "arbitrary")):
     """Mosaic compiler params carrying ``dimension_semantics`` for the
-    grid (rank must match). Returns None when unsupported (interpret
-    mode / non-TPU jaxlib)."""
-    if pltpu is None:
-        return None
-    dims = tuple(dims)
-    for cls_name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, cls_name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=dims)
-            except TypeError:  # pragma: no cover
-                continue
-    return None
+    grid (rank must match); interpret mode ignores them."""
+    return pltpu.CompilerParams(dimension_semantics=tuple(dims))
 
 
 def _dense_geometry(k_order: str, dims: str, m: int, n: int,
@@ -282,10 +264,8 @@ def pfp_dense_pallas(
         out_specs=[out_spec, out_spec],
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=_compiler_params(sem),
     )
-    params = _compiler_params(sem)
-    if params is not None and not interpret:
-        common["compiler_params"] = params
 
     unrolled = k_order == "unrolled"
     if first_layer:
@@ -310,7 +290,7 @@ def pfp_dense_pallas(
         fn = pl.pallas_call(
             functools.partial(_dense_kernel, nk=nk),
             in_specs=[in_specs_x, in_specs_x, in_specs_w, in_specs_w],
-            scratch_shapes=[_scratch((bm, bn))],
+            scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
             **common,
         )
     mu, var = fn(mu_x, srm_x, mu_w, srm_w)
@@ -356,10 +336,8 @@ def pfp_dense_var_pallas(
             jax.ShapeDtypeStruct((m, n), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_compiler_params(sem),
     )
-    params = _compiler_params(sem)
-    if params is not None and not interpret:
-        common["compiler_params"] = params
     kernel = (functools.partial(_var_formulation_kernel_unrolled, bk=bk, nk=nk)
               if k_order == "unrolled" else
               functools.partial(_var_formulation_kernel, nk=nk))
@@ -370,8 +348,3 @@ def pfp_dense_var_pallas(
     )
     return fn(mu_x, var_x, mu_w, var_w)
 
-
-def _scratch(shape):
-    if _VMEM is not None:
-        return _VMEM(shape, jnp.float32)
-    return pl.MemoryRef(shape, jnp.float32)  # pragma: no cover

@@ -27,15 +27,13 @@ Schedule axes searched by the autotuner: ``block_m``, ``block_n`` and the
 ``dims`` dimension_semantics annotation. ``block_k`` is deliberately NOT
 a fused axis — it is inherited from the dense op (see above).
 
-One backend caveat: the HLO op sequence is identical, but XLA's CPU
-emitter contracts mul+add pairs into FMAs per fusion region (LLVM-level,
-below HLO — ``optimization_barrier`` cannot pin it), and fusing three
-kernel bodies into one necessarily changes the region boundaries. In
-interpret mode the moments therefore agree to ~1 ulp per contraction
-(<= 1e-3 relative end-to-end) rather than bitwise; greedy tokens and the
-cache-miss fallback (which runs the real unfused chain) remain exact.
-The barriers below still pin every HLO-level rounding point to the
-unfused chain's HBM boundaries.
+One caveat: the op sequence is identical, but the compiler may contract
+mul+add pairs into FMAs (XLA's CPU emitter does so per fusion region), and
+fusing three kernel bodies into one changes where the unfused chain
+rounds to fp32 at HBM boundaries. The moments therefore agree to ~1 ulp
+per contraction (<= 1e-3 relative end-to-end) rather than bitwise; greedy
+tokens and the cache-miss fallback (which runs the real unfused chain)
+remain exact.
 """
 from __future__ import annotations
 
@@ -80,13 +78,6 @@ def _norm_dense_act_kernel(
         scale = jax.lax.rsqrt(spread + eps) * gain
         h_mu = (mu - mu_tok) * scale + bias_ref[...].astype(jnp.float32)
         h_var = var * jnp.square(scale)
-    # The unfused chain rounds the norm output to fp32 at the HBM
-    # boundary before to_srm / the dense consume it; inside one kernel
-    # body XLA would instead FMA-contract  var*scale^2 + h_mu^2  and
-    # produce different bits. The barrier pins the same rounding points
-    # the split kernels have (it only blocks instruction merging — the
-    # values never leave VMEM).
-    h_mu, h_var = jax.lax.optimization_barrier((h_mu, h_var))
     # GaussianTensor.to_srm on a VAR tensor: second + mean^2. Padded
     # columns have gain == 0, so h_mu == h_var == h_srm == 0 there and the
     # dense accumulation below matches the zero-padded unfused operands.
@@ -109,9 +100,6 @@ def _norm_dense_act_kernel(
         musq_acc = musq_acc + jnp.dot(jnp.square(xm), jnp.square(wm),
                                       preferred_element_type=jnp.float32)
     y_var = var_acc - musq_acc
-    # Second HBM-boundary rounding point of the unfused chain: the dense
-    # kernel writes (mean, var) out before the activation kernel reads it.
-    mu_acc, y_var = jax.lax.optimization_barrier((mu_acc, y_var))
 
     # Shared moment-matched activation epilogue: VAR -> SRM, elementwise,
     # so tile geometry can't perturb it.
@@ -172,10 +160,8 @@ def pfp_norm_dense_act_pallas(
             jax.ShapeDtypeStruct((m, n), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_compiler_params((dims, dims)),
     )
-    params = _compiler_params((dims, dims))
-    if params is not None and not interpret:
-        common["compiler_params"] = params
     fn = pl.pallas_call(
         functools.partial(
             _norm_dense_act_kernel, norm=norm, rep=rep, d=d, k128=k128,

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.gaussian import VAR_EPS
+from repro.kernels.pfp_activations import erf
 
 _SQRT_2 = math.sqrt(2.0)
 _SQRT_2PI = math.sqrt(2.0 * math.pi)
@@ -30,7 +31,7 @@ _SQRT_2PI = math.sqrt(2.0 * math.pi)
 def _clark(mu_a, var_a, mu_b, var_b):
     theta = jnp.sqrt(jnp.maximum(var_a + var_b, VAR_EPS))
     alpha = (mu_a - mu_b) / theta
-    cdf_a = 0.5 * (1.0 + jax.lax.erf(alpha / _SQRT_2))
+    cdf_a = 0.5 * (1.0 + erf(alpha / _SQRT_2))
     cdf_b = 1.0 - cdf_a
     pdf = jnp.exp(-0.5 * jnp.square(alpha)) / _SQRT_2PI
     mean = mu_a * cdf_a + mu_b * cdf_b + theta * pdf

@@ -27,8 +27,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pfp_dense import _compiler_params, _scratch
+from repro.kernels.pfp_dense import _compiler_params
 
 
 def _bdense_kernel(mu_x_ref, srm_x_ref, mu_w_ref, srm_w_ref,
@@ -249,10 +250,8 @@ def pfp_dense_batched_pallas(
             jax.ShapeDtypeStruct((e, c, n), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_compiler_params(sem),
     )
-    params = _compiler_params(sem)
-    if params is not None and not interpret:
-        common["compiler_params"] = params
 
     unrolled = k_order == "unrolled"
     if first_layer:
@@ -277,7 +276,7 @@ def pfp_dense_batched_pallas(
         fn = pl.pallas_call(
             functools.partial(_bdense_kernel, be=be, nk=nk),
             in_specs=[x_spec, x_spec, w_spec, w_spec],
-            scratch_shapes=[_scratch((be, bc, bn))],
+            scratch_shapes=[pltpu.VMEM((be, bc, bn), jnp.float32)],
             **common,
         )
     return fn(mu_x, srm_x, mu_w, srm_w)
@@ -321,10 +320,8 @@ def pfp_dense_batched_var_pallas(
             jax.ShapeDtypeStruct((e, c, n), jnp.float32),
         ],
         interpret=interpret,
+        compiler_params=_compiler_params(sem),
     )
-    params = _compiler_params(sem)
-    if params is not None and not interpret:
-        common["compiler_params"] = params
     kernel = (functools.partial(_bvar_formulation_kernel_unrolled, be=be,
                                 bk=bk, nk=nk)
               if k_order == "unrolled" else

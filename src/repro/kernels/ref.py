@@ -143,6 +143,10 @@ def pfp_attention_ref(q_mu, k_mu, v_mu, v_var, scale, causal=True):
         mask = idx_q >= jnp.arange(tk)[None, :]
         s = jnp.where(mask, s, jnp.finfo(f32).min)
     p = jax.nn.softmax(s, axis=-1)
+    if causal:
+        # A query with no key at or before it (tq > tk) attends to
+        # nothing and outputs (0, 0), as the kernels do.
+        p = p * jnp.any(mask, axis=-1, keepdims=True)
     out_mu = jnp.einsum("bhqk,bhkd->bhqd", p, v_mu.astype(f32))
     out_var = jnp.einsum("bhqk,bhkd->bhqd", jnp.square(p), v_var.astype(f32))
     return out_mu, out_var

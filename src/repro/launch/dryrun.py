@@ -144,8 +144,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         # XLA's cost_analysis counts while-loop bodies ONCE (a scanned
         # 36-layer model shows ~36x too cheap) — kept for reference only.
         cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # older jax: one dict per device
-            cost = cost[0] if cost else {}
         xla_flops = float(cost.get("flops", 0.0))
 
         # Trip-count-correct costs: exact dot FLOPs from the jaxpr (global /

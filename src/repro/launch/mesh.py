@@ -33,9 +33,15 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_mesh(shape, axes):
-    """Arbitrary mesh for tests (e.g. (2, 4) on 8 host devices)."""
+    """A mesh over the first prod(shape) devices, e.g. (1, 1) on one chip
+    or (2, 4) on 8 host devices."""
     need = int(np.prod(shape))
-    dev = np.array(jax.devices()[:need]).reshape(shape)
+    devices = jax.devices()
+    if len(devices) < need:
+        raise ValueError(
+            f"mesh {tuple(shape)} over axes {tuple(axes)} needs {need} "
+            f"devices, but {jax.default_backend()} has {len(devices)}")
+    dev = np.array(devices[:need]).reshape(shape)
     return jax.sharding.Mesh(dev, axes)
 
 

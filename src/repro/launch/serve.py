@@ -6,47 +6,42 @@ controlled scheduling, chunked prefill, one probabilistic forward pass per
 decode step for the whole slot batch, and uncertainty routing
 (continue / escalate-to-SVI / abstain). ``--impl kernel`` flips every PFP
 op onto the Pallas kernels via the impl-dispatch registry (interpret mode
-off-TPU).
+on the CPU backend only).
 
 Usage:
+  PYTHONPATH=src python -m repro.launch.serve --engine --impl kernel \
+      --page-size 16                      # chip: mesh (1, jax.device_count())
   PYTHONPATH=src python -m repro.launch.serve --devices 8 --mesh 2,4 \
-      --arch granite-8b --reduced --engine --tokens 8
-  PYTHONPATH=src python -m repro.launch.serve --devices 2 --reduced \
-      --engine --tokens 4            # CI interpret-mode smoke
-  PYTHONPATH=src python -m repro.launch.serve --devices 2 --reduced \
+      --engine --tokens 8                 # CPU: 8 forced host devices
+  PYTHONPATH=src python -m repro.launch.serve --devices 2 \
+      --engine --tokens 4                 # CPU interpret-mode smoke
+  PYTHONPATH=src python -m repro.launch.serve --devices 2 \
       --impl kernel --save-schedule-db db.json   # tune + persist fleet DB
-  PYTHONPATH=src python -m repro.launch.serve --devices 2 --reduced \
+  PYTHONPATH=src python -m repro.launch.serve --devices 2 \
       --impl kernel --schedule-db db.json --expect-warm-cache  # warm start
+
+Without ``--full-size`` the arch's ``reduced_config`` is served.
 """
 import argparse
+import dataclasses
 import json
 import os
 import sys
+from typing import Callable
 
+import jax
+import jax.numpy as jnp
 
-def _early_flags():
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--devices", type=int, default=8)
-    args, _ = ap.parse_known_args()
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
-
-
-_early_flags()
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from repro.bayes.convert import svi_to_pfp  # noqa: E402
-from repro.configs import get_config, reduced_config  # noqa: E402
-from repro.launch import sharding as shlib  # noqa: E402
-from repro.launch.mesh import make_mesh  # noqa: E402
-from repro.models import lm  # noqa: E402
-from repro.obs.trace import Tracer  # noqa: E402
-from repro.serving.engine import (Engine, EngineConfig, RequestScheduler,  # noqa: E402
+from repro.bayes.convert import svi_to_pfp
+from repro.configs import get_config, reduced_config
+from repro.launch import sharding as shlib
+from repro.launch.mesh import make_mesh
+from repro.models import lm
+from repro.obs.trace import Tracer
+from repro.serving.engine import (Engine, EngineConfig, RequestScheduler,
                                   RouterConfig, SchedulerConfig,
                                   UncertaintyRouter, poisson_trace, run_load)
-from repro.serving.fleet import Fleet, FleetConfig  # noqa: E402
+from repro.serving.fleet import Fleet, FleetConfig
 
 _SUMMARY_KEYS = (
     "submitted", "rejected", "expired", "completed", "abstained",
@@ -305,16 +300,18 @@ def _run_fleet(args, cfg, params, router, sched_cfg, mesh, dims, max_len,
     return 0
 
 
-def _parse_args():
+def parse_args(argv=None):
     ap = argparse.ArgumentParser()
-    ap.add_argument("--devices", type=int, default=8)
+    ap.add_argument("--devices", type=int, default=None,
+                    help="host devices to force on the CPU backend (tests "
+                         "of meshes without a chip); an accelerator "
+                         "backend ignores it")
     ap.add_argument("--mesh", default=None,
-                    help="data,model dims (default: 1,<devices>)")
+                    help="data,model dims (default: 1,<device count>)")
     ap.add_argument("--arch", default="granite-8b")
-    ap.add_argument("--reduced", action="store_true", default=True)
-    ap.add_argument("--full-size", dest="reduced", action="store_false",
-                    help="serve the full-size config instead of the "
-                         "reduced CPU-smoke one")
+    ap.add_argument("--full-size", action="store_true",
+                    help="serve the arch's published config instead of "
+                         "its reduced CPU-smoke one")
     ap.add_argument("--batch", type=int, default=4,
                     help="decode slots (the continuous batch size)")
     ap.add_argument("--prompt-len", type=int, default=16)
@@ -435,23 +432,37 @@ def _parse_args():
                          "Pallas kernel when a tuned norm_dense_act "
                          "schedule is cached (falls back to the unfused "
                          "chain otherwise)")
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-def _serve(args):
-    if args.mesh:
-        dims = tuple(int(x) for x in args.mesh.split(","))
-    else:
-        dims = (1, args.devices)
-    mesh = make_mesh(dims, ("data", "model"))
-    cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
+def init_serving_params(cfg, seed: int, mesh):
+    """Random PFP serving params for ``cfg``, placed on ``mesh`` by the
+    serve sharding rules. Init and SVI->PFP conversion run as ONE jitted
+    program, so the variational (mu, rho) tree never sits on the device
+    beside the (mu, srm) tree that is served."""
+    def init():
+        return svi_to_pfp(lm.init_params(cfg, jax.random.PRNGKey(seed)))
+
+    p_sh = shlib.params_shardings(jax.eval_shape(init), mesh, serve=True)
+    return jax.jit(init, out_shardings=p_sh)()
+
+
+@dataclasses.dataclass
+class ServingParts:
+    """What one serving run is built from: the engine builder, a trace
+    factory and the router/scheduler they share."""
+    build_engine: Callable[[int], Engine]   # speculate_k -> Engine
+    make_trace: Callable[[], list]           # fresh list of Requests
+    router: UncertaintyRouter
+    sched_cfg: SchedulerConfig
+    max_len: int
+
+
+def serving_parts(args, cfg, params, mesh, tracer=None) -> ServingParts:
+    """The serving path this driver runs, for ``args`` (from
+    :func:`parse_args`): ``main`` and ``chip_smoke.py`` both build their
+    engines here."""
     max_len = args.prompt_len + args.tokens
-
-    params = svi_to_pfp(lm.init_params(cfg, jax.random.PRNGKey(args.seed)))
-    p_sh = shlib.params_shardings(jax.eval_shape(lambda: params), mesh,
-                                  serve=True)
-    params = jax.device_put(params, p_sh)
-
     router = UncertaintyRouter(
         cfg, RouterConfig(mi_continue=args.mi_continue,
                           mi_abstain=args.mi_abstain,
@@ -460,6 +471,7 @@ def _serve(args):
     sched_cfg = SchedulerConfig(prefill_chunk=args.prefill_chunk,
                                 prefill_budget=2 * args.prefill_chunk)
     scheduler = RequestScheduler(sched_cfg, max_len=max_len)
+
     def make_trace():
         # Regenerable: run_load mutates the Request objects, so the
         # speculative parity check below needs a fresh copy per engine.
@@ -480,8 +492,6 @@ def _serve(args):
                 r.prompt[:n] = system[:n]
         return trace
 
-    tracer = Tracer(wall=args.trace_wall) if args.trace_out else None
-
     def build_engine(speculate_k):
         return Engine(
             cfg, params,
@@ -498,9 +508,25 @@ def _serve(args):
                          speculate_k=speculate_k),
             router=router, scheduler=scheduler, mesh=mesh, tracer=tracer)
 
+    return ServingParts(build_engine, make_trace, router, sched_cfg, max_len)
+
+
+def _serve(args):
+    if args.mesh:
+        dims = tuple(int(x) for x in args.mesh.split(","))
+    else:
+        dims = (1, jax.device_count())
+    mesh = make_mesh(dims, ("data", "model"))
+    cfg = get_config(args.arch) if args.full_size else reduced_config(args.arch)
+    params = init_serving_params(cfg, args.seed, mesh)
+    tracer = Tracer(wall=args.trace_wall) if args.trace_out else None
+    parts = serving_parts(args, cfg, params, mesh, tracer)
+    build_engine, make_trace = parts.build_engine, parts.make_trace
+
     if args.replicas > 1:
-        return _run_fleet(args, cfg, params, router, sched_cfg, mesh, dims,
-                          max_len, build_engine, make_trace, tracer)
+        return _run_fleet(args, cfg, params, parts.router, parts.sched_cfg,
+                          mesh, dims, parts.max_len, build_engine, make_trace,
+                          tracer)
 
     with mesh:
         engine = build_engine(args.speculate)
@@ -658,9 +684,18 @@ def _tuning_epilogue(args, queries):
 def main():
     import contextlib
 
-    args = _parse_args()
+    args = parse_args()
+    if args.devices:
+        # Read when the CPU backend starts, i.e. at the first device query
+        # below; accelerator backends ignore it.
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={args.devices}")
     from repro.core import dispatch
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.tuning import cache as sched_cache
+
+    enable_compile_cache()
 
     if args.fuse_ops:
         dispatch.set_fusion(True)

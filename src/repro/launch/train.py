@@ -1,10 +1,10 @@
 """Distributed training driver: the program the dry-run lowers, executed.
 
-On real hardware each host runs this under `jax.distributed.initialize()`;
-on this container it runs the same code path on a small host-device mesh
-(--devices N sets XLA_FLAGS before jax init). Demonstrates the full
-production loop: sharded params/optimizer, per-host data shards,
-checkpoint/restart (elastic), straggler monitoring.
+On one chip the mesh defaults to (1, jax.device_count()); on the CPU,
+--devices N forces N host devices so the same sharded program runs on a
+small host-device mesh. Demonstrates the full production loop: sharded
+params/optimizer, per-host data shards, checkpoint/restart (elastic),
+straggler monitoring.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.train --devices 8 --mesh 4,2 \
@@ -13,40 +13,31 @@ Usage:
 import argparse
 import os
 import sys
+import time
 
+import jax
+import jax.numpy as jnp
 
-def _early_flags():
-    ap = argparse.ArgumentParser(add_help=False)
-    ap.add_argument("--devices", type=int, default=8)
-    args, _ = ap.parse_known_args()
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}")
-
-
-_early_flags()
-
-import time  # noqa: E402
-
-import jax  # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-
-from repro.bayes.variational import KLSchedule  # noqa: E402
-from repro.configs import get_config, reduced_config  # noqa: E402
-from repro.data.tokens import TokenPipeline  # noqa: E402
-from repro.launch import sharding as shlib  # noqa: E402
-from repro.launch.mesh import make_mesh  # noqa: E402
-from repro.models import lm  # noqa: E402
-from repro.training.checkpoint import CheckpointManager  # noqa: E402
-from repro.training.fault_tolerance import StepMonitor  # noqa: E402
-from repro.training.optimizer import Adam, cosine_schedule  # noqa: E402
-from repro.training.train_loop import (TrainState, init_train_state,  # noqa: E402
+from repro.bayes.variational import KLSchedule
+from repro.configs import get_config, reduced_config
+from repro.data.tokens import TokenPipeline
+from repro.launch import sharding as shlib
+from repro.launch.mesh import make_mesh
+from repro.models import lm
+from repro.training.checkpoint import CheckpointManager
+from repro.training.fault_tolerance import StepMonitor
+from repro.training.optimizer import Adam, cosine_schedule
+from repro.training.train_loop import (TrainState, init_train_state,
                                        make_svi_train_step)
 
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--devices", type=int, default=8)
-    ap.add_argument("--mesh", default="4,2", help="data,model")
+    ap.add_argument("--devices", type=int, default=None,
+                    help="host devices to force on the CPU backend; an "
+                         "accelerator backend ignores it")
+    ap.add_argument("--mesh", default=None,
+                    help="data,model (default: 1,<device count>)")
     ap.add_argument("--arch", default="granite-8b")
     ap.add_argument("--reduced", action="store_true",
                     help="CPU-sized config of the same family")
@@ -56,8 +47,16 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/pfp_train_ckpt")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    if args.devices:
+        # Read when the CPU backend starts, at the first device query below.
+        os.environ.setdefault(
+            "XLA_FLAGS",
+            f"--xla_force_host_platform_device_count={args.devices}")
+    from repro.launch.compile_cache import enable_compile_cache
 
-    dims = tuple(int(x) for x in args.mesh.split(","))
+    enable_compile_cache()
+    dims = (tuple(int(x) for x in args.mesh.split(",")) if args.mesh
+            else (1, jax.device_count()))
     mesh = make_mesh(dims, ("data", "model"))
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     print(f"mesh={dict(mesh.shape)} arch={cfg.name} "

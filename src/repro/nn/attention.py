@@ -267,6 +267,11 @@ def attention_apply(
             q_mu, k_mu, v_mu, v_var, group=group, scale=scale, causal=causal,
             impl=ctx.impl)
     else:
+        if pfp and dispatch.resolve_impl(ctx.impl) == "kernel":
+            # The kernel stack was asked for, but this case (probit-
+            # corrected scores, a cache-free window or remapped causal
+            # positions) has no kernel: count it, don't hide it.
+            dispatch.note_fallback("attention:xla_core")
         if paged:
             # Gather the pool pages into the contiguous layout, then run
             # the exact same chunked core as the contiguous cache path —

@@ -31,7 +31,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import dispatch
@@ -216,11 +215,11 @@ def _dispatch_a2a(mesh, vals_list, flat_e, slot, *, num_experts, capacity):
         return tuple(outs)
 
     n = len(vals_list)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P("data"), P("data")) + (P("data", None),) * n,
         out_specs=(P("data", None, None),) * n,
-        check_rep=False)(flat_e, slot, *vals_list)
+        check_vma=False)(flat_e, slot, *vals_list)
 
 
 def _combine_a2a(mesh, buf_weight_list, flat_e, slot, token_of, *, tokens):
@@ -252,12 +251,12 @@ def _combine_a2a(mesh, buf_weight_list, flat_e, slot, token_of, *, tokens):
 
     n = len(buf_weight_list)
     flat_args = [a for pair in buf_weight_list for a in pair]
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P("data"), P("data"), P("data"))
         + (P("data", None, None), P("data")) * n,
         out_specs=(P("data", None),) * n,
-        check_rep=False)(flat_e, slot, token_of, *flat_args)
+        check_vma=False)(flat_e, slot, token_of, *flat_args)
 
 
 def _moe_tokens(params, x, ctx: Context, *, num_experts: int, top_k: int,

@@ -101,7 +101,9 @@ def make_svi_fallback(cfg: ModelConfig, num_samples: int, *,
             return jax.lax.dynamic_index_in_dim(
                 logits, out_idx, 1, keepdims=False).astype(jnp.float32)
 
-        samples = jax.vmap(one)(jax.random.split(key, num_samples))
+        # Samples run one after another: each draws a whole weight sample,
+        # and a vmap would hold num_samples of them at once.
+        samples = jax.lax.map(one, jax.random.split(key, num_samples))
         m = predictive_metrics_from_samples(samples)        # (N, 1, V) in
         return m["pred"][0], m["mi"][0]
 
@@ -136,7 +138,8 @@ def make_svi_fallback_batched(cfg: ModelConfig, num_samples: int, *,
         return _FALLBACK_CACHE[cache_key]
 
     def batched(params, inputs, states, base_key, uids, tok_idx, out_idx):
-        def row(tokens, positions, cache_len, write_start, table_row, uid, t):
+        def row(args):
+            tokens, positions, cache_len, write_start, table_row, uid, t = args
             inp = {"tokens": tokens[None], "positions": positions[None],
                    "cache_len": cache_len[None],
                    "write_start": write_start[None],
@@ -151,11 +154,14 @@ def make_svi_fallback_batched(cfg: ModelConfig, num_samples: int, *,
                     logits = logits.mean
                 return logits[0].astype(jnp.float32)        # (C, V)
 
-            return jax.vmap(one)(jax.random.split(key, num_samples))
+            return jax.lax.map(one, jax.random.split(key, num_samples))
 
-        samples = jax.vmap(row)(
+        # Rows and samples run one after another (lax.map, not vmap): each
+        # (row, sample) draws a whole weight sample, and at published
+        # widths slots x samples of them do not fit device memory.
+        samples = jax.lax.map(row, (
             inputs["tokens"], inputs["positions"], inputs["cache_len"],
-            inputs["write_start"], inputs["page_table"], uids, tok_idx)
+            inputs["write_start"], inputs["page_table"], uids, tok_idx))
         # (B, N, C, V) -> each row's samples at its own replay out_idx
         samples = jnp.take_along_axis(
             samples, out_idx[:, None, None, None], axis=2)[:, :, 0]

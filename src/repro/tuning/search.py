@@ -25,37 +25,37 @@ from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 from repro.tuning.schedules import (DEFAULT_SCHEDULES, OP_AXES,
                                     OP_BLOCK_NAMES, Schedule)
 
-# Fallback VMEM budget (v5e-class core: ~16 MB); the real budget is
-# derived from the running device's kind — see :func:`vmem_limit_bytes`.
-VMEM_LIMIT_BYTES = 16 * 2 ** 20
+# Scoped VMEM budget per kernel by device-kind substring (lowercased
+# match): the Mosaic compiler's default scoped limit, which is what a
+# kernel's pipelined blocks must fit — not the physical VMEM. v4/v5 cores
+# default to 16 MiB, Trillium (v6) to 32 MiB.
+_VMEM_MB_BY_KIND = (("v6", 32), ("trillium", 32), ("v5", 16), ("v4", 16))
 VMEM_HEADROOM = 0.75
-
-# Per-core VMEM by device-kind substring (lowercased match). v2–v5
-# cores all carry ~16 MB; Trillium (v6) doubled VMEM capacity.
-_VMEM_MB_BY_KIND = (("v6", 32), ("trillium", 32),
-                    ("v5", 16), ("v4", 16), ("v3", 16), ("v2", 16))
+# Off the chip (Pallas interpret mode on the CPU) schedules are ranked for
+# this target, so CPU tuning still produces schedules a v5e compiles.
+CPU_TARGET_KIND = "TPU v5 lite"
 
 
 @functools.lru_cache(maxsize=1)
 def vmem_limit_bytes() -> int:
-    """VMEM budget for the device actually running, from
-    ``obs/runmeta.device_kind`` — ``VMEM_LIMIT_BYTES`` when the kind is
-    unrecognized (CPU interpret mode ranks against the v5e budget so
-    off-TPU tuning produces TPU-plausible schedules). Override with
-    ``REPRO_VMEM_LIMIT_BYTES`` for tests / unlisted targets."""
+    """Scoped VMEM budget of the device running this process (from
+    ``obs/runmeta.device_kind``; the CPU ranks for ``CPU_TARGET_KIND``).
+    A TPU kind missing from the table raises rather than guess. Override
+    with ``REPRO_VMEM_LIMIT_BYTES`` for tests / unlisted targets."""
     env = os.environ.get("REPRO_VMEM_LIMIT_BYTES")
     if env:
         return int(env)
-    try:
-        from repro.obs.runmeta import device_kind
+    from repro.obs.runmeta import device_kind
 
-        kind = device_kind().lower()
-    except Exception:
-        return VMEM_LIMIT_BYTES
+    kind = device_kind()
+    if kind.lower() == "cpu":
+        kind = CPU_TARGET_KIND
     for tag, mb in _VMEM_MB_BY_KIND:
-        if tag in kind:
+        if tag in kind.lower():
             return mb * 2 ** 20
-    return VMEM_LIMIT_BYTES
+    raise ValueError(f"no scoped-VMEM budget known for device kind {kind!r}; "
+                     "add it to tuning/search.py or set "
+                     "REPRO_VMEM_LIMIT_BYTES")
 
 _SUBLANE = 8    # fp32 sublane multiple
 _LANE = 128     # lane multiple (MXU/VPU width)
@@ -71,11 +71,20 @@ def _steps(dim: int, block: int) -> int:
     return -(-_round_up(dim, block) // block)
 
 
+def _pipelined(blocks: int, *, scratch: int = 0, temps: int = 0) -> int:
+    """VMEM bytes of one grid step (fp32 elements in): Pallas double-buffers
+    every blocked operand and result so the next block's DMA overlaps this
+    step's compute; scratch accumulators and in-body temporaries are
+    single."""
+    return (2 * blocks + scratch + temps) * 4
+
+
 @dataclasses.dataclass(frozen=True)
 class CostSummary:
     """Analytic per-schedule cost figures (no hardware required)."""
 
-    vmem_bytes: int          # per-grid-step VMEM working set (fp32)
+    vmem_bytes: int          # per-grid-step VMEM working set (fp32,
+    #                          double-buffered blocks included)
     flops: int               # whole-op FLOPs
     bytes_moved: int         # whole-op HBM traffic estimate (fp32)
     grid_steps: int          # total grid size after padding
@@ -110,14 +119,21 @@ def cost_summary(op: str, shape_key: ShapeKey, schedule: Schedule) -> CostSummar
         # sequential axis): small bm re-streams the whole weight matrix.
         io = (x_bufs * m * k * _steps(n, bn) + 2 * k * n * _steps(m, bm)
               + 2 * m * n) * 4
+        # In-body temporaries: the squared tiles and one dot result.
+        temps = bm * bk + bk * bn + bm * bn
         if schedule.axis("k_order") == "unrolled":
             # Grid is (M/bm, N/bn); full K strips stay resident and the
-            # K-tile loop runs inside the kernel body.
+            # K-tile loop runs inside the kernel body, accumulators in
+            # values.
             kp = _round_up(k, bk)
-            vmem = (x_bufs * bm * kp + 2 * kp * bn + n_acc * bm * bn) * 4
+            vmem = _pipelined(x_bufs * bm * kp + 2 * kp * bn + 2 * bm * bn,
+                              temps=temps + n_acc * bm * bn)
             steps = _steps(m, bm) * _steps(n, bn)
         else:  # "mnk" / "nmk": same footprint, K innermost either way
-            vmem = (x_bufs * bm * bk + 2 * bk * bn + n_acc * bm * bn) * 4
+            # Outputs double as accumulators; dense's mu^2 correction
+            # lives in one scratch tile.
+            vmem = _pipelined(x_bufs * bm * bk + 2 * bk * bn + 2 * bm * bn,
+                              scratch=(n_acc - 2) * bm * bn, temps=temps)
             steps = _steps(m, bm) * _steps(n, bn) * _steps(k, bk)
         aligned = bm % _SUBLANE == 0 and bn % _LANE == 0 and bk % _LANE == 0
     elif op == "dense_batched":
@@ -137,12 +153,15 @@ def cost_summary(op: str, shape_key: ShapeKey, schedule: Schedule) -> CostSummar
         # once per N-block, w tiles once per C-block.
         io = (2 * e * c * k * _steps(n, bn) + 2 * e * k * n * _steps(c, bc)
               + 2 * e * c * n) * 4
+        temps = be * (bc * bk + bk * bn + bc * bn)
         if schedule.axis("k_order") == "unrolled":
             kp = _round_up(k, bk)
-            vmem = be * (2 * bc * kp + 2 * kp * bn + 3 * bc * bn) * 4
+            vmem = _pipelined(be * (2 * bc * kp + 2 * kp * bn + 2 * bc * bn),
+                              temps=temps + 3 * be * bc * bn)
             steps = _steps(e, be) * _steps(c, bc) * _steps(n, bn)
         else:
-            vmem = be * (2 * bc * bk + 2 * bk * bn + 3 * bc * bn) * 4
+            vmem = _pipelined(be * (2 * bc * bk + 2 * bk * bn + 2 * bc * bn),
+                              scratch=be * bc * bn, temps=temps)
             steps = (_steps(e, be) * _steps(c, bc) * _steps(n, bn)
                      * _steps(k, bk))
         aligned = bc % _SUBLANE == 0 and bn % _LANE == 0 and bk % _LANE == 0
@@ -157,10 +176,11 @@ def cost_summary(op: str, shape_key: ShapeKey, schedule: Schedule) -> CostSummar
         # Scalar-prefetch depth (paged only): pf pages of KV are resident
         # per grid step, shrinking the K grid by the same factor.
         pf = int(schedule.axis("prefetch")) if op == "attention_paged" else 1
-        vmem = (bq * d + 3 * bk * pf * d     # q tile + k/v_mu/v_var tiles
-                + bq * bk * pf               # score tile
-                + 4 * bq * d                 # acc_mu/acc_var + two outputs
-                + 2 * bq * _LANE) * 4        # running max / normalizer
+        vmem = _pipelined(
+            bq * d + 3 * bk * pf * d         # q tile + k/v_mu/v_var tiles
+            + 2 * bq * d,                    # two outputs
+            scratch=2 * bq * d + 2 * bq * _LANE,  # acc_mu/acc_var, max/norm
+            temps=3 * bq * bk)               # score, p and p^2 of one page
         flops = b * h * tq * tk * (6 * d + 8)
         io = (b * h * tq * d * 3 + b * hkv * tk * d * 3 * _steps(tq, bq)) * 4
         steps = b * h * _steps(tq, bq) * _steps(tk, bk * pf)
@@ -170,7 +190,7 @@ def cost_summary(op: str, shape_key: ShapeKey, schedule: Schedule) -> CostSummar
         br = min(get("block_rows", 256), _round_up(rows, _SUBLANE))
         bc = min(get("block_cols", 512), _round_up(cols, _LANE))
         tiles = {"activation": 4, "glu_product": 6, "maxpool2d": 10}[op]
-        vmem = tiles * br * bc * 4
+        vmem = _pipelined(tiles * br * bc, temps=tiles * br * bc)
         per_elem = {"activation": 50, "glu_product": 2, "maxpool2d": 60}[op]
         flops = per_elem * rows * cols
         io = tiles * rows * cols * 4
@@ -185,7 +205,8 @@ def cost_summary(op: str, shape_key: ShapeKey, schedule: Schedule) -> CostSummar
         kp = _round_up(k, _LANE)
         bm = min(get("block_m", 128), _round_up(m, _SUBLANE))
         bn = min(get("block_n", 128), _round_up(n, _LANE))
-        vmem = (2 * bm * kp + 2 * kp + 2 * kp * bn + 3 * bm * bn) * 4
+        vmem = _pipelined(2 * bm * kp + 2 * kp + 2 * kp * bn + 2 * bm * bn,
+                          temps=3 * bm * kp + 3 * bm * bn)
         flops = 3 * 2 * m * n * k + 12 * m * k + 50 * m * n
         # The fusion's whole point: x is normalized in-kernel, so the
         # norm's intermediate never round-trips HBM.
@@ -197,7 +218,8 @@ def cost_summary(op: str, shape_key: ShapeKey, schedule: Schedule) -> CostSummar
         rows, d = shape_key
         dp = _round_up(d, _LANE)
         br = min(get("block_rows", 256), _round_up(rows, _SUBLANE))
-        vmem = (4 * br * dp + 2 * dp) * 4
+        # two row strips in, two out, plus var/srm of the strip in-body
+        vmem = _pipelined(4 * br * dp + 2 * dp, temps=2 * br * dp)
         flops = 12 * rows * d
         io = 4 * rows * d * 4
         if schedule.axis("epilogue") == "split":

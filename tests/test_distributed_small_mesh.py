@@ -129,21 +129,15 @@ def test_compressed_psum_in_shard_map():
     from repro.launch.mesh import make_mesh
     from repro.training.compression import compressed_psum
 
-    # jax.shard_map only exists on newer jax; fall back to the
-    # experimental home on the pinned version.
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-
     mesh = make_mesh((8,), ("data",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P("data"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
                        out_specs=P("data"))
     def exact(v):
         return jax.lax.psum(v, "data")
 
-    @functools.partial(shard_map, mesh=mesh, in_specs=P("data"),
+    @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
                        out_specs=P("data"))
     def compressed(v):
         return compressed_psum(v, "data")

@@ -199,3 +199,30 @@ def test_attention_kernel_matches_model_attention():
     want = ref.pfp_attention_ref(q, k, vm, vv, D ** -0.5, causal=True)
     np.testing.assert_allclose(out.mean, want[0], rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(out.var, want[1], rtol=1e-4, atol=1e-5)
+
+
+def test_in_kernel_erf_matches_lax_erf():
+    # Mosaic has no lowering for lax.erf, so the ReLU and max-pool kernels
+    # evaluate XLA's fp32 rational approximation themselves. Run it inside
+    # a Pallas kernel (interpret mode) over a range wide enough to cover
+    # both saturated tails and every sign.
+    from jax.experimental import pallas as pl
+
+    from repro.kernels.pfp_activations import erf
+
+    def kernel(x_ref, o_ref):
+        o_ref[...] = erf(x_ref[...])
+
+    def run(x):
+        return pl.pallas_call(kernel, out_shape=jax.ShapeDtypeStruct(
+            x.shape, jnp.float32), interpret=True)(x)
+
+    x = jnp.linspace(-12.0, 12.0, 256 * 1024, dtype=jnp.float32)
+    x = x.reshape(256, 1024)
+    got = run(x)
+    want = jax.lax.erf(x)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-6)
+    # odd, bounded, and exactly +-1 where fp32 erf saturates
+    np.testing.assert_array_equal(run(-x), -got)
+    assert float(jnp.max(jnp.abs(got))) <= 1.0
+    np.testing.assert_array_equal(got[x >= 4.5], 1.0)
