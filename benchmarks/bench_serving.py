@@ -18,11 +18,6 @@ Row families, emitted through benchmarks/common.py:
                               loadgen throughput and the expert-capacity
                               drop accounting (assignments / dropped /
                               drop rate);
-  serving/op_profile/...      ONE eager lockstep decode pass through the
-                              dispatch profiler (every op fenced): the
-                              derived column is the live Table-4-style
-                              per-layer time breakdown + tuning-cache
-                              consult counters;
   serving/obs_overhead/...    the observability acceptance row: the same
                               loadgen trace with tracing disabled and
                               with a live Tracer + exports — derived
@@ -213,39 +208,11 @@ def _loadgen_row(lines, cfg, params, *, n_requests, page_size=None,
         s["elapsed_s"], derived))
 
 
-def _op_profile_row(lines, cfg, params):
-    """Live Table-4 row: ONE eager lockstep decode pass through the
-    dispatch-registry profiler — every PFP op block_until_ready-fenced,
-    so the derived column carries the per-layer time breakdown (and the
-    tuning-cache consult counters) of the forward the engine actually
-    serves. Runs with every slot inactive, so no engine state mutates."""
-    from repro.obs.profiler import profile_ops
-
-    engine = _build_engine(cfg, params, page_size=PAGE_SIZE)
-    b = engine.config.slots
-    feed = jnp.zeros((b, 1), jnp.int32)
-    pos = jnp.zeros((b, 1), jnp.int32)
-    clen = jnp.zeros(b, jnp.int32)
-    active = jnp.zeros(b, bool)
-    with profile_ops() as prof:
-        engine.decode_fn(engine.params, feed, pos, clen, active,
-                         engine.pool.states, engine.pool.device_table(),
-                         *engine.logit_buffers)
-    rows = prof.table()
-    assert rows, "profiled decode pass dispatched no registry ops"
-    top = ";".join(f"{r['op']}={r['frac'] * 100:.1f}%" for r in rows[:5])
-    lines.append(emit(
-        f"serving/op_profile/b{b}/ps{PAGE_SIZE}", prof.total_seconds,
-        f"{top};ops={len(rows)}"
-        f";cache_consults={prof.cache_consults}"
-        f";cache_hits={prof.cache_hits}"))
-
-
 def _obs_overhead_row(lines, cfg, params, *, n_requests, full):
     """Acceptance row: the SAME warmed Poisson loadgen run with tracing
     disabled (the default engine — every emit site sits behind an
-    ``if tracer is not None``) and with a live Tracer attached, both
-    trace exports and the Prometheus text rendered afterwards. The
+    ``if tracer is not None``) and with a live Tracer attached, the
+    JSONL log and the Prometheus text rendered afterwards. The
     derived column carries the enabled/disabled elapsed ratio; --full
     pins it < 1.05 (the quick profile is too short to time stably)."""
     from repro.obs.trace import Tracer
@@ -270,7 +237,6 @@ def _obs_overhead_row(lines, cfg, params, *, n_requests, full):
     # pathological exporter would still show up in the bench log
     n_events = len(tracer.events)
     tracer.to_jsonl()
-    tracer.to_chrome()
     eng_on.metrics.registry.to_prometheus()
     ratio = s_on["elapsed_s"] / max(s_off["elapsed_s"], 1e-9)
     lines.append(emit(
@@ -643,8 +609,6 @@ def run(quick: bool = True, page_sizes=None):
     # -- uncertainty-aware MoE decode: routed experts + drop accounting ----
     _moe_decode_row(lines, n_requests=8 if quick else 32)
 
-    # -- live Table-4: per-op fenced decode profile ------------------------
-    _op_profile_row(lines, cfg, params)
 
     # -- observability cost: tracing on vs off on one loadgen trace --------
     _obs_overhead_row(lines, cfg, params, n_requests=n_requests,

@@ -12,7 +12,7 @@
 10. Speculative decoding gated by the PFP's own uncertainty  (repro.serving)
 11. Fleet serving: two disaggregated replicas behind a prefix router
 12. Observability: deterministic traces (Perfetto-viewable), metrics
-    registry exports, live per-op profile, uncertainty telemetry (repro.obs)
+    registry exports, profiler-clock spans, uncertainty telemetry (repro.obs)
 13. Warm-start fleet schedule DB: tune once, persist, every replica
     serves warm with zero schedule search on the hot path (repro.tuning)
 14. Uncertainty-aware MoE decode: routed top-k experts through the
@@ -347,7 +347,7 @@ def main():
     # with parity + page/hold-leak checks and a `--expect-route-hits`
     # floor; bench_serving's fleet row pins the acceptance criteria.
 
-    print("== 12. Observability: traces, metrics, live per-op profile ==")
+    print("== 12. Observability: request log, spans, metrics ==")
     # The whole serving stack instruments through repro.obs. A Tracer
     # records every lifecycle event keyed on (engine step, seq) — the
     # engine's step counter is the only time base, so two identical runs
@@ -370,11 +370,10 @@ def main():
     kinds = sorted({e["event"] for e in events})
     print(f"  {len(events)} trace events across "
           f"{len({e['lane'] for e in events})} lanes: {', '.join(kinds)}")
-    # Write the Perfetto view: drop this file onto https://ui.perfetto.dev
-    # and each lane becomes a track with per-request lifetime spans.
-    chrome = tracer.to_chrome()
-    print(f"  Chrome trace-event export: {len(chrome['traceEvents'])} "
-          f"entries (tracer.write_chrome('trace.chrome.json') to save)")
+    # The same phases are host spans on the profiler's clock (pfp.step,
+    # pfp.prefill_round, pfp.route, ...): run the fleet inside
+    # jax.profiler.trace(dir) and the .xplane.pb holds them beside the
+    # device's programs (create_perfetto_trace=True adds a Perfetto view).
     # Uncertainty telemetry rides the same summaries: band totals pool
     # across replicas by summation; calibration (mi_ece) stays per-engine
     # because an error RATE does not sum.
@@ -389,21 +388,9 @@ def main():
     sample = [ln for ln in prom.splitlines()
               if ln.startswith("repro_tokens_generated")][0]
     print(f"  Prometheus sample: {sample}")
-    # And the live Table-4 per-op profile of the serving forward:
-    from repro.obs.profiler import profile_ops
-    eng0 = fleet2.replicas[0].decode_engine
-    feed = jnp.zeros((eng0.config.slots, 1), jnp.int32)
-    zeros = jnp.zeros(eng0.config.slots, jnp.int32)
-    with profile_ops() as prof:  # eager, per-op block_until_ready fences
-        eng0.decode_fn(eng0.params, feed, feed, zeros,
-                       jnp.zeros(eng0.config.slots, bool), eng0.pool.states,
-                       eng0.pool.device_table(), *eng0.logit_buffers)
-    top = prof.table()[0]
-    print(f"  per-op decode profile: {len(prof.table())} ops, top = "
-          f"{top['op']} at {top['frac']:.0%} of pass time")
-    _ = _json.dumps(tracer.to_chrome())  # both exports are plain JSON
+    _ = _json.dumps(tracer.events)  # the log is plain JSON
     # `launch/serve.py --trace-out t.jsonl --metrics-out m.json --prom-out
-    # m.prom --profile-ops` exports all of this from a real run, and
+    # m.prom` exports all of this from a real run, and
     # `python -m repro.obs.validate` schema-checks the artifacts (the CI
     # obs-smoke job's gate).
 

@@ -52,13 +52,6 @@ _DEFAULT_IMPL = "xla"
 # op name -> {'xla': fn, 'kernel': fn}
 _REGISTRY: Dict[str, Dict[str, Callable]] = {}
 
-# Active per-op profiler (repro.obs.profiler.OpProfiler) or None. When
-# set, get_op returns a fenced/timed wrapper around the resolved impl and
-# _schedule_for reports each tuning-cache consult. The None check is the
-# only cost the un-profiled path pays.
-_PROFILER = None
-
-
 # Open count_fallbacks() scopes: dicts label -> count.
 _FALLBACK_SCOPES: list = []
 
@@ -79,24 +72,9 @@ def count_fallbacks():
 
 def note_fallback(label: str) -> None:
     """Record that a kernel-impl call fell back to XLA (``label`` names the
-    op and the case), for the op profiler and every open scope."""
-    if _PROFILER is not None:
-        _PROFILER.on_fallback(label)
+    op and the case), in every open scope."""
     for counts in _FALLBACK_SCOPES:
         counts[label] = counts.get(label, 0) + 1
-
-
-def set_profiler(profiler):
-    """Install (or clear, with None) the dispatch-level op profiler.
-    Returns the previous profiler so scopes nest."""
-    global _PROFILER
-    prev = _PROFILER
-    _PROFILER = profiler
-    return prev
-
-
-def get_profiler():
-    return _PROFILER
 
 
 # ---------------------------------------------------------------------------
@@ -136,10 +114,7 @@ def register(name: str, impl: str):
 
 def get_op(name: str, impl: Optional[str] = None) -> Callable:
     impl = resolve_impl(impl)
-    fn = _REGISTRY[name][impl]
-    if _PROFILER is not None:
-        return _PROFILER.wrap(name, impl, fn)
-    return fn
+    return _REGISTRY[name][impl]
 
 
 def registered_ops() -> Dict[str, Dict[str, Callable]]:
@@ -173,11 +148,8 @@ def _schedule_for(op: str, shape_key, dtype) -> Optional[Any]:
     """
     from repro.tuning import cache as _schedule_cache
 
-    sched = _schedule_cache.lookup(op, tuple(int(d) for d in shape_key),
-                                   jnp.dtype(dtype).name)
-    if _PROFILER is not None:
-        _PROFILER.on_cache_consult(op, sched is not None)
-    return sched
+    return _schedule_cache.lookup(op, tuple(int(d) for d in shape_key),
+                                  jnp.dtype(dtype).name)
 
 
 def _rows(shape) -> int:
@@ -802,9 +774,9 @@ class _PendingNormDense(_PendingFusion):
         """Attempt the fused lowering; None -> caller falls back unfused.
 
         The fused-unit schedule is consulted on EVERY attempt (hit or
-        miss) so shape recording discovers the unit and the profiler's
-        consult counters see it — a warm fleet DB therefore proves itself
-        with zero misses here too."""
+        miss) so shape recording discovers the unit and the tuning
+        cache's consult counters see it — a warm fleet DB therefore proves
+        itself with zero misses here too."""
         if (self._value is not None or act not in _FUSABLE_ACTS
                 or not _fusion_active(impl)):
             return None
@@ -905,7 +877,7 @@ def pfp_residual(x, y, impl: Optional[str] = None) -> GaussianTensor:
 
 __all__ = [
     "IMPLS", "set_default_impl", "get_default_impl", "resolve_impl",
-    "register", "get_op", "registered_ops", "set_profiler", "get_profiler",
+    "register", "get_op", "registered_ops",
     "count_fallbacks", "note_fallback",
     "set_fusion", "get_fusion", "fusion",
     "pfp_dense", "pfp_dense_batched", "pfp_einsum", "pfp_conv2d_im2col",

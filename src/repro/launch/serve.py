@@ -105,39 +105,13 @@ def _lane_registries(target):
     return {"engine": target.metrics.registry}
 
 
-def _profile_decode(engine):
-    """One eager, per-op-fenced lockstep decode pass through the dispatch
-    profiler — the live Table-4-style per-layer breakdown for the LM
-    forward the engine actually serves. Runs with every slot inactive
-    (paged writes redirect to the trash page; the contiguous select-merge
-    discards the update), so the engine's state is untouched."""
-    from repro.obs.profiler import profile_ops
-
-    b = engine.config.slots
-    feed = jnp.zeros((b, 1), jnp.int32)
-    pos = jnp.zeros((b, 1), jnp.int32)
-    clen = jnp.zeros(b, jnp.int32)
-    active = jnp.zeros(b, bool)
-    with profile_ops() as prof:
-        fwd = (engine.params, feed, pos, clen, active, engine.pool.states)
-        if engine.paged:
-            engine.decode_fn(*fwd, engine.pool.device_table(),
-                             *engine.logit_buffers)
-        else:
-            engine.decode_fn(*fwd, *engine.logit_buffers)
-    return prof
-
-
-def _export_obs(args, target, summary, tracer, profile=None):
-    """Write the run's observability artifacts: JSONL + Chrome traces,
+def _export_obs(args, target, summary, tracer):
+    """Write the run's observability artifacts: the JSONL request log,
     the metrics JSON payload (run metadata + summary + every lane's
     registry snapshot), and the Prometheus text export."""
     if tracer is not None and args.trace_out:
         tracer.write_jsonl(args.trace_out)
-        chrome = os.path.splitext(args.trace_out)[0] + ".chrome.json"
-        tracer.write_chrome(chrome)
-        print(f"trace: {len(tracer.events)} events -> {args.trace_out} "
-              f"(Perfetto: {chrome})")
+        print(f"trace: {len(tracer.events)} events -> {args.trace_out}")
     regs = _lane_registries(target)
     if args.metrics_out:
         from repro.obs.runmeta import run_metadata
@@ -147,8 +121,6 @@ def _export_obs(args, target, summary, tracer, profile=None):
             "registries": {lane: reg.snapshot()
                            for lane, reg in sorted(regs.items())},
         }
-        if profile is not None:
-            payload["op_profile"] = profile.summary()
         os.makedirs(os.path.dirname(args.metrics_out) or ".", exist_ok=True)
         with open(args.metrics_out, "w") as f:
             json.dump(payload, f, indent=1, sort_keys=True)
@@ -204,17 +176,7 @@ def _run_fleet(args, cfg, params, router, sched_cfg, mesh, dims, max_len,
                       router=router, scheduler_config=sched_cfg, mesh=mesh,
                       tracer=tracer)
         summary = run_load(fleet, make_trace())
-
-    profile = None
-    if args.profile_ops:
-        first = fleet.replicas[0]
-        eng = first.decode_engine if hasattr(first, "engines") else first
-        with mesh:
-            profile = _profile_decode(eng)
-        print("== per-op decode profile (one eager fenced pass, "
-              "replica 0) ==")
-        print(profile.format_table())
-    _export_obs(args, fleet, summary, tracer, profile)
+    _export_obs(args, fleet, summary, tracer)
 
     mode = "disaggregated" if args.disaggregate else "replicated"
     layout = (f"paged/ps={args.page_size}" if args.page_size
@@ -394,23 +356,16 @@ def parse_args(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     # -- observability ------------------------------------------------------
     ap.add_argument("--trace-out", default=None, metavar="PATH",
-                    help="write the deterministic request trace as JSONL "
-                         "to PATH and a Perfetto/chrome://tracing view to "
-                         "PATH's stem + '.chrome.json'")
-    ap.add_argument("--trace-wall", action="store_true",
-                    help="annotate every trace event with wall-clock "
-                         "seconds (strippable; the step-keyed trace stays "
-                         "deterministic without it)")
+                    help="write the deterministic step-keyed request "
+                         "log as JSONL to PATH (the engine's host spans on "
+                         "the device clock go to a jax.profiler session's "
+                         ".xplane.pb instead)")
     ap.add_argument("--metrics-out", default=None, metavar="PATH",
                     help="write the metrics payload (run metadata, the "
                          "summary, every lane's registry snapshot) as JSON")
     ap.add_argument("--prom-out", default=None, metavar="PATH",
                     help="write every lane's registry in Prometheus text "
                          "exposition format (lane as a label)")
-    ap.add_argument("--profile-ops", action="store_true",
-                    help="after the run, time ONE eager per-op-fenced "
-                         "decode pass at the dispatch registry and print "
-                         "the per-layer breakdown (paper Table 4, live)")
     # -- autoscheduler / warm-start fleet schedule DB -----------------------
     ap.add_argument("--schedule-db", default=None, metavar="PATH",
                     help="preload a persistent tuned-schedule DB "
@@ -519,7 +474,7 @@ def _serve(args):
     mesh = make_mesh(dims, ("data", "model"))
     cfg = get_config(args.arch) if args.full_size else reduced_config(args.arch)
     params = init_serving_params(cfg, args.seed, mesh)
-    tracer = Tracer(wall=args.trace_wall) if args.trace_out else None
+    tracer = Tracer() if args.trace_out else None
     parts = serving_parts(args, cfg, params, mesh, tracer)
     build_engine, make_trace = parts.build_engine, parts.make_trace
 
@@ -531,14 +486,7 @@ def _serve(args):
     with mesh:
         engine = build_engine(args.speculate)
         summary = run_load(engine, make_trace())
-
-    profile = None
-    if args.profile_ops:
-        with mesh:
-            profile = _profile_decode(engine)
-        print("== per-op decode profile (one eager fenced pass) ==")
-        print(profile.format_table())
-    _export_obs(args, engine, summary, tracer, profile)
+    _export_obs(args, engine, summary, tracer)
 
     layout = (f"paged/ps={args.page_size}" if args.page_size else "contiguous")
     if args.prefix_sharing:

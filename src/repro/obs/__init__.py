@@ -1,22 +1,22 @@
 """Observability substrate for the PFP serving stack.
 
-Four orthogonal pieces, all pure host-side bookkeeping (nothing here ever
-touches the device path unless explicitly asked to time it):
+Three orthogonal pieces, all pure host-side bookkeeping (nothing here ever
+touches the device path):
 
   * ``registry`` — Counter/Gauge/Histogram metric families with label
     sets, a shared ``Stopwatch`` wall clock, and Prometheus text export.
     ``EngineMetrics`` and ``FleetMetrics`` are backed by one
     ``MetricsRegistry`` each instead of hand-rolled attribute bags.
-  * ``trace`` — deterministic structured request tracing: every lifecycle
-    event (submit, admit, prefill round, decode step, route, escalate,
-    spec draft/verify, COW, preempt/requeue, handoff, finish) is keyed on
-    ``(engine_step, seq)`` so two identical runs produce byte-identical
-    traces; wall-clock is an optional strippable annotation. Exports
-    JSONL and Chrome trace-event JSON (Perfetto-viewable).
-  * ``profiler`` — opt-in per-op, per-impl timing at the dispatch
-    registry (``core/dispatch.py``), block_until_ready-fenced, plus
-    tuning-cache consult/hit/miss counters: the paper's Table-4-style
-    per-layer breakdown reproduced live at serve time.
+  * ``trace`` — the engine's trace layer, two views of one set of
+    phases. A deterministic request log: every lifecycle event (submit,
+    admit, prefill round, decode step, route, escalate, spec
+    draft/verify, COW, preempt/requeue, handoff, finish) keyed on
+    ``(engine_step, seq)``, so two identical runs produce byte-identical
+    JSONL. And always-on host spans (``span``: ``pfp.step``,
+    ``pfp.prefill_round``, ``pfp.route``, ...) written by
+    ``jax.profiler.TraceAnnotation`` into a profiler session's
+    ``.xplane.pb``, on the device trace's clock, with the step's counts
+    (prompt rows, computed rows, inter-token tokens) as their args.
   * ``uncertainty`` — router-band occupancy, escalation-outcome
     counters, abstention-rate and ECE-style calibration over the MI
     stream, and a thresholded OOD alarm.
@@ -25,18 +25,16 @@ touches the device path unless explicitly asked to time it):
 (git sha, device kind, jax versions, interpret mode), a dependency-free
 JSON-schema subset validator, and a CLI used by the CI obs-smoke job.
 """
-from repro.obs.profiler import OpProfiler, profile_ops
 from repro.obs.registry import (Counter, Gauge, Histogram, MetricsRegistry,
                                 Stopwatch, percentile)
 from repro.obs.runmeta import run_metadata
-from repro.obs.trace import EVENTS, Tracer
+from repro.obs.trace import EVENTS, Tracer, span
 from repro.obs.uncertainty import UncertaintyTelemetry
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Stopwatch",
     "percentile",
-    "Tracer", "EVENTS",
-    "OpProfiler", "profile_ops",
+    "Tracer", "EVENTS", "span",
     "UncertaintyTelemetry",
     "run_metadata",
 ]

@@ -75,7 +75,6 @@ TRACE_EVENT_SCHEMA = {
         "lane": {"type": "string"},
         "event": {"type": "string", "enum": list(EVENTS)},
         "uid": {"type": "integer"},
-        "wall": {"type": "number", "minimum": 0},
     },
 }
 
@@ -111,7 +110,6 @@ METRICS_SCHEMA = {
         },
         "summary": {"type": "object"},
         "registries": {"type": "object"},
-        "op_profile": {"type": "object"},
     },
 }
 

@@ -1,10 +1,11 @@
-"""Deterministic structured request tracing.
+"""The serving stack's trace layer: a step-keyed event log and host spans
+on the profiler's clock.
 
-Every lifecycle event is a flat dict keyed on ``(step, seq)``:
+Event log. Every lifecycle event is a flat dict keyed on ``(step, seq)``:
 
   * ``step`` — the emitting engine's (or fleet frontend's) logical step
     counter at emission time. The engine clock is the ONLY time base; two
-    identical runs therefore produce byte-identical traces.
+    identical runs therefore produce byte-identical logs.
   * ``seq`` — a per-tracer monotone sequence number breaking ties within
     a step (events of one step keep emission order).
   * ``lane`` — which component emitted it ("engine", "fleet", "r0",
@@ -12,23 +13,24 @@ Every lifecycle event is a flat dict keyed on ``(step, seq)``:
     and every replica so a request's whole journey lands in one stream.
   * ``event`` — one of :data:`EVENTS`; ``uid`` where the event concerns
     one request; free-form payload fields otherwise.
-  * ``wall`` — wall-clock seconds since tracer construction, attached
-    only when the tracer was built with ``wall=True`` and ALWAYS
-    strippable (``to_jsonl(strip_wall=True)``): determinism is the
-    contract, wall time is an annotation.
 
-Exports: JSONL (one event per line, sorted keys) and Chrome trace-event
-JSON viewable in Perfetto / chrome://tracing — request lifetimes become
-complete ("X") spans on a per-lane track, point events become instants.
-The synthetic timeline maps one engine step to 1000 trace-µs so step
-structure is readable regardless of real step duration.
+Export: JSONL, one event per line with sorted keys.
+
+Spans. :func:`span` opens a ``jax.profiler.TraceAnnotation`` named
+``pfp.<name>``: the profiler's own host event, on the same clock as the
+device planes of the ``.xplane.pb`` a profiler session writes, with its
+keyword arguments as the event's stats. Spans are always on; with no
+session recording one costs about a microsecond. Where the engine also
+emits a step-keyed event for the same phase, it emits it inside the span,
+so the log and the spans share one set of boundaries.
 """
 from __future__ import annotations
 
 import json
 import os
-import time
-from typing import Dict, List, Optional
+from typing import List, Optional
+
+import jax
 
 EVENTS = (
     "submit",        # request offered to a scheduler (accepted flag)
@@ -56,11 +58,9 @@ class Tracer:
     every call site with ``if tracer is not None`` so a disabled run pays
     nothing at all."""
 
-    def __init__(self, wall: bool = False):
+    def __init__(self):
         self.events: List[dict] = []
         self._seq = 0
-        self._wall = wall
-        self._t0 = time.perf_counter() if wall else None
 
     def emit(self, lane: str, step: int, event: str,
              uid: Optional[int] = None, **fields) -> None:
@@ -71,8 +71,6 @@ class Tracer:
         if uid is not None:
             rec["uid"] = int(uid)
         rec.update(fields)
-        if self._wall:
-            rec["wall"] = time.perf_counter() - self._t0
         self._seq += 1
         self.events.append(rec)
 
@@ -83,85 +81,16 @@ class Tracer:
         return LaneTracer(self, lane)
 
     # -- export -------------------------------------------------------------
-    def to_jsonl(self, strip_wall: bool = False) -> str:
+    def to_jsonl(self) -> str:
         """One event per line, keys sorted — byte-identical across
-        identical runs once ``strip_wall`` removes the only
-        non-deterministic field."""
-        lines = []
-        for rec in self.events:
-            if strip_wall and "wall" in rec:
-                rec = {k: v for k, v in rec.items() if k != "wall"}
-            lines.append(json.dumps(rec, sort_keys=True))
+        identical runs."""
+        lines = [json.dumps(rec, sort_keys=True) for rec in self.events]
         return "\n".join(lines) + ("\n" if lines else "")
 
-    def write_jsonl(self, path: str, strip_wall: bool = False) -> None:
+    def write_jsonl(self, path: str) -> None:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         with open(path, "w") as f:
-            f.write(self.to_jsonl(strip_wall=strip_wall))
-
-    def to_chrome(self) -> dict:
-        """Chrome trace-event JSON (Perfetto-viewable).
-
-        Per-request lifetimes (admit -> finish, per lane) become complete
-        "X" spans; every other event becomes an instant. The timeline is
-        synthetic and deterministic: 1 engine step = 1000 µs, seq breaks
-        ties inside a step.
-        """
-        lanes: Dict[str, int] = {}
-
-        def pid(lane: str) -> int:
-            if lane not in lanes:
-                lanes[lane] = len(lanes) + 1
-            return lanes[lane]
-
-        def ts(rec: dict) -> int:
-            return rec["step"] * 1000 + (rec["seq"] % 1000)
-
-        out = []
-        open_spans: Dict[tuple, dict] = {}
-        for rec in self.events:
-            p = pid(rec["lane"])
-            if rec["event"] == "admit":
-                open_spans[(rec["lane"], rec.get("uid"))] = rec
-                continue
-            if rec["event"] == "finish":
-                start = open_spans.pop((rec["lane"], rec.get("uid")), None)
-                if start is not None:
-                    out.append({
-                        "name": f"req {rec.get('uid')}",
-                        "cat": "request", "ph": "X",
-                        "pid": p, "tid": rec.get("uid", 0),
-                        "ts": ts(start),
-                        "dur": max(ts(rec) - ts(start), 1),
-                        "args": {"reason": rec.get("reason"),
-                                 "tokens": rec.get("tokens")},
-                    })
-                continue
-            args = {k: v for k, v in rec.items()
-                    if k not in ("step", "seq", "lane", "event", "uid",
-                                 "wall")}
-            out.append({
-                "name": rec["event"], "cat": "engine", "ph": "i", "s": "t",
-                "pid": p, "tid": rec.get("uid", 0), "ts": ts(rec),
-                "args": args,
-            })
-        # spans never closed (still in flight when the trace was cut)
-        for (lane, uid), start in sorted(open_spans.items(),
-                                         key=lambda kv: kv[1]["seq"]):
-            out.append({
-                "name": f"req {uid}", "cat": "request", "ph": "X",
-                "pid": lanes[lane], "tid": uid or 0, "ts": ts(start),
-                "dur": 1, "args": {"reason": "unfinished"},
-            })
-        meta = [{"name": "process_name", "ph": "M", "pid": i,
-                 "args": {"name": lane}}
-                for lane, i in sorted(lanes.items(), key=lambda kv: kv[1])]
-        return {"traceEvents": meta + out, "displayTimeUnit": "ms"}
-
-    def write_chrome(self, path: str) -> None:
-        os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
-        with open(path, "w") as f:
-            json.dump(self.to_chrome(), f, sort_keys=True)
+            f.write(self.to_jsonl())
 
 
 class LaneTracer:
@@ -178,4 +107,18 @@ class LaneTracer:
         self._tracer.emit(self.lane, step, event, uid=uid, **fields)
 
 
-__all__ = ["Tracer", "LaneTracer", "EVENTS"]
+# Span names: every event phase, the whole engine step, and the host's wait
+# for the device's tokens inside a route.
+SPANS = frozenset(EVENTS) | {"step", "sync"}
+
+
+def span(name: str, **args) -> jax.profiler.TraceAnnotation:
+    """Host span ``pfp.<name>`` with ``args`` as its stats; use as a
+    context manager, and ``set_metadata(**args)`` on what it returns for
+    args known only at the span's end. ``name`` is one of :data:`SPANS`."""
+    if name not in SPANS:
+        raise ValueError(f"unknown span {name!r}")
+    return jax.profiler.TraceAnnotation("pfp." + name, **args)
+
+
+__all__ = ["Tracer", "LaneTracer", "EVENTS", "SPANS", "span"]

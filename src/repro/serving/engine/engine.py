@@ -46,7 +46,7 @@ from repro.core.gaussian import is_gaussian
 from repro.core.modes import Mode
 from repro.models import lm
 from repro.nn.module import Context
-from repro.obs.trace import Tracer
+from repro.obs.trace import Tracer, span
 from repro.serving.batcher import Request
 from repro.serving.decode import uncertainty_decode
 from repro.serving.engine.metrics import EngineMetrics
@@ -263,6 +263,9 @@ class Engine:
         self._prev_states = None
         self._admit_seq = 0
         self._step_idx = 0
+        # tokens served this step to requests that already had one (the
+        # inter-token gaps a step closes; pfp.step's itl_tokens)
+        self._itl_tokens = 0
         self._key_unc = jax.random.PRNGKey(config.seed)
         self._key_esc = jax.random.PRNGKey(config.seed + 1)
         v = cfg.vocab_size
@@ -474,12 +477,14 @@ class Engine:
 
     # -- public API ---------------------------------------------------------
     def submit(self, req: Request) -> bool:
-        ok = self.scheduler.submit(req, float(self._step_idx))
-        self.metrics.on_submit(ok)
-        if self._tracer is not None:
-            self._tracer.emit(self._step_idx, "submit", uid=req.uid,
-                              accepted=ok, prompt_len=len(req.prompt),
-                              max_new=req.max_new_tokens)
+        with span("submit", uid=req.uid) as sp:
+            ok = self.scheduler.submit(req, float(self._step_idx))
+            sp.set_metadata(accepted=int(ok))
+            self.metrics.on_submit(ok)
+            if self._tracer is not None:
+                self._tracer.emit(self._step_idx, "submit", uid=req.uid,
+                                  accepted=ok, prompt_len=len(req.prompt),
+                                  max_new=req.max_new_tokens)
         return ok
 
     def reset_metrics(self) -> None:
@@ -550,29 +555,34 @@ class Engine:
 
     # -- the engine step ----------------------------------------------------
     def step(self) -> None:
-        now = float(self._step_idx)
-        # drain deadline-expired waiters even while the pool is full, so
-        # they never hold the bounded admission queue against live traffic
-        for e in self.scheduler.drain_expired(now):
-            self.metrics.on_expire()
-            if self._tracer is not None:
-                self._tracer.emit(self._step_idx, "expire", uid=e.uid)
-            self.finished.append(e)
-        self._admit(now)
-        self._prefill()
-        self._route_and_decode(now)
-        self._step_idx += 1
-        if self.paged:
-            pages = self.pool.page_gauges()
-            if self.prefix is not None:
-                pages += (self.pool.shared_pages, self.prefix.pages_held)
-            self.metrics.on_step(self.pool.live, pages=pages)
-            if self.config.auto_defrag and self.pool.page_fragmentation():
-                self.defrag()
-        else:
-            self.metrics.on_step(self.pool.live)
-            if self.config.auto_compact and self.pool.fragmentation():
-                self.compact()
+        with span("step", step=self._step_idx) as sp:
+            self._itl_tokens = 0
+            now = float(self._step_idx)
+            # drain deadline-expired waiters even while the pool is full,
+            # so they never hold the bounded admission queue against live
+            # traffic
+            for e in self.scheduler.drain_expired(now):
+                self.metrics.on_expire()
+                if self._tracer is not None:
+                    self._tracer.emit(self._step_idx, "expire", uid=e.uid)
+                self.finished.append(e)
+            self._admit(now)
+            rounds = self._prefill()
+            self._route_and_decode(now)
+            self._step_idx += 1
+            if self.paged:
+                pages = self.pool.page_gauges()
+                if self.prefix is not None:
+                    pages += (self.pool.shared_pages, self.prefix.pages_held)
+                self.metrics.on_step(self.pool.live, pages=pages)
+                if self.config.auto_defrag and \
+                        self.pool.page_fragmentation():
+                    self.defrag()
+            else:
+                self.metrics.on_step(self.pool.live)
+                if self.config.auto_compact and self.pool.fragmentation():
+                    self.compact()
+            sp.set_metadata(rounds=rounds, itl_tokens=self._itl_tokens)
 
     def _request_tokens(self, req: Request) -> np.ndarray:
         tokens = np.asarray(req.prompt, np.int32)
@@ -621,47 +631,54 @@ class Engine:
                         and self.prefix.reclaim(self.pool, 1)):
                     continue
                 break
-            slot = self.pool.alloc(req.uid)
-            tokens = self._request_tokens(req)
-            sl = _Slot(request=req, admit_seq=self._admit_seq,
-                       prefill_tokens=tokens)
-            self._slots[slot] = sl
-            if self.prefix is not None:
-                # Map the cached prefix into this slot's table and prefill
-                # only the non-shared suffix: paged attention reads through
-                # the table indirection, so the logits are bit-for-bit a
-                # cold prefill's. The limit keeps >= 1 token to prefill
-                # (next-token logits come from feeding the last token).
-                # pop_ready's _page_need already walked the index for this
-                # request; reuse its match (nothing mutates in between).
-                if self._prefix_match is not None and \
-                        self._prefix_match[0] == req.uid:
-                    _, pages, matched = self._prefix_match
-                else:
-                    pages, matched = self.prefix.match(
-                        tokens, limit=len(tokens) - 1)
-                self._prefix_match = None
-                self.pool.share(slot, pages)
-                self.pool.positions[slot] = matched
-                sl.prefill_pos = matched
-                sl.write_start = matched
-                self.metrics.on_prefix(matched, len(pages))
-            if self._tracer is not None:
-                extra = ({"shared_pages": len(pages),
-                          "matched_tokens": matched}
-                         if self.prefix is not None else {})
-                self._tracer.emit(self._step_idx, "admit", uid=req.uid,
-                                  slot=slot, **extra)
-            if self.paged and self.config.reserve_pages:
-                # pop_ready admitted against the free-page count (prefix
-                # pages discounted), so reserving the full prompt +
-                # generation need — including the eager copy-on-write of a
-                # partially-shared boundary page — cannot fail.
-                ok = self._ensure_pages(
-                    slot, len(req.prompt) + req.max_new_tokens)
-                assert ok, "page reservation failed after admission check"
-            self._admit_seq += 1
-            self.metrics.on_admit(req.uid, req.arrival, now)
+            with span("admit", uid=req.uid) as sp:
+                sp.set_metadata(slot=self._admit_request(req, now))
+
+    def _admit_request(self, req: Request, now: float) -> int:
+        """Give a popped request a slot: prefix pages mapped, its pages
+        reserved. Returns the slot."""
+        slot = self.pool.alloc(req.uid)
+        tokens = self._request_tokens(req)
+        sl = _Slot(request=req, admit_seq=self._admit_seq,
+                   prefill_tokens=tokens)
+        self._slots[slot] = sl
+        if self.prefix is not None:
+            # Map the cached prefix into this slot's table and prefill
+            # only the non-shared suffix: paged attention reads through
+            # the table indirection, so the logits are bit-for-bit a
+            # cold prefill's. The limit keeps >= 1 token to prefill
+            # (next-token logits come from feeding the last token).
+            # pop_ready's _page_need already walked the index for this
+            # request; reuse its match (nothing mutates in between).
+            if self._prefix_match is not None and \
+                    self._prefix_match[0] == req.uid:
+                _, pages, matched = self._prefix_match
+            else:
+                pages, matched = self.prefix.match(
+                    tokens, limit=len(tokens) - 1)
+            self._prefix_match = None
+            self.pool.share(slot, pages)
+            self.pool.positions[slot] = matched
+            sl.prefill_pos = matched
+            sl.write_start = matched
+            self.metrics.on_prefix(matched, len(pages))
+        if self._tracer is not None:
+            extra = ({"shared_pages": len(pages),
+                      "matched_tokens": matched}
+                     if self.prefix is not None else {})
+            self._tracer.emit(self._step_idx, "admit", uid=req.uid,
+                              slot=slot, **extra)
+        if self.paged and self.config.reserve_pages:
+            # pop_ready admitted against the free-page count (prefix
+            # pages discounted), so reserving the full prompt +
+            # generation need — including the eager copy-on-write of a
+            # partially-shared boundary page — cannot fail.
+            ok = self._ensure_pages(
+                slot, len(req.prompt) + req.max_new_tokens)
+            assert ok, "page reservation failed after admission check"
+        self._admit_seq += 1
+        self.metrics.on_admit(req.uid, req.arrival, now)
+        return slot
 
     def _prefill_pending(self):
         pending = sorted(
@@ -670,151 +687,173 @@ class Engine:
         return [(slot, len(self._slots[slot].prefill_tokens)
                  - self._slots[slot].prefill_pos) for _, slot in pending]
 
-    def _prefill(self) -> None:
+    def _prefill(self) -> int:
+        """Run this step's prefill plan; returns the rounds (device
+        passes) it took."""
         if self.paged:
-            self._prefill_paged()
-            return
+            return self._prefill_paged()
         plan = self.scheduler.plan_prefill(self._prefill_pending())
+        c = self.scheduler.config.prefill_chunk
         for slot, n in plan:
-            sl = self._slots[slot]
-            start = sl.prefill_pos
-            end = start + n
-            prompt = sl.prefill_tokens
-            if self._static_chunks:
-                # fixed-size window ending at `end`: one compiled shape.
-                # Re-fed rows rewrite identical k/v; right-pad rows (only
-                # while end < chunk) sit beyond cache_len, so they stay
-                # masked until the decode loop overwrites them in the same
-                # step their position becomes valid.
-                c = self.scheduler.config.prefill_chunk
-                lo = max(0, end - c)
-                window = prompt[lo:end]
-                tokens = np.zeros(c, np.int32)
-                tokens[:len(window)] = window
-                positions = lo + np.arange(c, dtype=np.int32)
-                out_idx = len(window) - 1
-            else:
-                tokens = prompt[start:end]
-                positions = start + np.arange(n, dtype=np.int32)
-                out_idx = n - 1
-            inputs = {
-                "tokens": jnp.asarray(tokens)[None],
-                "positions": jnp.asarray(positions)[None],
-                "cache_len": jnp.asarray([end], jnp.int32),
-            }
-            sub = self.pool.take_slot(slot)
-            (mean, var), new_sub = self._chunk_fn(
-                self.params, inputs, sub, jnp.asarray(out_idx, jnp.int32))
-            self.pool.write_slot(slot, new_sub)
-            sl.prefill_pos += n
-            self.pool.positions[slot] = sl.prefill_pos
-            self.metrics.on_prefill(n)
-            if self._tracer is not None:
-                self._tracer.emit(self._step_idx, "prefill_round",
-                                  uid=sl.request.uid, slot=slot, tokens=n,
-                                  pos=sl.prefill_pos)
-            if sl.prefill_pos == len(prompt):
-                if sl.request.prefill_only:
-                    # disaggregation: the pages are the product — finish
-                    # without ever entering the decode phase
-                    self._finish(slot, "prefill", float(self._step_idx))
-                    continue
-                sl.phase = "decode"
-                sl.last_input = int(prompt[-1])
-                sl.replay = (sub, inputs, out_idx)
-                self._lm_mean = self._set_row(self._lm_mean, slot, mean[0])
-                self._lm_var = self._set_row(self._lm_var, slot, var[0])
+            with span("prefill_round", rows=n,
+                      width=c if self._static_chunks else n):
+                self._prefill_chunk(slot, n)
+        return len(plan)
 
-    def _prefill_paged(self) -> None:
+    def _prefill_chunk(self, slot: int, n: int) -> None:
+        """One slot's next ``n`` prompt tokens as one single-slot pass."""
+        sl = self._slots[slot]
+        start = sl.prefill_pos
+        end = start + n
+        prompt = sl.prefill_tokens
+        if self._static_chunks:
+            # fixed-size window ending at `end`: one compiled shape.
+            # Re-fed rows rewrite identical k/v; right-pad rows (only
+            # while end < chunk) sit beyond cache_len, so they stay
+            # masked until the decode loop overwrites them in the same
+            # step their position becomes valid.
+            c = self.scheduler.config.prefill_chunk
+            lo = max(0, end - c)
+            window = prompt[lo:end]
+            tokens = np.zeros(c, np.int32)
+            tokens[:len(window)] = window
+            positions = lo + np.arange(c, dtype=np.int32)
+            out_idx = len(window) - 1
+        else:
+            tokens = prompt[start:end]
+            positions = start + np.arange(n, dtype=np.int32)
+            out_idx = n - 1
+        inputs = {
+            "tokens": jnp.asarray(tokens)[None],
+            "positions": jnp.asarray(positions)[None],
+            "cache_len": jnp.asarray([end], jnp.int32),
+        }
+        sub = self.pool.take_slot(slot)
+        (mean, var), new_sub = self._chunk_fn(
+            self.params, inputs, sub, jnp.asarray(out_idx, jnp.int32))
+        self.pool.write_slot(slot, new_sub)
+        sl.prefill_pos += n
+        self.pool.positions[slot] = sl.prefill_pos
+        self.metrics.on_prefill(n)
+        if self._tracer is not None:
+            self._tracer.emit(self._step_idx, "prefill_round",
+                              uid=sl.request.uid, slot=slot, tokens=n,
+                              pos=sl.prefill_pos)
+        if sl.prefill_pos == len(prompt):
+            if sl.request.prefill_only:
+                # disaggregation: the pages are the product — finish
+                # without ever entering the decode phase
+                self._finish(slot, "prefill", float(self._step_idx))
+                return
+            sl.phase = "decode"
+            sl.last_input = int(prompt[-1])
+            sl.replay = (sub, inputs, out_idx)
+            self._lm_mean = self._set_row(self._lm_mean, slot, mean[0])
+            self._lm_var = self._set_row(self._lm_var, slot, var[0])
+
+    def _prefill_paged(self) -> int:
         """Batched multi-slot prefill over the shared page pool: every
         round of the scheduler's plan (at most one chunk per slot) runs as
         ONE lockstep pass at the full slot-batch width — a single compiled
         shape regardless of how many slots are prefilling. Unplanned rows
         carry cache_len 0, so their writes land on the trash page and
         their outputs are discarded."""
+        width = self.config.slots * self.scheduler.config.prefill_chunk
+        rounds = 0
+        for rnd in self.scheduler.plan_prefill_rounds(self._prefill_pending()):
+            with span("prefill_round") as sp:
+                rows = self._prefill_round(rnd)
+                sp.set_metadata(rows=rows, width=width if rows else 0)
+            rounds += rows > 0
+        return rounds
+
+    def _prefill_round(self, rnd) -> int:
+        """One round of the plan (``(slot, n)`` chunks) as one pass at the
+        slot-batch width; returns the prompt tokens it carried (0 = every
+        planned slot was preempted, and nothing ran)."""
         b = self.config.slots
         c = self.scheduler.config.prefill_chunk
-        for rnd in self.scheduler.plan_prefill_rounds(self._prefill_pending()):
-            tokens = np.zeros((b, c), np.int32)
-            positions = np.tile(np.arange(c, dtype=np.int32), (b, 1))
-            cache_len = np.zeros(b, np.int32)
-            write_start = np.zeros(b, np.int32)
-            out_idx = np.zeros(b, np.int32)
-            done = np.zeros(b, bool)
-            planned = []
-            for slot, n in rnd:
-                sl = self._slots[slot]
-                if sl is None or sl.phase != "prefill":
-                    continue  # preempted as a page victim in this step
-                end = sl.prefill_pos + n
-                if not self._ensure_pages(slot, end) and \
-                        not self._make_room(slot, end):
-                    # pool exhausted and nothing to preempt: bounce this
-                    # request back to the queue (it keeps its progress)
-                    self._preempt(slot)
-                    continue
-                lo = max(0, end - c)
-                window = sl.prefill_tokens[lo:end]
-                tokens[slot, :len(window)] = window
-                positions[slot] = lo + np.arange(c, dtype=np.int32)
-                cache_len[slot] = end
-                # The window may re-feed tokens below the shared-prefix
-                # boundary — their writes are redirected to the trash page
-                # (the shared pages already hold the identical rows).
-                write_start[slot] = sl.write_start
-                out_idx[slot] = len(window) - 1
-                done[slot] = end == len(sl.prefill_tokens)
-                planned.append((slot, n, end))
-            # A planned slot may have been preempted by a LATER slot's
-            # _make_room in the same round: drop it (its table row is
-            # already zeroed, so even its staged write would only reach
-            # the trash page) and keep its logit rows untouched.
-            dropped = [p for p in planned if self._slots[p[0]] is None]
-            for slot, _, _ in dropped:
-                cache_len[slot] = 0
-                done[slot] = False
-            planned = [p for p in planned if self._slots[p[0]] is not None]
-            if not planned:
+        tokens = np.zeros((b, c), np.int32)
+        positions = np.tile(np.arange(c, dtype=np.int32), (b, 1))
+        cache_len = np.zeros(b, np.int32)
+        write_start = np.zeros(b, np.int32)
+        out_idx = np.zeros(b, np.int32)
+        done = np.zeros(b, bool)
+        planned = []
+        for slot, n in rnd:
+            sl = self._slots[slot]
+            if sl is None or sl.phase != "prefill":
+                continue  # preempted as a page victim in this step
+            end = sl.prefill_pos + n
+            if not self._ensure_pages(slot, end) and \
+                    not self._make_room(slot, end):
+                # pool exhausted and nothing to preempt: bounce this
+                # request back to the queue (it keeps its progress)
+                self._preempt(slot)
                 continue
-            pre_states = self.pool.states  # escalation-replay snapshot
-            #            (copy-on-write duplicates are already in it: every
-            #            _ensure_pages above ran before this reference)
-            table = self.pool.device_table()
-            inputs = {
-                "tokens": jnp.asarray(tokens),
-                "positions": jnp.asarray(positions),
-                "cache_len": jnp.asarray(cache_len),
-                "write_start": jnp.asarray(write_start),
-                "page_table": table,
-            }
-            self._lm_mean, self._lm_var, self.pool.states = \
-                self._batch_chunk_fn(self.params, inputs, self.pool.states,
-                                     jnp.asarray(out_idx),
-                                     jnp.asarray(done),
-                                     self._lm_mean, self._lm_var)
-            if self._tracer is not None:
-                self._tracer.emit(self._step_idx, "prefill_round",
-                                  slots=len(planned),
-                                  tokens=sum(n for _, n, _ in planned))
-            for slot, n, end in planned:
-                sl = self._slots[slot]
-                sl.prefill_pos = end
-                self.pool.positions[slot] = end
-                self.metrics.on_prefill(n)
-                if done[slot]:
-                    if sl.request.prefill_only:
-                        self._finish(slot, "prefill", float(self._step_idx))
-                        continue
-                    sl.phase = "decode"
-                    sl.last_input = int(sl.prefill_tokens[-1])
-                    row = {
-                        "tokens": inputs["tokens"][slot:slot + 1],
-                        "positions": inputs["positions"][slot:slot + 1],
-                        "cache_len": inputs["cache_len"][slot:slot + 1],
-                        "write_start": inputs["write_start"][slot:slot + 1],
-                        "page_table": table[slot:slot + 1],
-                    }
-                    sl.replay = (pre_states, row, int(out_idx[slot]))
+            lo = max(0, end - c)
+            window = sl.prefill_tokens[lo:end]
+            tokens[slot, :len(window)] = window
+            positions[slot] = lo + np.arange(c, dtype=np.int32)
+            cache_len[slot] = end
+            # The window may re-feed tokens below the shared-prefix
+            # boundary — their writes are redirected to the trash page
+            # (the shared pages already hold the identical rows).
+            write_start[slot] = sl.write_start
+            out_idx[slot] = len(window) - 1
+            done[slot] = end == len(sl.prefill_tokens)
+            planned.append((slot, n, end))
+        # A planned slot may have been preempted by a LATER slot's
+        # _make_room in the same round: drop it (its table row is
+        # already zeroed, so even its staged write would only reach
+        # the trash page) and keep its logit rows untouched.
+        dropped = [p for p in planned if self._slots[p[0]] is None]
+        for slot, _, _ in dropped:
+            cache_len[slot] = 0
+            done[slot] = False
+        planned = [p for p in planned if self._slots[p[0]] is not None]
+        if not planned:
+            return 0
+        pre_states = self.pool.states  # escalation-replay snapshot
+        #            (copy-on-write duplicates are already in it: every
+        #            _ensure_pages above ran before this reference)
+        table = self.pool.device_table()
+        inputs = {
+            "tokens": jnp.asarray(tokens),
+            "positions": jnp.asarray(positions),
+            "cache_len": jnp.asarray(cache_len),
+            "write_start": jnp.asarray(write_start),
+            "page_table": table,
+        }
+        self._lm_mean, self._lm_var, self.pool.states = \
+            self._batch_chunk_fn(self.params, inputs, self.pool.states,
+                                 jnp.asarray(out_idx),
+                                 jnp.asarray(done),
+                                 self._lm_mean, self._lm_var)
+        rows = sum(n for _, n, _ in planned)
+        if self._tracer is not None:
+            self._tracer.emit(self._step_idx, "prefill_round",
+                              slots=len(planned), tokens=rows)
+        for slot, n, end in planned:
+            sl = self._slots[slot]
+            sl.prefill_pos = end
+            self.pool.positions[slot] = end
+            self.metrics.on_prefill(n)
+            if done[slot]:
+                if sl.request.prefill_only:
+                    self._finish(slot, "prefill", float(self._step_idx))
+                    continue
+                sl.phase = "decode"
+                sl.last_input = int(sl.prefill_tokens[-1])
+                row = {
+                    "tokens": inputs["tokens"][slot:slot + 1],
+                    "positions": inputs["positions"][slot:slot + 1],
+                    "cache_len": inputs["cache_len"][slot:slot + 1],
+                    "write_start": inputs["write_start"][slot:slot + 1],
+                    "page_table": table[slot:slot + 1],
+                }
+                sl.replay = (pre_states, row, int(out_idx[slot]))
+        return rows
 
     def _route_current(self, decode_slots):
         """Route every listed slot's CURRENT logits: one keyed uncertainty
@@ -828,8 +867,41 @@ class Engine:
             tok_idx[slot] = len(req.generated)
         toks, mis = self._unc(self._lm_mean, self._lm_var, self._key_unc,
                               jnp.asarray(uids), jnp.asarray(tok_idx))
-        return self._resolve_escalations(decode_slots, np.asarray(toks),
-                                         np.asarray(mis))
+        with span("sync"):
+            toks, mis = np.asarray(toks), np.asarray(mis)
+        return self._resolve_escalations(decode_slots, toks, mis)
+
+    def _serve_token(self, req: Request, tok: int, mi: float) -> None:
+        """Serve one token: a token after the request's first closes an
+        inter-token gap this step."""
+        self._itl_tokens += bool(req.generated)
+        req.generated.append(tok)
+        req.mi_trace.append(mi)
+        self.metrics.on_token()
+
+    def _serve_routed(self, slot: int, tok: int, mi: float,
+                      decision: Decision, now: float) -> bool:
+        """Apply one routed token: abstain finishes the request; otherwise
+        the token is served, and finishes it at EOS or its budget. True =
+        the slot goes on with ``tok`` as its next input."""
+        req = self._slots[slot].request
+        if self._tracer is not None:
+            self._tracer.emit(self._step_idx, "route", uid=req.uid,
+                              token=tok, mi=mi, decision=decision.value,
+                              tok_idx=len(req.generated))
+        if decision is Decision.ABSTAIN:
+            req.mi_trace.append(mi)
+            req.abstained = True
+            self._finish(slot, "abstain", now)
+            return False
+        self._serve_token(req, tok, mi)
+        if self.config.eos_id is not None and tok == self.config.eos_id:
+            self._finish(slot, "eos", now)
+        elif len(req.generated) >= req.max_new_tokens:
+            self._finish(slot, "length", now)
+        else:
+            return True
+        return False
 
     def _route_and_decode(self, now: float) -> None:
         if self.config.speculate_k:
@@ -839,38 +911,24 @@ class Engine:
                         if sl is not None and sl.phase == "decode"]
         if not decode_slots:
             return
-        resolved = self._route_current(decode_slots)
-
         feed = np.zeros(self.config.slots, np.int32)
         active = np.zeros(self.config.slots, bool)
-        for slot in decode_slots:
-            sl = self._slots[slot]
-            req = sl.request
-            tok, mi, decision = resolved[slot]
-            if self._tracer is not None:
-                self._tracer.emit(self._step_idx, "route", uid=req.uid,
-                                  token=tok, mi=mi,
-                                  decision=decision.value,
-                                  tok_idx=len(req.generated))
-            if decision is Decision.ABSTAIN:
-                req.mi_trace.append(mi)
-                req.abstained = True
-                self._finish(slot, "abstain", now)
-                continue
-            req.generated.append(tok)
-            req.mi_trace.append(mi)
-            self.metrics.on_token()
-            if self.config.eos_id is not None and tok == self.config.eos_id:
-                self._finish(slot, "eos", now)
-            elif len(req.generated) >= req.max_new_tokens:
-                self._finish(slot, "length", now)
-            else:
-                feed[slot] = tok
-                active[slot] = True
-                sl.last_input = tok
+        with span("route", slots=len(decode_slots)):
+            resolved = self._route_current(decode_slots)
+            for slot in decode_slots:
+                tok, mi, decision = resolved[slot]
+                if self._serve_routed(slot, tok, mi, decision, now):
+                    feed[slot] = tok
+                    active[slot] = True
+                    self._slots[slot].last_input = tok
+        if active.any():
+            with span("decode_step") as sp:
+                sp.set_metadata(active=self._decode(feed, active))
 
-        if not active.any():
-            return
+    def _decode(self, feed: np.ndarray, active: np.ndarray) -> int:
+        """One lockstep decode pass feeding ``feed`` to the ``active``
+        slots; returns how many it ran (fewer where page pressure
+        preempted some, 0 = no pass)."""
         if self.paged:
             # Each active slot writes one KV row at its position this
             # step: make sure the covering page exists. Under optimistic
@@ -885,7 +943,7 @@ class Engine:
                     self._preempt(slot)
             active &= np.asarray([sl is not None for sl in self._slots])
             if not active.any():
-                return
+                return 0
         positions = self.pool.positions.copy()
         self._prev_states = self.pool.states
         args = (self.params,
@@ -905,12 +963,13 @@ class Engine:
         if self.cfg.family == "moe":
             self.metrics.on_moe_drop(float(aux["moe_dropped"]),
                                      float(aux["moe_assignments"]))
+        n = int(active.sum())
         if self._tracer is not None:
-            self._tracer.emit(self._step_idx, "decode_step",
-                              active=int(active.sum()))
+            self._tracer.emit(self._step_idx, "decode_step", active=n)
         self.pool.positions[active] += 1
         for slot in np.flatnonzero(active):
             self._slots[slot].replay = None  # replay via _prev_states now
+        return n
 
     # -- uncertainty-speculative decoding ------------------------------------
     def _route_and_decode_spec(self, now: float) -> None:
@@ -939,31 +998,12 @@ class Engine:
         route_slots = [s for s in decode_slots
                        if self._slots[s].pending is None]
         if route_slots:
-            resolved = self._route_current(route_slots)
-            for slot in route_slots:
-                sl = self._slots[slot]
-                req = sl.request
-                tok, mi, decision = resolved[slot]
-                if self._tracer is not None:
-                    self._tracer.emit(self._step_idx, "route", uid=req.uid,
-                                      token=tok, mi=mi,
-                                      decision=decision.value,
-                                      tok_idx=len(req.generated))
-                if decision is Decision.ABSTAIN:
-                    req.mi_trace.append(mi)
-                    req.abstained = True
-                    self._finish(slot, "abstain", now)
-                    continue
-                req.generated.append(tok)
-                req.mi_trace.append(mi)
-                self.metrics.on_token()
-                if self.config.eos_id is not None and \
-                        tok == self.config.eos_id:
-                    self._finish(slot, "eos", now)
-                elif len(req.generated) >= req.max_new_tokens:
-                    self._finish(slot, "length", now)
-                else:
-                    sl.pending = tok
+            with span("route", slots=len(route_slots)):
+                resolved = self._route_current(route_slots)
+                for slot in route_slots:
+                    tok, mi, decision = resolved[slot]
+                    if self._serve_routed(slot, tok, mi, decision, now):
+                        self._slots[slot].pending = tok
         spec_slots = [s for s in decode_slots
                       if self._slots[s] is not None
                       and self._slots[s].pending is not None]
@@ -973,167 +1013,176 @@ class Engine:
     def _speculative_round(self, spec_slots, now: float) -> None:
         """Draft K-1 tokens per pending slot, verify the K-token block
         with one chunked PFP pass, accept greedily."""
-        k = self.config.speculate_k
-        b = self.config.slots
-        # Per-slot block width: the pending head plus up to K-1 drafts,
-        # clipped so a fully-accepted block lands exactly on the request's
-        # generation budget (fed positions then never pass max_len - 1).
-        f_of = {}
-        for slot in list(spec_slots):
-            sl = self._slots[slot]
-            if sl is None:
-                continue  # preempted as a page victim below
-            req = sl.request
-            # generation budget left (>= 1: a slot at its budget finished
-            # in phase 0); a fully-accepted block lands exactly on it
-            f = min(k, req.max_new_tokens - len(req.generated))
-            pos = int(self.pool.positions[slot])
-            if not self._ensure_pages(slot, pos + f) and \
-                    not self._make_room(slot, pos + f):
-                self._preempt(slot)
-                continue
-            f_of[slot] = f
-        live = [s for s in spec_slots
-                if self._slots[s] is not None and s in f_of]
-        if not live:
-            return
+        with span("decode_step") as sp:
+            k = self.config.speculate_k
+            b = self.config.slots
+            # Per-slot block width: the pending head plus up to K-1
+            # drafts, clipped so a fully-accepted block lands exactly on
+            # the request's generation budget (fed positions then never
+            # pass max_len - 1).
+            f_of = {}
+            for slot in list(spec_slots):
+                sl = self._slots[slot]
+                if sl is None:
+                    continue  # preempted as a page victim below
+                req = sl.request
+                # generation budget left (>= 1: a slot at its budget
+                # finished in phase 0); a fully-accepted block lands
+                # exactly on it
+                f = min(k, req.max_new_tokens - len(req.generated))
+                pos = int(self.pool.positions[slot])
+                if not self._ensure_pages(slot, pos + f) and \
+                        not self._make_room(slot, pos + f):
+                    self._preempt(slot)
+                    continue
+                f_of[slot] = f
+            live = [s for s in spec_slots
+                    if self._slots[s] is not None and s in f_of]
+            if not live:
+                sp.set_metadata(active=0)
+                return
 
-        head = np.zeros(b, np.int32)
-        pos0 = np.zeros(b, np.int32)
-        for slot in live:
-            head[slot] = self._slots[slot].pending
-            pos0[slot] = self.pool.positions[slot]
-        table = self.pool.device_table()
-        drafts = np.zeros((b, max(k - 1, 0)), np.int32)
-        if k > 1:
-            drafts = np.asarray(self._draft_fn(
-                self.params, jnp.asarray(head), jnp.asarray(pos0),
-                self.pool.states, table)).T          # (K-1, B) -> (B, K-1)
-            self.metrics.on_draft_pass(k - 1)
-            if self._tracer is not None:
-                self._tracer.emit(self._step_idx, "spec_draft",
-                                  slots=len(live), drafted=k - 1)
-        if self._draft_override is not None:
-            drafts = self._draft_override(drafts)
-
-        # ONE chunked PFP verify pass over every block. Pad rows (beyond a
-        # slot's width, or whole rows for slots not speculating) carry
-        # positions at/past cache_len or an all-trash table row, so the
-        # pool is only written through live blocks' own pages.
-        tokens = np.zeros((b, k), np.int32)
-        positions = np.tile(np.arange(k, dtype=np.int32), (b, 1))
-        cache_len = np.ones(b, np.int32)
-        write_start = np.zeros(b, np.int32)
-        vtable = np.zeros_like(self.pool.page_table)
-        uids = np.zeros(b, np.int32)
-        tok0 = np.zeros(b, np.int32)
-        fed_of = {}
-        for slot in live:
-            sl = self._slots[slot]
-            f = f_of[slot]
-            fed = [int(head[slot])] + [int(t) for t in drafts[slot, :f - 1]]
-            fed_of[slot] = fed
-            tokens[slot, :f] = fed
-            positions[slot] = pos0[slot] + np.arange(k, dtype=np.int32)
-            cache_len[slot] = pos0[slot] + f
-            write_start[slot] = sl.write_start
-            vtable[slot] = self.pool.page_table[slot]
-            uids[slot] = sl.request.uid & 0x7FFFFFFF
-            tok0[slot] = len(sl.request.generated)
-        inputs = {
-            "tokens": jnp.asarray(tokens),
-            "positions": jnp.asarray(positions),
-            "cache_len": jnp.asarray(cache_len),
-            "write_start": jnp.asarray(write_start),
-            "page_table": jnp.asarray(vtable, jnp.int32),
-        }
-        vmean, vvar, self.pool.states = self._verify_fn(
-            self.params, inputs, self.pool.states)
-        self.metrics.on_verify_pass()
-        btoks, bmis = self._unc_block(vmean, vvar, self._key_unc,
-                                      jnp.asarray(uids), jnp.asarray(tok0))
-        tok_np = np.asarray(btoks)
-        mi_np = np.asarray(bmis)
-
-        drafted_total = accepted_total = 0
-        for slot in live:
-            sl = self._slots[slot]
-            req = sl.request
-            f = f_of[slot]
-            fed = fed_of[slot]
-            a = f                    # fed rows that stay valid
-            finish_reason = None
-            defer_row = None
-            for i in range(f):
-                mi = float(mi_np[slot, i])
-                tok = int(tok_np[slot, i])
-                decision = self.router.route(mi)
-                if decision is not Decision.ESCALATE:
-                    # An ESCALATE row is NOT counted here: it re-routes
-                    # next step in phase 0 under the same (uid, token)
-                    # key, and _resolve_escalations counts it there —
-                    # band totals match the plain engine's exactly.
-                    self.metrics.on_decision(mi, decision.value)
+            head = np.zeros(b, np.int32)
+            pos0 = np.zeros(b, np.int32)
+            for slot in live:
+                head[slot] = self._slots[slot].pending
+                pos0[slot] = self.pool.positions[slot]
+            table = self.pool.device_table()
+            drafts = np.zeros((b, max(k - 1, 0)), np.int32)
+            if k > 1:
+                drafts = np.asarray(self._draft_fn(
+                    self.params, jnp.asarray(head), jnp.asarray(pos0),
+                    self.pool.states, table)).T      # (K-1, B) -> (B, K-1)
+                self.metrics.on_draft_pass(k - 1)
                 if self._tracer is not None:
-                    self._tracer.emit(self._step_idx, "route",
-                                      uid=req.uid, token=tok, mi=mi,
-                                      decision=decision.value,
-                                      tok_idx=len(req.generated),
-                                      speculative=True)
-                if decision is Decision.ESCALATE:
-                    # Stop UNSERVED: row i's logits become the slot's
-                    # current logits and next step's phase 0 — same
-                    # (uid, token) key, same MI — escalates them into
-                    # that step's single batched SVI pass.
+                    self._tracer.emit(self._step_idx, "spec_draft",
+                                      slots=len(live), drafted=k - 1)
+            if self._draft_override is not None:
+                drafts = self._draft_override(drafts)
+
+            # ONE chunked PFP verify pass over every block. Pad rows
+            # (beyond a slot's width, or whole rows for slots not
+            # speculating) carry positions at/past cache_len or an
+            # all-trash table row, so the pool is only written through
+            # live blocks' own pages.
+            tokens = np.zeros((b, k), np.int32)
+            positions = np.tile(np.arange(k, dtype=np.int32), (b, 1))
+            cache_len = np.ones(b, np.int32)
+            write_start = np.zeros(b, np.int32)
+            vtable = np.zeros_like(self.pool.page_table)
+            uids = np.zeros(b, np.int32)
+            tok0 = np.zeros(b, np.int32)
+            fed_of = {}
+            for slot in live:
+                sl = self._slots[slot]
+                f = f_of[slot]
+                fed = [int(head[slot])] + [int(t)
+                                           for t in drafts[slot, :f - 1]]
+                fed_of[slot] = fed
+                tokens[slot, :f] = fed
+                positions[slot] = pos0[slot] + np.arange(k, dtype=np.int32)
+                cache_len[slot] = pos0[slot] + f
+                write_start[slot] = sl.write_start
+                vtable[slot] = self.pool.page_table[slot]
+                uids[slot] = sl.request.uid & 0x7FFFFFFF
+                tok0[slot] = len(sl.request.generated)
+            inputs = {
+                "tokens": jnp.asarray(tokens),
+                "positions": jnp.asarray(positions),
+                "cache_len": jnp.asarray(cache_len),
+                "write_start": jnp.asarray(write_start),
+                "page_table": jnp.asarray(vtable, jnp.int32),
+            }
+            vmean, vvar, self.pool.states = self._verify_fn(
+                self.params, inputs, self.pool.states)
+            self.metrics.on_verify_pass()
+            sp.set_metadata(active=len(live))
+        with span("route", slots=len(live)):
+            btoks, bmis = self._unc_block(vmean, vvar, self._key_unc,
+                                          jnp.asarray(uids), jnp.asarray(tok0))
+            with span("sync"):
+                tok_np = np.asarray(btoks)
+                mi_np = np.asarray(bmis)
+
+            drafted_total = accepted_total = 0
+            for slot in live:
+                sl = self._slots[slot]
+                req = sl.request
+                f = f_of[slot]
+                fed = fed_of[slot]
+                a = f                    # fed rows that stay valid
+                finish_reason = None
+                defer_row = None
+                for i in range(f):
+                    mi = float(mi_np[slot, i])
+                    tok = int(tok_np[slot, i])
+                    decision = self.router.route(mi)
+                    if decision is not Decision.ESCALATE:
+                        # An ESCALATE row is NOT counted here: it
+                        # re-routes next step in phase 0 under the same
+                        # (uid, token) key, and _resolve_escalations
+                        # counts it there — band totals match the plain
+                        # engine's exactly.
+                        self.metrics.on_decision(mi, decision.value)
+                    if self._tracer is not None:
+                        self._tracer.emit(self._step_idx, "route",
+                                          uid=req.uid, token=tok, mi=mi,
+                                          decision=decision.value,
+                                          tok_idx=len(req.generated),
+                                          speculative=True)
+                    if decision is Decision.ESCALATE:
+                        # Stop UNSERVED: row i's logits become the slot's
+                        # current logits and next step's phase 0 — same
+                        # (uid, token) key, same MI — escalates them into
+                        # that step's single batched SVI pass.
+                        a = i + 1
+                        sl.pending = None
+                        sl.last_input = fed[i]
+                        defer_row = i
+                        break
+                    if decision is Decision.ABSTAIN:
+                        req.mi_trace.append(mi)
+                        req.abstained = True
+                        a = i + 1
+                        finish_reason = "abstain"
+                        break
+                    self._serve_token(req, tok, mi)
+                    if self.config.eos_id is not None and \
+                            tok == self.config.eos_id:
+                        a = i + 1
+                        finish_reason = "eos"
+                        break
+                    if len(req.generated) >= req.max_new_tokens:
+                        a = i + 1
+                        finish_reason = "length"
+                        break
+                    if i + 1 < f and tok == fed[i + 1]:
+                        continue     # draft confirmed; row i+1 stays valid
+                    # Draft mismatch (or block exhausted): the verified
+                    # token is served but unfed — it heads the next block.
+                    # Rows past i are stale; they sit masked past
+                    # ``positions`` until re-fed (rollback-to-trash, no
+                    # device work).
                     a = i + 1
-                    sl.pending = None
+                    sl.pending = tok
                     sl.last_input = fed[i]
-                    defer_row = i
                     break
-                if decision is Decision.ABSTAIN:
-                    req.mi_trace.append(mi)
-                    req.abstained = True
-                    a = i + 1
-                    finish_reason = "abstain"
-                    break
-                req.generated.append(tok)
-                req.mi_trace.append(mi)
-                self.metrics.on_token()
-                if self.config.eos_id is not None and \
-                        tok == self.config.eos_id:
-                    a = i + 1
-                    finish_reason = "eos"
-                    break
-                if len(req.generated) >= req.max_new_tokens:
-                    a = i + 1
-                    finish_reason = "length"
-                    break
-                if i + 1 < f and tok == fed[i + 1]:
-                    continue         # draft confirmed; row i+1 stays valid
-                # Draft mismatch (or block exhausted): the verified token
-                # is served but unfed — it heads the next block. Rows past
-                # i are stale; they sit masked past ``positions`` until
-                # re-fed (rollback-to-trash, no device work).
-                a = i + 1
-                sl.pending = tok
-                sl.last_input = fed[i]
-                break
-            drafted_total += f - 1
-            accepted_total += a - 1
-            self.pool.positions[slot] = int(pos0[slot]) + a
-            sl.replay = None
-            if defer_row is not None:
-                self._lm_mean = self._set_row(self._lm_mean, slot,
-                                              vmean[slot, defer_row])
-                self._lm_var = self._set_row(self._lm_var, slot,
-                                             vvar[slot, defer_row])
-            if finish_reason is not None:
-                self._finish(slot, finish_reason, now)
-        self.metrics.on_spec_round(drafted_total, accepted_total)
-        if self._tracer is not None:
-            self._tracer.emit(self._step_idx, "spec_verify",
-                              slots=len(live), drafted=drafted_total,
-                              accepted=accepted_total)
+                drafted_total += f - 1
+                accepted_total += a - 1
+                self.pool.positions[slot] = int(pos0[slot]) + a
+                sl.replay = None
+                if defer_row is not None:
+                    self._lm_mean = self._set_row(self._lm_mean, slot,
+                                                  vmean[slot, defer_row])
+                    self._lm_var = self._set_row(self._lm_var, slot,
+                                                 vvar[slot, defer_row])
+                if finish_reason is not None:
+                    self._finish(slot, finish_reason, now)
+            self.metrics.on_spec_round(drafted_total, accepted_total)
+            if self._tracer is not None:
+                self._tracer.emit(self._step_idx, "spec_verify",
+                                  slots=len(live), drafted=drafted_total,
+                                  accepted=accepted_total)
 
     # -- escalation ----------------------------------------------------------
     def _resolve_escalations(self, decode_slots, tok_np, mi_np):
@@ -1153,7 +1202,9 @@ class Engine:
                 esc.append(slot)
             else:
                 out[slot] = (tok, mi, decision)
-        if esc:
+        if not esc:
+            return out
+        with span("escalate", slots=len(esc)):
             if self.paged and self.config.batch_escalations:
                 out.update(self._escalate_batched(esc, tok_np, mi_np))
             else:
@@ -1419,14 +1470,16 @@ class Engine:
         if not self.paged:
             raise ValueError("defrag() applies to the paged engine; the "
                              "contiguous engine compacts slots instead")
-        perm = self.pool.defrag()
-        if perm is None:
-            return
-        self.metrics.on_defrag()
-        if self._tracer is not None:
-            self._tracer.emit(self._step_idx, "defrag")
-        if self._prev_states is not None:
-            self._prev_states = lm.take_decode_slots(self._prev_states, perm)
+        with span("defrag"):
+            perm = self.pool.defrag()
+            if perm is None:
+                return
+            self.metrics.on_defrag()
+            if self._tracer is not None:
+                self._tracer.emit(self._step_idx, "defrag")
+            if self._prev_states is not None:
+                self._prev_states = lm.take_decode_slots(self._prev_states,
+                                                         perm)
 
     def compact(self) -> None:
         """Pack live slots to the front; remap host-side slot records and
@@ -1434,18 +1487,20 @@ class Engine:
         if self.paged:
             raise ValueError("the paged engine has no slot compaction "
                              "(slots are just batch rows); use defrag()")
-        remap = self.pool.compact()
-        if not remap:
-            return
-        new_slots: List[Optional[_Slot]] = [None] * self.config.slots
-        perm = np.arange(self.config.slots)
-        for old, new in remap.items():
-            new_slots[new] = self._slots[old]
-            perm[new] = old
-        self._slots = new_slots
-        self._lm_mean = self._lm_mean[jnp.asarray(perm)]
-        self._lm_var = self._lm_var[jnp.asarray(perm)]
-        if self._prev_states is not None:
-            # keep the escalation-replay snapshot slot-aligned (free rows
-            # may duplicate — replay only ever reads live slots)
-            self._prev_states = lm.take_decode_slots(self._prev_states, perm)
+        with span("defrag"):
+            remap = self.pool.compact()
+            if not remap:
+                return
+            new_slots: List[Optional[_Slot]] = [None] * self.config.slots
+            perm = np.arange(self.config.slots)
+            for old, new in remap.items():
+                new_slots[new] = self._slots[old]
+                perm[new] = old
+            self._slots = new_slots
+            self._lm_mean = self._lm_mean[jnp.asarray(perm)]
+            self._lm_var = self._lm_var[jnp.asarray(perm)]
+            if self._prev_states is not None:
+                # keep the escalation-replay snapshot slot-aligned (free
+                # rows may duplicate — replay only ever reads live slots)
+                self._prev_states = lm.take_decode_slots(self._prev_states,
+                                                         perm)

@@ -498,7 +498,11 @@ class PagedDecodeStatePool:
         if slots is not None:
             return jnp.asarray(self.page_table[slots], jnp.int32)
         if self._device_table is None:
-            self._device_table = jnp.asarray(self.page_table, jnp.int32)
+            # A copy: on the CPU backend jnp.asarray may alias the numpy
+            # buffer, which the host edits in place while programs that
+            # read this table can still be in flight.
+            self._device_table = jnp.array(self.page_table, jnp.int32,
+                                           copy=True)
         return self._device_table
 
     def check_invariants(self) -> None:

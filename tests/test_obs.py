@@ -3,18 +3,21 @@
 Covers: the unified metrics registry (percentile edge cases, Prometheus
 histogram bucket-boundary semantics, deterministic snapshots, text-
 export round-trip through our own parser), deterministic request tracing
-(byte-identical traces across identical fleet runs, wall-clock
-strippability, Chrome export), the dispatch-registry op profiler
-(Table-4-style rows, profiler uninstalled on context exit), uncertainty
-telemetry (band occupancy, OOD alarms, escalation outcomes, ECE), the
-export schemas, and two regressions on the re-plumbed engine/fleet
-metrics: the summary() key set is stable, and a fleet's pooled
-throughput is exactly the sum of its per-replica throughputs (shared
-Stopwatch).
+(byte-identical traces across identical fleet runs), the engine's host
+spans on the profiler's clock (read back from a CPU profiler session's
+``.xplane.pb``: the span tree, args equal to what the engine did, the
+programs' names the benchmark matches, outputs untouched by a recording
+session), uncertainty telemetry (band occupancy, OOD alarms, escalation
+outcomes, ECE), the export schemas, and two regressions on the
+re-plumbed engine/fleet metrics: the summary() key set is stable, and a
+fleet's pooled throughput is exactly the sum of its per-replica
+throughputs (shared Stopwatch).
 """
 import dataclasses
+import glob
 import json
 import math
+import re
 
 import jax
 import numpy as np
@@ -28,7 +31,7 @@ from repro.obs.registry import (Counter, Gauge, Histogram, MetricsRegistry,
 from repro.obs.runmeta import run_metadata
 from repro.obs.schema import (METRICS_SCHEMA, TRACE_EVENT_SCHEMA,
                               validate, validate_metrics_payload)
-from repro.obs.trace import EVENTS, Tracer
+from repro.obs.trace import EVENTS, SPANS, Tracer, span
 from repro.obs.uncertainty import UncertaintyTelemetry
 from repro.serving.batcher import Request
 from repro.serving.engine import (Engine, EngineConfig, RequestScheduler,
@@ -47,14 +50,15 @@ def lm_setup():
 
 
 def _engine(cfg, params, *, tracer=None, page_size=None, prefix_sharing=False,
-            mi_continue=1e9, mi_abstain=2e9):
+            mi_continue=1e9, mi_abstain=2e9, speculate_k=0):
     router = UncertaintyRouter(cfg, RouterConfig(mi_continue=mi_continue,
                                                  mi_abstain=mi_abstain))
     return Engine(cfg, params,
                   EngineConfig(slots=3, max_len=MAX_LEN,
                                num_uncertainty_samples=8, seed=0,
                                page_size=page_size,
-                               prefix_sharing=prefix_sharing),
+                               prefix_sharing=prefix_sharing,
+                               speculate_k=speculate_k),
                   router=router,
                   scheduler=RequestScheduler(
                       SchedulerConfig(prefill_chunk=3, prefill_budget=6),
@@ -250,62 +254,229 @@ class TestTracer:
         for line in a.to_jsonl().splitlines():
             assert validate(json.loads(line), TRACE_EVENT_SCHEMA) == []
 
-    def test_wall_clock_is_strippable(self):
-        t = Tracer(wall=True)
-        t.emit("engine", 0, "decode_step", active=2)
-        assert "wall" in t.events[0]
-        rec = json.loads(t.to_jsonl(strip_wall=True))
-        assert "wall" not in rec
-        plain = Tracer()
-        plain.emit("engine", 0, "decode_step", active=2)
-        assert t.to_jsonl(strip_wall=True) == plain.to_jsonl()
-
-    def test_chrome_export_spans_and_lanes(self):
-        t = Tracer()
-        t.emit("r0", 0, "admit", uid=7)
-        t.emit("r0", 2, "finish", uid=7, reason="length", tokens=2)
-        t.emit("r1", 1, "defrag", moved=3)
-        out = t.to_chrome()
-        spans = [e for e in out["traceEvents"] if e.get("ph") == "X"]
-        # 1 step = 1000 trace-µs; seq breaks ties inside a step, so the
-        # admit(step 0, seq 0) -> finish(step 2, seq 1) span is 2001 µs
-        assert len(spans) == 1 and spans[0]["dur"] == 2001
-        names = {e["args"]["name"] for e in out["traceEvents"]
-                 if e.get("ph") == "M"}
-        assert names == {"r0", "r1"}
-
 
 # ---------------------------------------------------------------------------
-# op profiler
+# host spans on the profiler's clock
 # ---------------------------------------------------------------------------
-class TestProfiler:
-    def test_profiled_forward_produces_table4_rows(self):
-        from repro.core import dispatch
-        from repro.models.simple import mlp_forward, mlp_init
-        from repro.nn.module import Context, Mode
-        from repro.obs.profiler import profile_ops
+def _profiled(tmp, fn):
+    """fn() inside a CPU profiler session; returns its result and the
+    session's ``pfp.*`` host spans as dicts (name without the prefix,
+    t0, t1 in ns on the trace's clock, args), ordered by start."""
+    from jax.profiler import ProfileData
 
-        params = svi_to_pfp(mlp_init(jax.random.PRNGKey(0), d_hidden=8))
-        x = jax.random.normal(jax.random.PRNGKey(1), (2, 784))
-        ctx = Context(mode=Mode.PFP, impl="xla")
-        with profile_ops() as prof:
-            assert dispatch.get_profiler() is prof
-            mlp_forward(params, x, ctx)
-        rows = prof.table()
-        assert rows and {r["op"] for r in rows} >= {"dense"}
-        assert sum(r["frac"] for r in rows) == pytest.approx(1.0)
-        assert all(r["calls"] >= 1 and r["total_s"] >= 0 for r in rows)
-        # uninstalled on exit: a later forward is not profiled
-        assert dispatch.get_profiler() is None
-        n = len(prof.table())
-        mlp_forward(params, x, ctx)
-        assert len(prof.table()) == n
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp), profiler_options=opts)
+    try:
+        out = fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{tmp}/**/*.xplane.pb", recursive=True)
+    spans = [{"name": e.name[len("pfp."):], "t0": e.start_ns,
+              "t1": e.start_ns + e.duration_ns, "args": dict(e.stats)}
+             for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("pfp.")]
+    return out, sorted(spans, key=lambda sp: (sp["t0"], -sp["t1"]))
 
-    def test_summary_shape(self):
-        from repro.obs.profiler import OpProfiler
-        s = OpProfiler().summary()
-        assert set(s) >= {"total_s", "rows", "cache_consults", "cache_hits",
-                          "cache_misses", "cache_by_op"}
+
+def _named(spans, name):
+    return [sp for sp in spans if sp["name"] == name]
+
+
+def _within(inner, outer):
+    return outer["t0"] <= inner["t0"] and inner["t1"] <= outer["t1"]
+
+
+def _parent(sp, spans, name):
+    """The one ``name`` span holding ``sp``."""
+    holders = [o for o in _named(spans, name) if o is not sp
+               and _within(sp, o)]
+    assert len(holders) == 1, (sp, holders)
+    return holders[0]
+
+
+def _outputs(eng):
+    return {r.uid: (list(r.generated), [float(m) for m in r.mi_trace])
+            for r in eng.finished}
+
+
+def _serve(cfg, params, reqs, **kw):
+    eng = _engine(cfg, params, **kw)
+    return eng, run_load(eng, reqs)
+
+
+def _itl_tokens(eng):
+    """Inter-token gaps the run closed: every served token but each
+    request's first."""
+    return sum(max(len(r.generated) - 1, 0) for r in eng.finished)
+
+
+@pytest.fixture(scope="module")
+def paged_spans(lm_setup, tmp_path_factory):
+    """A tiny paged engine run under a profiler session: four requests
+    due at once on three slots, after an empty prompt that is refused."""
+    cfg, params = lm_setup
+    reqs = _trace_reqs()
+    for r in reqs:
+        r.arrival = 0.0
+
+    def run():
+        eng = _engine(cfg, params, page_size=4)
+        refused = eng.submit(Request(uid=99, prompt=np.zeros(0, np.int32),
+                                     max_new_tokens=2))
+        return eng, refused, run_load(eng, reqs)
+
+    (eng, refused, s), spans = _profiled(tmp_path_factory.mktemp("paged"),
+                                          run)
+    assert not refused
+    return eng, s, spans
+
+
+class TestSpans:
+    def test_unknown_span_refused(self):
+        with pytest.raises(ValueError):
+            span("nope")
+        assert SPANS == set(EVENTS) | {"step", "sync"}
+        with span("step", step=0) as sp:  # no session: a no-op span
+            sp.set_metadata(rounds=0, itl_tokens=0)
+
+    def test_step_spans_nest_route_and_sync(self, paged_spans):
+        eng, s, spans = paged_spans
+        steps = _named(spans, "step")
+        assert [sp["args"]["step"] for sp in steps] == list(range(s["steps"]))
+        routes = _named(spans, "route")
+        assert routes and len(_named(spans, "sync")) == len(routes)
+        for sync in _named(spans, "sync"):
+            _parent(_parent(sync, spans, "route"), spans, "step")
+        for name in ("admit", "prefill_round", "decode_step"):
+            for sp in _named(spans, name):
+                _parent(sp, spans, "step")
+        assert len(_named(spans, "decode_step")) == s["decode_passes"]
+
+    def test_prefill_round_rows_and_width(self, paged_spans):
+        eng, s, spans = paged_spans
+        rounds = _named(spans, "prefill_round")
+        # every round computes the whole slot batch at the chunk width
+        # (3 slots x 3 tokens) and carries at most one chunk per slot
+        assert rounds and all(r["args"]["width"] == 9 for r in rounds)
+        assert all(0 < r["args"]["rows"] <= 9 for r in rounds)
+        assert sum(r["args"]["rows"] for r in rounds) == s["prefill_tokens"]
+        for step in _named(spans, "step"):
+            inside = [r for r in rounds if _within(r, step)]
+            assert step["args"]["rounds"] == len(inside)
+        assert any(st["args"]["rounds"] == 0
+                   for st in _named(spans, "step"))
+
+    def test_itl_tokens_count_inter_token_gaps(self, paged_spans):
+        eng, s, spans = paged_spans
+        steps = _named(spans, "step")
+        assert sum(st["args"]["itl_tokens"] for st in steps) == \
+            _itl_tokens(eng) == s["tokens_generated"] - 4
+        # a step that prefilled and a step that only decoded both closed
+        # gaps: the share the benchmark reads has both sides
+        assert any(st["args"]["rounds"] and st["args"]["itl_tokens"]
+                   for st in steps)
+        assert any(not st["args"]["rounds"] and st["args"]["itl_tokens"]
+                   for st in steps)
+
+    def test_submit_and_admit_spans_per_request(self, paged_spans):
+        eng, s, spans = paged_spans
+        subs = {sp["args"]["uid"]: sp for sp in _named(spans, "submit")}
+        admits = {sp["args"]["uid"]: sp for sp in _named(spans, "admit")}
+        assert subs[99]["args"]["accepted"] == 0 and 99 not in admits
+        assert set(admits) == {0, 1, 2, 3}
+        for uid, adm in admits.items():
+            assert subs[uid]["args"]["accepted"] == 1
+            assert subs[uid]["t1"] <= adm["t0"]
+            assert 0 <= adm["args"]["slot"] < 3
+        # four requests due at once on three slots: request 3 waits in
+        # the queue until a slot frees, steps after the others' admission
+        slots = [admits[u]["args"]["slot"] for u in range(4)]
+        assert len(set(slots[:3])) == 3 and slots[3] in slots[:3]
+        first = _parent(admits[0], spans, "step")["args"]["step"]
+        assert all(_parent(admits[u], spans, "step")["args"]["step"]
+                   == first for u in range(3))
+        assert _parent(admits[3], spans, "step")["args"]["step"] > first
+
+    def test_contiguous_prefill_rounds(self, lm_setup, tmp_path):
+        cfg, params = lm_setup
+        (eng, s), spans = _profiled(
+            tmp_path, lambda: _serve(cfg, params, _trace_reqs()))
+        rounds = _named(spans, "prefill_round")
+        # one single-slot pass a chunk, at the static chunk width
+        assert rounds and all(r["args"]["width"] == 3 for r in rounds)
+        assert sum(r["args"]["rows"] for r in rounds) == s["prefill_tokens"]
+        assert sum(st["args"]["rounds"] for st in _named(spans, "step")) \
+            == len(rounds)
+        assert sum(st["args"]["itl_tokens"]
+                   for st in _named(spans, "step")) == _itl_tokens(eng)
+        for sync in _named(spans, "sync"):
+            _parent(sync, spans, "route")
+
+    def test_escalate_spans_inside_route(self, lm_setup, tmp_path):
+        cfg, params = lm_setup
+        (eng, s), spans = _profiled(tmp_path, lambda: _serve(
+            cfg, params, _trace_reqs(n=2), page_size=4, mi_continue=-1.0))
+        esc = _named(spans, "escalate")
+        assert esc and s["escalations"] == s["tokens_generated"]
+        assert sum(e["args"]["slots"] for e in esc) == s["escalations"]
+        for e in esc:
+            _parent(e, spans, "route")
+
+    def test_speculative_route_and_defrag(self, lm_setup, tmp_path):
+        cfg, params = lm_setup
+
+        def run():
+            eng, s = _serve(cfg, params, _trace_reqs(n=2), page_size=4,
+                            speculate_k=2)
+            eng.defrag()
+            return eng, s
+
+        (eng, s), spans = _profiled(tmp_path, run)
+        assert s["verify_passes"] > 0
+        assert len(_named(spans, "decode_step")) >= s["verify_passes"]
+        for sync in _named(spans, "sync"):
+            _parent(_parent(sync, spans, "route"), spans, "step")
+        assert sum(st["args"]["itl_tokens"]
+                   for st in _named(spans, "step")) == _itl_tokens(eng)
+        defrag, = _named(spans, "defrag")
+        assert all(not _within(defrag, st) for st in _named(spans, "step"))
+
+    def test_outputs_identical_under_a_recording_session(self, lm_setup,
+                                                         tmp_path):
+        cfg, params = lm_setup
+        eng_off, _ = _serve(cfg, params, _trace_reqs(), page_size=4)
+        (eng_on, _), spans = _profiled(tmp_path, lambda: _serve(
+            cfg, params, _trace_reqs(), page_size=4))
+        assert spans and _outputs(eng_on) == _outputs(eng_off)
+
+    def test_step_programs_match_the_benchmark_pattern(self, lm_setup):
+        """``bench/metrics/step_mfu.py`` finds the prefill and decode
+        programs in the device trace by name: the engine's programs must
+        keep names it matches, and the sampling pass a name it does not."""
+        from bench import spec
+
+        programs = re.compile(spec.load_module("metrics",
+                                               "step_mfu").PROGRAMS)
+        cfg, params = lm_setup
+        eng = _engine(cfg, params, page_size=4)
+        calls = {}
+        for attr in ("_batch_chunk_fn", "_decode_fn", "_unc"):
+            fn = getattr(eng, attr)
+
+            def record(*args, _fn=fn, _attr=attr):
+                calls[_attr] = (_fn, args)
+                return _fn(*args)
+
+            setattr(eng, attr, record)
+        run_load(eng, _trace_reqs(n=1))
+        names = {attr: re.search(r"module @(\S+)",
+                                 fn.lower(*args).as_text()).group(1)
+                 for attr, (fn, args) in calls.items()}
+        assert programs.search(names["_batch_chunk_fn"]), names
+        assert programs.search(names["_decode_fn"]), names
+        assert not programs.search(names["_unc"]), names
 
 
 # ---------------------------------------------------------------------------
