@@ -37,7 +37,8 @@ from repro.kernels.pfp_moe import (pfp_dense_batched_pallas,
 from repro.kernels.pfp_maxpool import pfp_maxpool2d_pallas
 from repro.kernels.pfp_norms import pfp_layernorm_pallas, pfp_rmsnorm_pallas
 from repro.tuning.schedules import AXIS_DEFAULTS, Schedule
-from repro.tuning.search import cost_summary
+from repro.tuning.search import (activation_tiling, activation_transposed,
+                                 cost_summary)
 
 Impl = Literal["kernel", "xla"]
 
@@ -253,7 +254,12 @@ def pfp_dense_batched_var(
 def pfp_activation(mu, var, *, kind: str = "relu", impl: Impl | None = None,
                    block_rows: int = 256, block_cols: int = 512,
                    schedule: Optional[Schedule] = None):
-    """Fused moment-matched activation for any shape. Returns (mean, srm)."""
+    """Fused moment-matched activation for any shape. Returns (mean, srm).
+
+    The kernel runs on ``search.activation_tiling``'s view: the input as
+    ``(rows, cols)``, or transposed where its trailing axis is narrower
+    than a lane row. Each element meets the same moment code either way,
+    so the view changes the grid, never the numbers."""
     impl = impl or get_default_impl()
     if impl == "xla":
         fn = {"relu": ref.pfp_relu_ref, "gelu": ref.pfp_gelu_ref,
@@ -262,26 +268,29 @@ def pfp_activation(mu, var, *, kind: str = "relu", impl: Impl | None = None,
         return fn(mu, var)
     shape = mu.shape
     cols = shape[-1]
-    mu2 = mu.reshape(-1, cols)
-    var2 = var.reshape(-1, cols)
-    m = mu2.shape[0]
-    bm = _block(schedule, "block_rows", min(block_rows, _ceil_mult(m, 8)),
-                m, 8)
-    bn = _block(schedule, "block_cols", min(block_cols, _ceil_mult(cols)),
-                cols, 128)
-    mu2 = _pad_to(mu2, bm, 0)
-    # Pad variances with ones (not zeros) to dodge the det-branch select;
-    # padded outputs are sliced away regardless.
-    var2 = _pad_to(var2, bm, 0)
-    mu2 = _pad_to(mu2, bn, 1)
-    var2 = _pad_to(var2, bn, 1)
+    rows = mu.size // cols
+    transposed = activation_transposed(rows, cols)
+    if schedule is not None:
+        block_rows = schedule.block("block_rows", block_rows)
+        block_cols = schedule.block("block_cols", block_cols)
+    r, c, bm, bn = activation_tiling(rows, cols, block_rows, block_cols)
+
+    # Zero padding is finite through every moment function (a zero
+    # variance takes the deterministic branch); padded outputs are sliced
+    # away.
+    def view(a):
+        a = a.reshape(rows, cols)
+        return _pad_to(_pad_to(a.T if transposed else a, bm, 0), bn, 1)
+
+    def unview(o):
+        o = o[:r, :c]
+        return (o.T if transposed else o).reshape(shape)
+
     mo, so = pfp_activation_pallas(
-        mu2, var2, kind=kind, block_rows=bm, block_cols=bn,
+        view(mu), view(var), kind=kind, block_rows=bm, block_cols=bn,
         interpret=_interpret(),
     )
-    mo = mo[:m, :cols].reshape(shape)
-    so = so[:m, :cols].reshape(shape)
-    return mo, so
+    return unview(mo), unview(so)
 
 
 def pfp_maxpool2d(mu, var, *, impl: Impl | None = None,

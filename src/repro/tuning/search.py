@@ -187,13 +187,18 @@ def cost_summary(op: str, shape_key: ShapeKey, schedule: Schedule) -> CostSummar
         aligned = bq % _SUBLANE == 0 and bk % _SUBLANE == 0
     elif op in ("activation", "glu_product", "maxpool2d"):
         rows, cols = _elementwise_rows_cols(op, shape_key)
-        br = min(get("block_rows", 256), _round_up(rows, _SUBLANE))
-        bc = min(get("block_cols", 512), _round_up(cols, _LANE))
+        elems = rows * cols
+        if op == "activation":  # steps and alignment on the kernel's view
+            rows, cols, br, bc = activation_tiling(
+                rows, cols, get("block_rows", 256), get("block_cols", 512))
+        else:
+            br = min(get("block_rows", 256), _round_up(rows, _SUBLANE))
+            bc = min(get("block_cols", 512), _round_up(cols, _LANE))
         tiles = {"activation": 4, "glu_product": 6, "maxpool2d": 10}[op]
         vmem = _pipelined(tiles * br * bc, temps=tiles * br * bc)
         per_elem = {"activation": 50, "glu_product": 2, "maxpool2d": 60}[op]
-        flops = per_elem * rows * cols
-        io = tiles * rows * cols * 4
+        flops = per_elem * elems
+        io = tiles * elems * 4
         steps = _steps(rows, br) * _steps(cols, bc)
         aligned = br % _SUBLANE == 0 and bc % _LANE == 0
     elif op == "norm_dense_act":
@@ -233,6 +238,30 @@ def cost_summary(op: str, shape_key: ShapeKey, schedule: Schedule) -> CostSummar
         mxu_aligned=aligned,
         fits_vmem=vmem <= vmem_limit_bytes() * VMEM_HEADROOM,
     )
+
+
+def activation_transposed(rows: int, cols: int) -> bool:
+    """Whether the activation kernel runs on ``(rows, cols)`` transposed:
+    a trailing axis narrower than a lane row (LeNet-5's 6 and 16
+    channels) over at least a lane row of rows. The op is elementwise, so
+    its tiling is free; transposed, the rows run along the lanes."""
+    return cols < _LANE <= rows
+
+
+def activation_tiling(rows: int, cols: int, block_rows: int,
+                      block_cols: int) -> Tuple[int, int, int, int]:
+    """The activation kernel's 2-D view ``(R, L)`` of a ``(rows, cols)``
+    input (``(cols, rows)`` when transposed) and its block ``(bm, bn)``;
+    the wrapper pads the view to whole blocks. Each block is clamped to
+    the padded view. A transposed view's few rows all go in one block,
+    which spends the rest of the ``block_rows x block_cols`` budget on
+    lanes."""
+    if activation_transposed(rows, cols):
+        bm = _round_up(cols, _SUBLANE)
+        bn = max(_LANE, block_rows * block_cols // bm // _LANE * _LANE)
+        return cols, rows, bm, min(bn, _round_up(rows, _LANE))
+    return (rows, cols, min(block_rows, _round_up(rows, _SUBLANE)),
+            min(block_cols, _round_up(cols, _LANE)))
 
 
 def _elementwise_rows_cols(op: str, shape_key: ShapeKey) -> Tuple[int, int]:
@@ -343,6 +372,8 @@ def _clamped_axis_values(op: str, name: str, shape_key: ShapeKey) -> List[int]:
     menu = _AXIS_MENU[op][name]
     if op in ("activation", "glu_product", "maxpool2d"):
         rows, cols = _elementwise_rows_cols(op, shape_key)
+        if op == "activation" and activation_transposed(rows, cols):
+            return sorted(menu)  # both axes size the lane block
         dim = rows if name == "block_rows" else cols
         align = _SUBLANE if name == "block_rows" else _LANE
     else:

@@ -9,8 +9,10 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.pfp_activations import pfp_activation_pallas
 from repro.kernels.pfp_attention import pfp_attention_pallas
 from repro.kernels.pfp_dense import pfp_dense_pallas
+from repro.tuning import search
 from repro.tuning.schedules import Schedule
 
 KEY = jax.random.PRNGKey(0)
@@ -147,6 +149,115 @@ def test_activation_kernels(kind, shape):
     want = ops.pfp_activation(mu, var, kind=kind, impl="xla")
     np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-5)
+
+
+# The activation's callers at the paper's and granite's widths: LeNet-5's
+# conv0, conv1, dense0 and dense1 ReLUs at batch 100, granite's gate SiLU
+# over 16 decode slots in bf16.
+_ACT_CASES = [
+    ("relu", (100, 28, 28, 6), jnp.float32),
+    ("relu", (100, 14, 14, 16), jnp.float32),
+    ("relu", (100, 120), jnp.float32),
+    ("relu", (100, 84), jnp.float32),
+    ("silu", (16, 14336), jnp.bfloat16),
+]
+
+
+def _act_inputs(shape, dtype):
+    mu, var = _gauss_pair(jax.random.fold_in(KEY, sum(shape)), shape)
+    return mu.astype(dtype), var.astype(dtype)
+
+
+def _row_blocks(mu, var, kind):
+    """The activation kernel in 8-row blocks (all rows below 8) over the
+    caller's own trailing axis, at most 128 lanes wide: the tiling each
+    view of ``ops.pfp_activation`` must agree with."""
+    shape = mu.shape
+    cols = shape[-1]
+    mu2, var2 = mu.reshape(-1, cols), var.reshape(-1, cols)
+    m = mu2.shape[0]
+    bm, bn = min(m, 8), min(cols, 128)
+    mu2, var2 = (ops._pad_to(ops._pad_to(a, bm, 0), bn, 1)
+                 for a in (mu2, var2))
+    mo, so = pfp_activation_pallas(mu2, var2, kind=kind, block_rows=bm,
+                                   block_cols=bn, interpret=True)
+    return mo[:m, :cols].reshape(shape), so[:m, :cols].reshape(shape)
+
+
+@pytest.mark.parametrize("kind,shape,dtype", _ACT_CASES)
+def test_activation_view_bit_equal_to_row_blocks(kind, shape, dtype):
+    mu, var = _act_inputs(shape, dtype)
+    got = ops.pfp_activation(mu, var, kind=kind, impl="kernel")
+    want = _row_blocks(mu, var, kind)
+    np.testing.assert_array_equal(got[0], want[0])
+    if shape[-1] != 6:
+        np.testing.assert_array_equal(got[1], want[1])
+        return
+    # XLA:CPU rounds the SRM's a*b + c*d of the ReLU differently in a loop
+    # 6 elements wide than in a longer one, with no Pallas involved: it
+    # contracts one product or the other into an FMA (jit of MOMENT_FNS
+    # alone reads the same). Held here to one rounding of each product,
+    # at the scale of the largest output; on the TPU the outputs are
+    # bit-identical.
+    scale = float(jnp.max(jnp.abs(want[1])))
+    np.testing.assert_allclose(got[1], want[1], rtol=2.0 ** -23,
+                               atol=2.0 ** -22 * scale)
+
+
+@pytest.mark.parametrize("kind,shape,dtype", _ACT_CASES)
+def test_activation_view_matches_oracle(kind, shape, dtype):
+    mu, var = _act_inputs(shape, dtype)
+    got = ops.pfp_activation(mu, var, kind=kind, impl="kernel")
+    want = ops.pfp_activation(mu, var, kind=kind, impl="xla")
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(got[1], want[1], rtol=1e-5, atol=1e-5)
+
+
+def _activation_grid(monkeypatch, shape, schedule=None):
+    """The 2-D view and blocks ``ops.pfp_activation`` hands its kernel."""
+    seen = []
+
+    def spy(mu, var, *, kind, block_rows, block_cols, interpret):
+        seen.append((mu.shape, block_rows, block_cols))
+        return jnp.zeros(mu.shape), jnp.zeros(mu.shape)
+
+    monkeypatch.setattr(ops, "pfp_activation_pallas", spy)
+    x = jax.ShapeDtypeStruct(shape, jnp.float32)
+    jax.eval_shape(lambda a, b: ops.pfp_activation(
+        a, b, impl="kernel", schedule=schedule), x, x)
+    (view, bm, bn), = seen
+    return view, bm, bn
+
+
+@pytest.mark.parametrize("shape,view,max_steps", [
+    ((100, 28, 28, 6), (6, 78400), 64),    # transposed: rows on lanes
+    ((100, 14, 14, 16), (16, 19600), 64),
+    ((100, 120), (100, 120), 1),
+    ((16, 14336), (16, 14336), 28),        # lane-dense: the input itself
+    ((4096, 14336), (4096, 14336), 448),
+])
+def test_activation_view_and_blocks(monkeypatch, shape, view, max_steps):
+    (rows, lanes), bm, bn = _activation_grid(monkeypatch, shape)
+    assert lanes % 128 == 0 and bn % 128 == 0 and bm % 8 == 0
+    assert rows % bm == 0 and lanes % bn == 0
+    assert (rows // bm) * (lanes // bn) <= max_steps
+    # the view, padded to whole blocks by less than one block
+    assert 0 <= rows - view[0] < bm and 0 <= lanes - view[1] < bn
+
+
+@pytest.mark.parametrize("shape", [(100, 28, 28, 6), (100, 120), (33, 100),
+                                   (16, 14336)])
+@pytest.mark.parametrize("blocks", [None, (8, 128), (64, 256), (512, 512)])
+def test_activation_cost_model_counts_the_wrapper_grid(monkeypatch, shape,
+                                                       blocks):
+    sched = None if blocks is None else Schedule.make(
+        "activation", block_rows=blocks[0], block_cols=blocks[1])
+    (rows, lanes), bm, bn = _activation_grid(monkeypatch, shape, sched)
+    cost = search.cost_summary(
+        "activation", (int(np.prod(shape[:-1])), shape[-1]),
+        sched or search.DEFAULT_SCHEDULES["activation"])
+    assert cost.grid_steps == (rows // bm) * (lanes // bn)
+    assert cost.mxu_aligned
 
 
 @pytest.mark.parametrize("shape", [(2, 8, 12, 7), (1, 28, 28, 6), (3, 14, 14, 16)])

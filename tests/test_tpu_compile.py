@@ -104,6 +104,22 @@ def test_activation(one_chip, kind, rows, cols):
         one_chip, ((rp, cols), F32), ((rp, cols), F32))
 
 
+@pytest.mark.parametrize("kind,shape,dtype", [
+    ("relu", (100, 28, 28, 6), F32),   # LeNet-5 at batch 100: conv0,
+    ("relu", (100, 14, 14, 16), F32),  # conv1,
+    ("relu", (100, 120), F32),         # dense0,
+    ("relu", (100, 84), F32),          # dense1
+    ("silu", (16, FF), BF16),          # granite gate: 16 decode slots,
+    ("silu", (4096, FF), BF16),        # one lockstep prefill round
+])
+def test_activation_wrapper_blocks(one_chip, monkeypatch, kind, shape, dtype):
+    # Through ops.pfp_activation, so the view and blocks are the ones the
+    # wrapper picks; only the interpret switch is turned for the chip.
+    monkeypatch.setattr(ops, "_interpret", lambda: False)
+    _compile(lambda a, b: ops.pfp_activation(a, b, kind=kind, impl="kernel"),
+             one_chip, (shape, dtype), (shape, dtype))
+
+
 @pytest.mark.parametrize("shape", [(100, 28, 28, 6), (100, 14, 14, 16)])
 def test_maxpool_lenet5(one_chip, shape):
     _compile(lambda a, b: pfp_maxpool2d_pallas(a, b),
