@@ -119,7 +119,7 @@ def _moe_forward_row(lines, *, iters):
         ctx = Context(mode=Mode.PFP, formulation="srm", impl=impl)
         fn = jax.jit(lambda p, a, _ctx=ctx: moe.moe_apply(
             p, a, _ctx, num_experts=n_e, top_k=top_k,
-            capacity_factor=1.0, aux_loss=False))
+            capacity_factor=1.0))
         rows[impl] = (fn, time_fn(fn, params, x, warmup=1, iters=iters))
     _, aux_k = rows["kernel"][0](params, x)
     drop = float(aux_k["moe_dropped"]) / float(aux_k["moe_assignments"])

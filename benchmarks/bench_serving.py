@@ -138,8 +138,8 @@ def _decode_step_row(lines, cfg, params, *, page_size=None):
 
 def _moe_decode_row(lines, *, n_requests):
     """Uncertainty-aware MoE decode: the engine decode loop on the reduced
-    DeepSeek-MoE config (routed top-k experts through the grid-level
-    batched-expert kernel path on --impl kernel). The derived column
+    DeepSeek-MoE config (routed top-k experts through the dropless
+    grouped-expert kernel path on --impl kernel). The derived column
     carries one lockstep decode-step wall time plus the aux-loss-free
     routing accounting (assignments / dropped / drop rate) a loadgen run
     records through the moe_drop_rate gauge."""

@@ -437,12 +437,12 @@ def main():
     print("== 14. Uncertainty-aware MoE decode (DeepSeek-style routing) ==")
     # A Mixture-of-Experts LM through the same engine: the router picks
     # top-k experts per token on the MEAN path (deterministic control
-    # flow), while the Gaussian moments ride through ONE grid-level
-    # batched-expert Pallas call per MoE layer (kernels/pfp_moe.py —
-    # E independent Eq. 12 dense problems, expert axis on the grid).
-    # Decode runs the aux-loss-free path: no load-balance loss term in
-    # the graph, but the capacity-drop accounting still surfaces through
-    # the engine's moe_drop_rate gauge.
+    # flow), while the Gaussian moments ride through one grouped-expert
+    # Pallas call per projection (kernels/pfp_moe.py — each row tile
+    # runs Eq. 12 against its own expert). Serving runs the aux-loss-free
+    # dropless path: no load-balance loss term in the graph, every
+    # assignment computed, and the routing counters surface through the
+    # engine's summary (moe_drop_rate reads 0).
     from repro.configs import reduced_config
     from repro.models import lm as lmmod
     from repro.serving.engine import (Engine, EngineConfig, RequestScheduler,
@@ -464,7 +464,7 @@ def main():
     print(f"  served {summary['completed']} requests through "
           f"{moe_cfg.num_experts} experts (top-{moe_cfg.top_k}): "
           f"{summary['moe_assignments']:.0f} routed assignments, "
-          f"{summary['moe_dropped_assignments']:.0f} dropped at capacity "
+          f"{summary['moe_dropped_assignments']:.0f} dropped "
           f"(drop rate {summary['moe_drop_rate']:.3f})")
     assert summary["final_occupancy"] == 0  # the MoE pool drains too
     # `--arch deepseek-moe-16b --impl kernel` on launch/serve.py runs this

@@ -40,6 +40,8 @@ def reduced_config(name: str) -> ModelConfig:
         num_kv_heads=min(cfg.num_kv_heads, 2) if cfg.num_kv_heads else 0,
         head_dim=16 if cfg.head_dim else 0,
         num_experts=min(cfg.num_experts, 8) if cfg.num_experts else 0,
+        routed_experts=0, expert_offset=0,
+        dense_d_ff=192 if cfg.dense_d_ff else 0,
         d_rnn=64 if cfg.d_rnn else 0,
         window=8 if cfg.window else 0,
         ssm_state=16 if cfg.ssm_state else 0,

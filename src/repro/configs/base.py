@@ -24,10 +24,20 @@ class ModelConfig:
     tie_embeddings: bool = False
 
     # MoE
-    num_experts: int = 0
+    num_experts: int = 0             # routed experts held here (see below)
     top_k: int = 0
     num_shared_experts: int = 0
     first_dense_layers: int = 0      # leading dense-FFN layers (DeepSeekMoE)
+    dense_d_ff: int = 0              # their FFN width; 0 = d_ff
+    # Gates are the top-k softmax probabilities, renormalised to sum to 1
+    # (True) or taken as they are (False, DeepSeekMoE).
+    norm_topk_prob: bool = True
+    # Expert share: the router scores ``routed_experts`` experts (0 = all
+    # ``num_experts``), of which this program holds the ``num_experts``
+    # starting at global index ``expert_offset``; it computes their part
+    # of each token's result and routes nothing to the rest.
+    routed_experts: int = 0
+    expert_offset: int = 0
     capacity_factor: float = 1.25
     # 'scatter' = GSPMD scatter/gather dispatch; 'a2a' = explicit shard_map
     # all-to-all dispatch/combine over the 'data' mesh axis (nn/moe.py) —
@@ -60,6 +70,15 @@ class ModelConfig:
     def attn_dim(self) -> int:
         return self.num_heads * self.head_dim
 
+    @property
+    def router_width(self) -> int:
+        return self.routed_experts or self.num_experts
+
+    @property
+    def dense_ffn_width(self) -> int:
+        """FFN width of the leading dense layers of an MoE model."""
+        return self.dense_d_ff or self.d_ff
+
     def param_count(self) -> int:
         """Approximate N (per-weight count, mu only) for MODEL_FLOPS."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
@@ -73,10 +92,11 @@ class ModelConfig:
                 n += d * self.attn_dim + 2 * d * self.num_kv_heads * self.head_dim \
                      + self.attn_dim * d
             if kind in ("attn", "cross"):
-                n += (3 if self.gated_mlp else 2) * d * f
+                fi = self.dense_ffn_width if self.family == "moe" else f
+                n += (3 if self.gated_mlp else 2) * d * fi
             if kind == "moe":
                 per_e = (3 if self.gated_mlp else 2) * d * f
-                n += self.num_experts * per_e + d * self.num_experts
+                n += self.num_experts * per_e + d * self.router_width
                 n += self.num_shared_experts * per_e
             if kind == "rec":
                 r = self.d_rnn or d
@@ -101,9 +121,9 @@ class ModelConfig:
             per_e = (3 if self.gated_mlp else 2) * d * f
             if kind == "moe":
                 n += (self.top_k + self.num_shared_experts) * per_e \
-                     + d * self.num_experts
+                     + d * self.router_width
             else:
-                n += per_e
+                n += (3 if self.gated_mlp else 2) * d * self.dense_ffn_width
         return n
 
     def layer_kind(self, i: int) -> str:

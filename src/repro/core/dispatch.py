@@ -368,6 +368,73 @@ def pfp_dense_batched(x, w, *, formulation: str = "srm",
 
 
 # ---------------------------------------------------------------------------
+# dense_grouped — the dropless MoE path's grouped-expert dense
+# ---------------------------------------------------------------------------
+def map_row_tiles(fn, rows, tile_expert, num_tiles, block_m: int):
+    """Map ``fn(*row_tiles, e) -> outputs`` over the row tiles of
+    ``block_m`` rows of every (M, K) array in ``rows``, ``e`` being the
+    tile's expert ``tile_expert[i]``; tiles from ``num_tiles`` on are
+    zero. Returns the outputs as (M, N) arrays: the grouped-expert tile
+    loop of the ``dense_grouped`` xla impl and of its kernel's oracle."""
+    m = rows[0].shape[0]
+    t = m // block_m
+
+    def one(args):
+        *tiles, e, i = args
+        live = i < num_tiles
+        return tuple(jnp.where(live, o, jnp.zeros_like(o))
+                     for o in fn(*tiles, e))
+
+    outs = jax.lax.map(one, (*[r.reshape(t, block_m, r.shape[-1])
+                               for r in rows], tile_expert,
+                             jnp.arange(t, dtype=jnp.int32)))
+    return tuple(o.reshape(m, -1) for o in outs)
+
+
+@register("dense_grouped", "xla")
+def _dense_grouped_xla(x, w, tile_expert, num_tiles, block_m, formulation):
+    # The PFP dense chain per row tile against its expert's weights, cast
+    # to the rows' dtype as the kernel casts them.
+    def one(xm, xs, e):
+        we = GaussianTensor(w.mean[e].astype(xm.dtype),
+                            w.second[e].astype(xm.dtype), w.rep)
+        out = pfp_layers.pfp_dense(GaussianTensor(xm, xs, x.rep), we,
+                                   formulation=formulation)
+        return out.mean, out.var
+
+    mu, var = map_row_tiles(one, (x.mean, x.second), tile_expert, num_tiles,
+                            block_m)
+    return GaussianTensor(mu, var, VAR)
+
+
+@register("dense_grouped", "kernel")
+def _dense_grouped_kernel(x, w, tile_expert, num_tiles, block_m, formulation):
+    if formulation != "srm":
+        note_fallback(f"dense_grouped:{formulation}")
+        return _dense_grouped_xla(x, w, tile_expert, num_tiles, block_m,
+                                  formulation)
+    ops = _kernel_ops()
+    mu, var = ops.pfp_dense_grouped(x.mean, x.srm, w.mean, w.srm,
+                                    tile_expert, num_tiles, block_m=block_m,
+                                    impl="kernel")
+    return GaussianTensor(mu.astype(x.dtype), var.astype(x.dtype), VAR)
+
+
+def pfp_dense_grouped(x, w, tile_expert, num_tiles, *, block_m: int,
+                      formulation: str = "srm",
+                      impl: Optional[str] = None) -> GaussianTensor:
+    """Grouped-expert PFP dense: (M, K) rows sorted by expert in row tiles
+    of ``block_m`` x (E, K, N) -> (M, N); tile i runs against expert
+    ``tile_expert[i]``, and tiles from ``num_tiles`` on come out zero.
+    Consumes SRM, emits VAR. The kernel impl is one Pallas call with the
+    tiles' experts as scalar prefetch (kernels/pfp_moe.py); the xla impl
+    is the per-tile PFP dense chain it is tested against."""
+    return get_op("dense_grouped", impl)(_to_compute_rep(x, formulation), w,
+                                         tile_expert, num_tiles, block_m,
+                                         formulation)
+
+
+# ---------------------------------------------------------------------------
 # conv2d (im2col) — shares the dense kernel's blocked schedule
 # ---------------------------------------------------------------------------
 @register("conv2d_im2col", "xla")
@@ -878,9 +945,10 @@ def pfp_residual(x, y, impl: Optional[str] = None) -> GaussianTensor:
 __all__ = [
     "IMPLS", "set_default_impl", "get_default_impl", "resolve_impl",
     "register", "get_op", "registered_ops",
-    "count_fallbacks", "note_fallback",
+    "count_fallbacks", "note_fallback", "map_row_tiles",
     "set_fusion", "get_fusion", "fusion",
-    "pfp_dense", "pfp_dense_batched", "pfp_einsum", "pfp_conv2d_im2col",
+    "pfp_dense", "pfp_dense_batched", "pfp_dense_grouped", "pfp_einsum",
+    "pfp_conv2d_im2col",
     "pfp_activation",
     "pfp_maxpool2d", "pfp_attention", "pfp_attention_cache",
     "pfp_attention_paged", "pfp_rmsnorm", "pfp_layernorm",

@@ -33,7 +33,8 @@ from repro.kernels.pfp_attention import (pfp_attention_cache_pallas,
 from repro.kernels.pfp_dense import pfp_dense_pallas, pfp_dense_var_pallas
 from repro.kernels.pfp_fused import pfp_norm_dense_act_pallas
 from repro.kernels.pfp_moe import (pfp_dense_batched_pallas,
-                                   pfp_dense_batched_var_pallas)
+                                   pfp_dense_batched_var_pallas,
+                                   pfp_dense_grouped_pallas)
 from repro.kernels.pfp_maxpool import pfp_maxpool2d_pallas
 from repro.kernels.pfp_norms import pfp_layernorm_pallas, pfp_rmsnorm_pallas
 from repro.tuning.schedules import AXIS_DEFAULTS, Schedule
@@ -249,6 +250,51 @@ def pfp_dense_batched_var(
         interpret=_interpret(),
     )
     return mu[:e, :c, :n], var[:e, :c, :n]
+
+
+# Largest weight tile, per array, one step of the grouped kernel reads.
+GROUPED_W_TILE_BYTES = 1 << 20
+
+
+def _divisor_block(dim: int, most: int) -> int:
+    """The largest multiple of 128 that divides ``dim`` and is at most
+    ``most`` (at least 128); ``dim`` itself where it is at most ``most``
+    or not a multiple of 128 (a block may span its whole axis)."""
+    if dim <= most or dim % 128:
+        return dim
+    best = 128
+    for b in range(128, most + 1, 128):
+        if dim % b == 0:
+            best = b
+    return best
+
+
+def grouped_blocks(k: int, n: int, w_bytes: int):
+    """(block_k, block_n) of the grouped kernel for (K, N) weights: blocks
+    that divide the weights (nothing is padded or copied per call), N up
+    to 1536 wide, K as deep as ``GROUPED_W_TILE_BYTES`` allows."""
+    bn = _divisor_block(n, 1536)
+    bk = _divisor_block(k, max(128, GROUPED_W_TILE_BYTES // (bn * w_bytes)))
+    return bk, bn
+
+
+def pfp_dense_grouped(mu_x, srm_x, mu_w, srm_w, tile_expert, num_tiles, *,
+                      block_m: int, impl: Impl | None = None):
+    """Grouped-expert joint PFP dense (SRM formulation): (M, K) rows,
+    sorted by expert in row tiles of ``block_m``, x (E, K, N) weights ->
+    (mean, var), each (M, N) fp32. ``tile_expert`` (M/block_m,) names each
+    tile's expert and ``num_tiles`` how many tiles are live; the rest come
+    out zero. The weights may be stored in a wider dtype than the rows:
+    each tile is cast to the rows' dtype in the kernel."""
+    impl = impl or get_default_impl()
+    if impl == "xla":
+        return ref.pfp_dense_grouped_ref(mu_x, srm_x, mu_w, srm_w,
+                                         tile_expert, num_tiles, block_m)
+    bk, bn = grouped_blocks(mu_x.shape[-1], mu_w.shape[-1],
+                            jnp.dtype(mu_w.dtype).itemsize)
+    return pfp_dense_grouped_pallas(
+        mu_x, srm_x, mu_w, srm_w, tile_expert, num_tiles, block_m=block_m,
+        block_n=bn, block_k=bk, interpret=_interpret())
 
 
 def pfp_activation(mu, var, *, kind: str = "relu", impl: Impl | None = None,

@@ -19,6 +19,14 @@ fp32 accumulators live in VMEM across K steps, exactly like
 `pfp_dense.py`). The searched axes (`dims`, `k_order`, block shapes) have
 the same semantics as the dense kernel; ``k_order='unrolled'`` drops the
 K grid axis and replays the identical accumulation sequence in-body.
+
+The grouped kernel (``pfp_dense_grouped_pallas``) is the dropless serving
+path's: rows sorted by expert, each expert's rows starting on a row tile
+of their own, and per tile its expert's index as scalar prefetch. Each
+row tile runs Eq. 12 against its own expert's (``mu``, ``srm``) tiles, so
+an expert's weights are read once per row tile it has, and an expert with
+no rows is not read at all. The row buffer is sized for the worst case;
+tiles from the real count on are skipped (zero output, no weight DMA).
 """
 from __future__ import annotations
 
@@ -332,3 +340,96 @@ def pfp_dense_batched_var_pallas(
         **common,
     )
     return fn(mu_x, var_x, mu_w, var_w)
+
+
+# ---------------------------------------------------------------------------
+# Grouped experts: one row tile per step group, its expert by scalar prefetch
+# ---------------------------------------------------------------------------
+def _grouped_kernel(tile_expert_ref, num_tiles_ref, mu_x_ref, srm_x_ref,
+                    mu_w_ref, srm_w_ref, mu_out_ref, var_out_ref,
+                    acc_musq_ref, *, nk: int):
+    """One (i, j, k) step: Eq. 12 for row tile i against its expert's
+    (k, j) weight tiles, read as stored and cast to the rows' dtype."""
+    del tile_expert_ref  # used by the index maps only
+    i = pl.program_id(0)
+    k = pl.program_id(2)
+
+    @pl.when(k == 0)
+    def _init():
+        mu_out_ref[...] = jnp.zeros_like(mu_out_ref)
+        var_out_ref[...] = jnp.zeros_like(var_out_ref)
+        acc_musq_ref[...] = jnp.zeros_like(acc_musq_ref)
+
+    @pl.when(i < num_tiles_ref[0])
+    def _accumulate():
+        mu_x = mu_x_ref[...]
+        mu_w = mu_w_ref[0].astype(mu_x.dtype)
+        mu_out_ref[...] += jnp.dot(mu_x, mu_w,
+                                   preferred_element_type=jnp.float32)
+        var_out_ref[...] += jnp.dot(srm_x_ref[...],
+                                    srm_w_ref[0].astype(mu_x.dtype),
+                                    preferred_element_type=jnp.float32)
+        acc_musq_ref[...] += jnp.dot(jnp.square(mu_x), jnp.square(mu_w),
+                                     preferred_element_type=jnp.float32)
+
+    @pl.when(k == nk - 1)
+    def _finalize():
+        var_out_ref[...] = var_out_ref[...] - acc_musq_ref[...]
+
+
+def _grouped_steps(i, j, k, num_tiles_ref, nn: int, nk: int):
+    """(row tile, n, k) a step reads. A skipped tile (i >= the real count)
+    reads what the last live step read, so no tile is fetched for it."""
+    nt = num_tiles_ref[0]
+    dead = (i >= nt).astype(jnp.int32)
+    return (jnp.minimum(i, jnp.maximum(nt - 1, 0)),
+            j + dead * (nn - 1 - j), k + dead * (nk - 1 - k))
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "block_n",
+                                             "block_k", "interpret"))
+def pfp_dense_grouped_pallas(mu_x, srm_x, mu_w, srm_w, tile_expert,
+                             num_tiles, *, block_m: int, block_n: int,
+                             block_k: int, interpret: bool = False):
+    """Grouped joint PFP dense: (M, K) rows x (E, K, N) expert weights ->
+    mean, variance (M, N) fp32. Row tile i (``block_m`` rows) belongs to
+    expert ``tile_expert[i]``; ``num_tiles`` (shape (1,)) tiles are live.
+    M, K, N must be multiples of the blocks (`ops.pfp_dense_grouped`
+    chooses blocks that divide them)."""
+    m, kdim = mu_x.shape
+    n = mu_w.shape[-1]
+    bm, bn, bk = block_m, block_n, block_k
+    assert m % bm == 0 and n % bn == 0 and kdim % bk == 0, \
+        (m, n, kdim, bm, bn, bk)
+    nn, nk = n // bn, kdim // bk
+
+    def x_map(i, j, k, te, nt):
+        ii, _, kk = _grouped_steps(i, j, k, nt, nn, nk)
+        return ii, kk
+
+    def w_map(i, j, k, te, nt):
+        ii, jj, kk = _grouped_steps(i, j, k, nt, nn, nk)
+        return te[ii], kk, jj
+
+    x_spec = pl.BlockSpec((bm, bk), x_map)
+    w_spec = pl.BlockSpec((1, bk, bn), w_map)
+    out_spec = pl.BlockSpec((bm, bn), lambda i, j, k, te, nt: (i, j))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(m // bm, nn, nk),
+        in_specs=[x_spec, x_spec, w_spec, w_spec],
+        out_specs=[out_spec, out_spec],
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
+    fn = pl.pallas_call(
+        functools.partial(_grouped_kernel, nk=nk),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((m, n), jnp.float32),
+                   jax.ShapeDtypeStruct((m, n), jnp.float32)],
+        interpret=interpret,
+        compiler_params=_compiler_params(("parallel", "parallel",
+                                          "arbitrary")),
+    )
+    return fn(tile_expert.astype(jnp.int32),
+              jnp.reshape(num_tiles, (1,)).astype(jnp.int32),
+              mu_x, srm_x, mu_w, srm_w)

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import pfp_math
+from repro.core.dispatch import map_row_tiles
 
 
 # -- pfp_dense ---------------------------------------------------------------
@@ -51,6 +52,18 @@ def pfp_dense_var_ref(mu_x, var_x, mu_w, var_w):
 pfp_dense_batched_ref = jax.vmap(pfp_dense_ref)
 pfp_dense_batched_first_layer_ref = jax.vmap(pfp_dense_first_layer_ref)
 pfp_dense_batched_var_ref = jax.vmap(pfp_dense_var_ref)
+
+
+def pfp_dense_grouped_ref(mu_x, srm_x, mu_w, srm_w, tile_expert, num_tiles,
+                          block_m: int):
+    """Grouped joint PFP dense (the grouped kernel's oracle): row tile i
+    of ``block_m`` rows of (M, K) runs Eq. 12 against expert
+    ``tile_expert[i]``'s (K, N) weights, cast to the rows' dtype; tiles
+    from ``num_tiles`` on are zero. Returns (mean, var), each (M, N)."""
+    return map_row_tiles(
+        lambda xm, xs, e: pfp_dense_ref(xm, xs, mu_w[e].astype(xm.dtype),
+                                        srm_w[e].astype(xm.dtype)),
+        (mu_x, srm_x), tile_expert, num_tiles, block_m)
 
 
 # -- pfp_activations ---------------------------------------------------------

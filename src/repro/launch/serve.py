@@ -63,6 +63,8 @@ _PREFIX_KEYS = (
 
 _MOE_KEYS = (
     "moe_assignments", "moe_dropped_assignments", "moe_drop_rate",
+    "moe_held_assignments", "moe_experts_touched", "moe_padding_routed",
+    "moe_max_expert_load",
 )
 
 _SPEC_KEYS = (
@@ -323,9 +325,9 @@ def parse_args(argv=None):
                          "engine on the same trace")
     ap.add_argument("--expect-moe-drop", action="store_true",
                     help="exit nonzero unless the run recorded MoE routing "
-                         "accounting (moe_assignments > 0) and a finite "
-                         "drop rate — CI: prove the aux-loss-free decode "
-                         "path surfaces expert-capacity drops (moe only)")
+                         "accounting (moe_assignments > 0), no dropped "
+                         "assignment and no padding row routed — CI: "
+                         "prove the serving path is dropless (moe only)")
     ap.add_argument("--expect-accept-rate", type=float, default=None,
                     metavar="R",
                     help="exit nonzero if the draft acceptance rate falls "
@@ -553,8 +555,15 @@ def _serve(args):
                   "engine metrics)", file=sys.stderr)
             return 1
         print(f"moe routing: {summary['moe_assignments']} assignments, "
+              f"{summary['moe_held_assignments']} held, "
               f"{summary['moe_dropped_assignments']} dropped "
-              f"(rate {summary['moe_drop_rate']:.4f})")
+              f"(rate {summary['moe_drop_rate']:.4f}), "
+              f"{summary['moe_padding_routed']} padding rows routed")
+        if summary["moe_dropped_assignments"] or \
+                summary["moe_padding_routed"]:
+            print("ERROR: --expect-moe-drop: the serving path dropped an "
+                  "assignment or routed a padding row", file=sys.stderr)
+            return 1
     if args.expect_prefix_hits and summary["prefix_hits"] == 0:
         print("ERROR: --expect-prefix-hits but no admission mapped shared "
               "prefix pages (trace lacks a common prefix, or donors never "

@@ -33,7 +33,7 @@ from repro.nn.layers import (NORMS, dense_apply, dense_init, embedding_apply,
                              embedding_init, residual_add,
                              sinusoidal_embedding)
 from repro.nn.mlp import mlp_apply, mlp_init
-from repro.nn.moe import moe_apply, moe_init, zero_aux
+from repro.nn.moe import merge_aux, moe_apply, moe_init, zero_aux
 from repro.nn.pjit_hints import constrain
 from repro.nn.module import Context
 from repro.nn.recurrent import (RecurrentState, init_recurrent_state,
@@ -44,7 +44,8 @@ from repro.nn.ssm import SSMState, init_ssm_state, mamba2_apply, mamba2_init
 # ---------------------------------------------------------------------------
 # Block init/apply
 # ---------------------------------------------------------------------------
-def _block_init(kind: str, cfg: ModelConfig, key):
+def _block_init(kind: str, cfg: ModelConfig, key, d_ff: int = 0):
+    """``d_ff`` (0 = ``cfg.d_ff``) is the FFN width of a dense block."""
     norm_init_fn = NORMS[cfg.norm][0]
     ks = jax.random.split(key, 3)
     si = cfg.sigma_init
@@ -55,7 +56,7 @@ def _block_init(kind: str, cfg: ModelConfig, key):
                                    cfg.num_kv_heads, cfg.head_dim,
                                    sigma_init=si),
             "ln2": norm_init_fn(cfg.d_model),
-            "mlp": mlp_init(ks[1], cfg.d_model, cfg.d_ff,
+            "mlp": mlp_init(ks[1], cfg.d_model, d_ff or cfg.d_ff,
                             gated=cfg.gated_mlp, sigma_init=si),
         }
     if kind == "moe":
@@ -67,7 +68,8 @@ def _block_init(kind: str, cfg: ModelConfig, key):
             "ln2": norm_init_fn(cfg.d_model),
             "moe": moe_init(ks[1], cfg.d_model, cfg.d_ff, cfg.num_experts,
                             num_shared=cfg.num_shared_experts,
-                            gated=cfg.gated_mlp, sigma_init=si),
+                            gated=cfg.gated_mlp, sigma_init=si,
+                            router_width=cfg.router_width),
         }
     if kind == "rec":
         return {
@@ -88,12 +90,24 @@ def _block_init(kind: str, cfg: ModelConfig, key):
     raise ValueError(kind)
 
 
+def moe_options(cfg: ModelConfig) -> dict:
+    """``nn.moe.moe_apply``'s keyword arguments for ``cfg``'s MoE blocks:
+    the expert share, the router and the gate rule."""
+    return dict(num_experts=cfg.num_experts, top_k=cfg.top_k,
+                capacity_factor=cfg.capacity_factor,
+                activation=cfg.activation, dispatch_mode=cfg.moe_dispatch,
+                norm_topk_prob=cfg.norm_topk_prob,
+                routed_experts=cfg.routed_experts,
+                expert_offset=cfg.expert_offset)
+
+
 def _block_apply(kind: str, params, x, ctx: Context, cfg: ModelConfig, *,
                  positions, image_emb=None, state=None, cache_len=None,
                  page_table=None, write_start=None,
-                 standard_positions=False, moe_aux_loss=True):
-    """Returns (x, new_state, aux) — aux is the MoE aux dict ('loss',
-    'moe_dropped', 'moe_assignments'), zeros for non-MoE blocks."""
+                 standard_positions=False, moe_aux_loss=True, valid=None):
+    """Returns (x, new_state, aux) — aux is the MoE aux dict
+    (``nn.moe.zero_aux``), zeros for non-MoE blocks. ``valid`` (B, T) marks
+    the rows that are not padding; the MoE block routes only those."""
     norm_apply = NORMS[cfg.norm][1]
     aux = zero_aux()
     new_state = None
@@ -118,11 +132,11 @@ def _block_apply(kind: str, params, x, ctx: Context, cfg: ModelConfig, *,
         x = residual_add(x, attn_out)
         h = norm_apply(params["ln2"], x, ctx)
         if kind == "moe":
-            ffn_out, aux = moe_apply(
-                params["moe"], h, ctx, num_experts=cfg.num_experts,
-                top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-                activation=cfg.activation, aux_loss=moe_aux_loss,
-                dispatch_mode=cfg.moe_dispatch)
+            # The inference path (no load-balance loss) serves dropless
+            # over this program's expert share; training keeps capacity.
+            ffn_out, aux = moe_apply(params["moe"], h, ctx,
+                                     aux_loss=moe_aux_loss, valid=valid,
+                                     **moe_options(cfg))
         else:
             ffn_out = mlp_apply(params["mlp"], h, ctx, activation=cfg.activation)
         x = residual_add(x, ffn_out)
@@ -168,7 +182,9 @@ def init_params(cfg: ModelConfig, key) -> dict:
     # Leading unscanned layers (e.g. DeepSeekMoE's first dense-FFN layer).
     head_cfg = cfg
     for i in range(cfg.first_dense_layers):
-        params[f"head{i}"] = _block_init("attn", cfg, jax.random.fold_in(ks[1], i))
+        params[f"head{i}"] = _block_init("attn", cfg,
+                                         jax.random.fold_in(ks[1], i),
+                                         d_ff=cfg.dense_ffn_width)
 
     lpg, num_groups, tail = _group_counts(cfg)
 
@@ -226,10 +242,12 @@ def forward(params, cfg: ModelConfig, inputs, ctx: Context, *,
 
     states/collect_states support the prefill program: pass initialized
     per-layer states and get back the filled ones alongside the output.
-    Returns (logits, aux, new_states) — aux is the summed MoE aux dict
-    ({'loss', 'moe_dropped', 'moe_assignments'} f32 scalars).
+    Returns (logits, aux, new_states) — aux is the MoE aux dict reduced
+    over layers (``nn.moe.zero_aux``, f32 scalars).
     ``moe_aux_loss=False`` is the aux-loss-free inference path: decode and
-    prefill graphs never build the router's load-balance loss term.
+    prefill graphs never build the router's load-balance loss term, and
+    MoE blocks route dropless, padding rows (positions at or past
+    ``cache_len``) to no expert.
     """
     x, positions, standard_positions = _embed_inputs(params, cfg, inputs, ctx)
     x = constrain(x, "batch", "seq", "embed")
@@ -243,12 +261,12 @@ def forward(params, cfg: ModelConfig, inputs, ctx: Context, *,
     cache_len = inputs.get("cache_len")
     page_table = inputs.get("page_table")
     write_start = inputs.get("write_start")
+    valid = (positions < cache_len[:, None] if cache_len is not None
+             else None)
 
     lpg, num_groups, tail = _group_counts(cfg)
     aux_total = zero_aux()
-
-    def _acc(acc, aux):
-        return {k: acc[k] + aux[k] for k in acc}
+    _acc = merge_aux
 
     for i in range(cfg.first_dense_layers):
         st = None if states is None else states.get(f"head{i}")
@@ -259,7 +277,7 @@ def forward(params, cfg: ModelConfig, inputs, ctx: Context, *,
                                       page_table=page_table,
                                       write_start=write_start,
                                       standard_positions=standard_positions,
-                                      moe_aux_loss=moe_aux_loss)
+                                      moe_aux_loss=moe_aux_loss, valid=valid)
         aux_total = _acc(aux_total, aux)
         if collect_states and states is not None:
             states[f"head{i}"] = new_st
@@ -288,7 +306,7 @@ def forward(params, cfg: ModelConfig, inputs, ctx: Context, *,
                         cache_len=cache_len, page_table=page_table,
                         write_start=write_start,
                         standard_positions=standard_positions,
-                        moe_aux_loss=moe_aux_loss)
+                        moe_aux_loss=moe_aux_loss, valid=valid)
 
                 # Nested remat: per-layer checkpoints inside the remat'd
                 # group bound the backward live-set to ONE layer.
@@ -322,7 +340,7 @@ def forward(params, cfg: ModelConfig, inputs, ctx: Context, *,
                                       page_table=page_table,
                                       write_start=write_start,
                                       standard_positions=standard_positions,
-                                      moe_aux_loss=moe_aux_loss)
+                                      moe_aux_loss=moe_aux_loss, valid=valid)
         aux_total = _acc(aux_total, aux)
         if collect_states and states is not None:
             states[f"tail{i}"] = new_st
@@ -532,8 +550,8 @@ def decode_step(params, cfg: ModelConfig, inputs, states, ctx: Context):
 def decode_step_with_aux(params, cfg: ModelConfig, inputs, states,
                          ctx: Context):
     """:func:`decode_step` that also returns the MoE aux dict
-    ({'loss', 'moe_dropped', 'moe_assignments'}) — the serving engine reads
-    the drop counters per step. Runs the aux-loss-free inference path: the
+    (``nn.moe.zero_aux``) — the serving engine reads the routing counters
+    per pass. Runs the aux-loss-free inference path: the
     'loss' entry stays zero and the decode graph never builds the router's
     load-balance term. Returns (logits, aux, new_states)."""
     logits, aux, new_states = forward(

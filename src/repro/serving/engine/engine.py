@@ -57,6 +57,12 @@ from repro.serving.engine.scheduler import (RequestScheduler, SchedulerConfig,
                                             pages_for)
 from repro.serving.engine.state import DecodeStatePool, PagedDecodeStatePool
 
+# A pass's MoE routing counters: EngineMetrics.on_moe argument -> the
+# key of the models' aux dict (nn.moe.zero_aux) it reads.
+_MOE_COUNTERS = {"assignments": "moe_assignments", "held": "moe_held",
+                 "max_load": "moe_max_load", "touched": "moe_touched",
+                 "pad_routed": "moe_pad_routed", "dropped": "moe_dropped"}
+
 
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
@@ -266,6 +272,9 @@ class Engine:
         # tokens served this step to requests that already had one (the
         # inter-token gaps a step closes; pfp.step's itl_tokens)
         self._itl_tokens = 0
+        # MoE routing counters of prefill rounds not yet read (device
+        # arrays; read in the route's next sync, _sync)
+        self._moe_pending = []
         self._key_unc = jax.random.PRNGKey(config.seed)
         self._key_esc = jax.random.PRNGKey(config.seed + 1)
         v = cfg.vocab_size
@@ -424,16 +433,18 @@ class Engine:
         """One batched multi-slot prefill round over the page pool:
         (B, C) window tokens in, per-slot logit (mean, var) rows gathered
         at each slot's own last-real-token index, merged into the logit
-        buffers only where ``done`` (prefill completed this round)."""
-        logits, new_states = lm.decode_step(params, self.cfg, inputs, states,
-                                            self._ctx())
+        buffers only where ``done`` (prefill completed this round). The
+        4th output is the MoE aux dict (routing counters; zeros on dense
+        families)."""
+        logits, aux, new_states = lm.decode_step_with_aux(
+            params, self.cfg, inputs, states, self._ctx())
         mean, var = self._split_logits(logits)
         mean = jnp.take_along_axis(
             mean.astype(jnp.float32), out_idx[:, None, None], axis=1)[:, 0]
         var = jnp.take_along_axis(
             var.astype(jnp.float32), out_idx[:, None, None], axis=1)[:, 0]
         return (jnp.where(done[:, None], mean, lm_mean),
-                jnp.where(done[:, None], var, lm_var), new_states)
+                jnp.where(done[:, None], var, lm_var), new_states, aux)
 
     def _draft_steps(self, params, head, positions, states, table):
         """K-1 mean-only (zero-variance) draft decode steps over the shared
@@ -767,10 +778,41 @@ class Engine:
             rounds += rows > 0
         return rounds
 
-    def _prefill_round(self, rnd) -> int:
+    def _count_moe(self, counters, *, decode: bool) -> dict:
+        """Passes' MoE routing counters, read from the device, into the
+        metrics; returns them summed (the max load maxed) as span args:
+        ``moe_<k>`` for a decode pass, ``moe_prefill_<k>`` for prefill
+        rounds."""
+        args = {}
+        prefix = "moe_" if decode else "moe_prefill_"
+        for c in counters:
+            moe = {name: int(c[k]) for name, k in _MOE_COUNTERS.items()}
+            self.metrics.on_moe(decode=decode, **moe)
+            for name, v in moe.items():
+                key = prefix + name
+                args[key] = (max(args.get(key, 0), v) if name == "max_load"
+                             else args.get(key, 0) + v)
+        return args
+
+    def _sync(self, *arrays):
+        """The route's one transfer: ``arrays`` to the host, and with them
+        the MoE counters of the prefill rounds since the last sync (kept
+        on the device until now) into the metrics and the ``pfp.sync``
+        span's args."""
+        pending, self._moe_pending = self._moe_pending, []
+        with span("sync") as sp:
+            got, counters = jax.device_get(
+                (arrays, [{k: a[k] for k in _MOE_COUNTERS.values()}
+                          for a in pending]))
+            sp.set_metadata(**self._count_moe(counters, decode=False))
+        return [np.asarray(a) for a in got]
+
+    def _prefill_round(self, rnd):
         """One round of the plan (``(slot, n)`` chunks) as one pass at the
         slot-batch width; returns the prompt tokens it carried (0 = every
-        planned slot was preempted, and nothing ran)."""
+        planned slot was preempted, and nothing ran). An MoE pass's
+        routing counters stay on the device until the route's next sync
+        reads them (:meth:`_sync`)."""
         b = self.config.slots
         c = self.scheduler.config.prefill_chunk
         tokens = np.zeros((b, c), np.int32)
@@ -825,11 +867,13 @@ class Engine:
             "write_start": jnp.asarray(write_start),
             "page_table": table,
         }
-        self._lm_mean, self._lm_var, self.pool.states = \
+        self._lm_mean, self._lm_var, self.pool.states, aux = \
             self._batch_chunk_fn(self.params, inputs, self.pool.states,
                                  jnp.asarray(out_idx),
                                  jnp.asarray(done),
                                  self._lm_mean, self._lm_var)
+        if self.cfg.family == "moe":
+            self._moe_pending.append(aux)
         rows = sum(n for _, n, _ in planned)
         if self._tracer is not None:
             self._tracer.emit(self._step_idx, "prefill_round",
@@ -867,8 +911,7 @@ class Engine:
             tok_idx[slot] = len(req.generated)
         toks, mis = self._unc(self._lm_mean, self._lm_var, self._key_unc,
                               jnp.asarray(uids), jnp.asarray(tok_idx))
-        with span("sync"):
-            toks, mis = np.asarray(toks), np.asarray(mis)
+        toks, mis = self._sync(toks, mis)
         return self._resolve_escalations(decode_slots, toks, mis)
 
     def _serve_token(self, req: Request, tok: int, mi: float) -> None:
@@ -923,12 +966,14 @@ class Engine:
                     self._slots[slot].last_input = tok
         if active.any():
             with span("decode_step") as sp:
-                sp.set_metadata(active=self._decode(feed, active))
+                n, moe = self._decode(feed, active)
+                sp.set_metadata(active=n, **moe)
 
-    def _decode(self, feed: np.ndarray, active: np.ndarray) -> int:
+    def _decode(self, feed: np.ndarray, active: np.ndarray):
         """One lockstep decode pass feeding ``feed`` to the ``active``
         slots; returns how many it ran (fewer where page pressure
-        preempted some, 0 = no pass)."""
+        preempted some, 0 = no pass) and its MoE counters, read in the
+        transfer that follows it on MoE families (:meth:`_count_moe`)."""
         if self.paged:
             # Each active slot writes one KV row at its position this
             # step: make sure the covering page exists. Under optimistic
@@ -943,7 +988,7 @@ class Engine:
                     self._preempt(slot)
             active &= np.asarray([sl is not None for sl in self._slots])
             if not active.any():
-                return 0
+                return 0, {}
         positions = self.pool.positions.copy()
         self._prev_states = self.pool.states
         args = (self.params,
@@ -960,16 +1005,17 @@ class Engine:
             self._lm_mean, self._lm_var, self.pool.states, aux = \
                 self._decode_fn(*args, self._lm_mean, self._lm_var)
         self.metrics.on_decode_pass()
+        moe = {}
         if self.cfg.family == "moe":
-            self.metrics.on_moe_drop(float(aux["moe_dropped"]),
-                                     float(aux["moe_assignments"]))
+            moe = self._count_moe(jax.device_get(
+                [{k: aux[k] for k in _MOE_COUNTERS.values()}]), decode=True)
         n = int(active.sum())
         if self._tracer is not None:
             self._tracer.emit(self._step_idx, "decode_step", active=n)
         self.pool.positions[active] += 1
         for slot in np.flatnonzero(active):
             self._slots[slot].replay = None  # replay via _prev_states now
-        return n
+        return n, moe
 
     # -- uncertainty-speculative decoding ------------------------------------
     def _route_and_decode_spec(self, now: float) -> None:
@@ -1100,9 +1146,7 @@ class Engine:
         with span("route", slots=len(live)):
             btoks, bmis = self._unc_block(vmean, vvar, self._key_unc,
                                           jnp.asarray(uids), jnp.asarray(tok0))
-            with span("sync"):
-                tok_np = np.asarray(btoks)
-                mi_np = np.asarray(bmis)
+            tok_np, mi_np = self._sync(btoks, bmis)
 
             drafted_total = accepted_total = 0
             for slot in live:

@@ -78,9 +78,15 @@ _COUNTERS = {
     "draft_passes": "mean-only draft decode passes",
     "svi_passes": "SVI second-opinion passes launched",
     # MoE routing telemetry (stays zero on dense families)
-    "moe_dropped_assignments": "routed (token, expert) assignments dropped "
-                               "at capacity",
-    "moe_assignments": "routed (token, expert) assignments offered",
+    "moe_dropped_assignments": "routed (token, expert) assignments to held "
+                               "experts left uncomputed",
+    "moe_assignments": "routed (token, expert) assignments of real tokens",
+    "moe_held_assignments": "routed assignments to experts held here",
+    "moe_experts_touched": "held experts given any row, summed over "
+                           "MoE layers and passes",
+    "moe_padding_routed": "assignments given to padding rows",
+    "moe_decode_held_assignments": "moe_held_assignments of decode passes",
+    "moe_decode_experts_touched": "moe_experts_touched of decode passes",
 }
 
 
@@ -96,6 +102,9 @@ class EngineMetrics:
         self._moe_drop_rate = self.registry.gauge(
             "moe_drop_rate", "fraction of routed assignments dropped at "
             "expert capacity (cumulative)")
+        self._moe_max_load = self.registry.gauge(
+            "moe_max_expert_load", "rows of the most loaded held expert in "
+            "one MoE layer of one pass (peak: the run's largest)")
         self.uncertainty = UncertaintyTelemetry(self.registry)
         self.records: List[RequestRecord] = []
         self.occupancy_trace: List[int] = []
@@ -216,12 +225,25 @@ class EngineMetrics:
     def on_draft_pass(self, n: int = 1) -> None:
         self._c["draft_passes"].inc(n)
 
-    def on_moe_drop(self, dropped: float, assignments: float) -> None:
-        """One MoE forward's drop accounting: ``dropped`` of
-        ``assignments`` routed (token, expert) pairs hit a full expert and
-        were zeroed. Updates the cumulative ``moe_drop_rate`` gauge."""
-        self._c["moe_dropped_assignments"].inc(int(dropped))
-        self._c["moe_assignments"].inc(int(assignments))
+    def on_moe(self, *, decode: bool, assignments: int, held: int,
+               max_load: int, touched: int, pad_routed: int,
+               dropped: int) -> None:
+        """One MoE pass's routing counters (``nn.moe.zero_aux``, summed over
+        layers, max load maxed): ``assignments`` routed of real tokens,
+        ``held`` of them to experts held here, ``dropped`` of those left
+        uncomputed, the largest ``max_load`` of a held expert in one layer,
+        the held experts ``touched`` by any row, and ``pad_routed``
+        assignments to padding rows; ``decode`` passes also count apart.
+        Updates the cumulative ``moe_drop_rate`` gauge."""
+        self._c["moe_dropped_assignments"].inc(dropped)
+        self._c["moe_assignments"].inc(assignments)
+        self._c["moe_held_assignments"].inc(held)
+        self._c["moe_experts_touched"].inc(touched)
+        self._c["moe_padding_routed"].inc(pad_routed)
+        if decode:
+            self._c["moe_decode_held_assignments"].inc(held)
+            self._c["moe_decode_experts_touched"].inc(touched)
+        self._moe_max_load.set(max_load)
         total = self._c["moe_assignments"].value
         if total:
             self._moe_drop_rate.set(
@@ -346,6 +368,12 @@ class EngineMetrics:
             # MoE routing gauges (all zero on dense families)
             "moe_dropped_assignments": c["moe_dropped_assignments"],
             "moe_assignments": c["moe_assignments"],
+            "moe_held_assignments": c["moe_held_assignments"],
+            "moe_experts_touched": c["moe_experts_touched"],
+            "moe_padding_routed": c["moe_padding_routed"],
+            "moe_decode_held_assignments": c["moe_decode_held_assignments"],
+            "moe_decode_experts_touched": c["moe_decode_experts_touched"],
+            "moe_max_expert_load": int(self._moe_max_load._solo().peak),
             "moe_drop_rate": c["moe_dropped_assignments"] / max(
                 c["moe_assignments"], 1),
         }

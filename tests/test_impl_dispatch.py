@@ -168,12 +168,17 @@ def test_lm_forward_parity(arch):
     params = svi_to_pfp(lm.init_params(cfg, jax.random.fold_in(KEY, 5)))
     tokens = {"tokens": jax.random.randint(jax.random.fold_in(KEY, 6),
                                            (2, 16), 0, cfg.vocab_size)}
-    lx, _, _ = lm.forward(params, cfg, tokens,
-                          Context(mode=Mode.PFP, impl="xla"))
-    lk, _, _ = lm.forward(params, cfg, tokens,
-                          Context(mode=Mode.PFP, impl="kernel"))
-    _assert_close(lx.mean, lk.mean, rtol=1e-3, atol=1e-4)
-    _assert_close(lx.var, lk.var, rtol=1e-2, atol=1e-5)
+    # the training path (capacity dispatch for MoE) and the inference
+    # path (dropless grouped experts)
+    for aux_loss in (True, False):
+        lx, _, _ = lm.forward(params, cfg, tokens,
+                              Context(mode=Mode.PFP, impl="xla"),
+                              moe_aux_loss=aux_loss)
+        lk, _, _ = lm.forward(params, cfg, tokens,
+                              Context(mode=Mode.PFP, impl="kernel"),
+                              moe_aux_loss=aux_loss)
+        _assert_close(lx.mean, lk.mean, rtol=1e-3, atol=1e-4)
+        _assert_close(lx.var, lk.var, rtol=1e-2, atol=1e-5)
 
 
 def test_lm_custom_positions_parity():

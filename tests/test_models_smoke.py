@@ -93,17 +93,21 @@ def test_svi_train_step_decreases_nothing_nan(models, arch):
     assert int(state.step) == 2
 
 
-@pytest.mark.parametrize("arch", ["granite-8b", "gemma-7b"])
+@pytest.mark.parametrize("arch", ["granite-8b", "gemma-7b",
+                                  "deepseek-moe-16b"])
 def test_prefill_then_decode_consistent(models, arch):
     """Prefill state + one decode step == full forward on the extended seq
-    (PFP mean path, tolerance for bf16-free fp32 run)."""
+    (PFP mean path, tolerance for bf16-free fp32 run). The MoE model
+    serves dropless, so no token's routing depends on the others'."""
     cfg, params = models[arch]
     params_pfp = svi_to_pfp(params)
     ctx = Context(mode=Mode.PFP)
     toks = jax.random.randint(KEY, (B, T + 1), 0, cfg.vocab_size)
 
-    # full forward over T+1 tokens
-    full, _, _ = lm.forward(params_pfp, cfg, {"tokens": toks}, ctx)
+    # full forward over T+1 tokens (the inference path, as prefill and
+    # decode run)
+    full, _, _ = lm.forward(params_pfp, cfg, {"tokens": toks}, ctx,
+                            moe_aux_loss=False)
 
     # prefill T, then decode token T
     last, states = lm.prefill(params_pfp, cfg, {"tokens": toks[:, :T]}, ctx,
@@ -138,7 +142,10 @@ def test_param_counts_sane():
     granite = get_config("granite-8b").param_count()
     assert 7e9 < granite < 9.5e9, granite
     moe = get_config("deepseek-moe-16b")
-    assert 1.3e10 < moe.param_count() < 2.2e10, moe.param_count()
-    assert moe.active_param_count() < 0.4 * moe.param_count()
+    # 16.4B parameters, 2.8B active (arXiv:2401.06066): the dense layer 0
+    # is 10944 wide, the router 64 wide, 6 routed + 2 shared active
+    assert moe.dense_ffn_width == 10944 and moe.router_width == 64
+    assert 1.6e10 < moe.param_count() < 1.7e10, moe.param_count()
+    assert 2.7e9 < moe.active_param_count() < 2.9e9
     vision = get_config("llama-3.2-vision-90b").param_count()
     assert 7e10 < vision < 1.1e11, vision

@@ -16,7 +16,11 @@ all-to-all dispatch (kernels/pfp_moe.py, core/dispatch.py, nn/moe.py):
   * PFP moments through the routed block match Monte-Carlo weight
     sampling (SVI forwards) within CLT bands;
   * the aux accounting is exact: moe_dropped equals the independently
-    recomputed capacity-overflow count, zero when capacity is ample.
+    recomputed capacity-overflow count, zero when capacity is ample;
+  * the dropless serving path: the grouped-expert kernel matches its
+    per-tile oracle, four expert shares add up to the uncut layer, and
+    every real token's assignments are computed while padding rows get
+    none (the capacity path drops them).
 """
 import os
 import subprocess
@@ -127,17 +131,21 @@ def _moe_fixture(key, *, gated, d=16, ff=32, n_e=4, s=12, sigma=1e-2):
 
 @pytest.mark.parametrize("gated", [True, False])
 def test_moe_apply_impl_parity(gated):
+    # both paths: training (capacity, batched experts) and inference
+    # (dropless, grouped experts, no load-balance loss term)
     params, x = _moe_fixture(jax.random.fold_in(KEY, 4), gated=gated)
-    outs = {}
-    for impl in ("xla", "kernel"):
-        ctx = Context(mode=Mode.PFP, impl=impl)
-        outs[impl], aux = moe.moe_apply(params, x, ctx, num_experts=4,
-                                        top_k=2, capacity_factor=1.25,
-                                        aux_loss=False)
-        assert float(aux["loss"]) == 0.0  # aux-loss-free inference path
-    _assert_close(outs["xla"].mean, outs["kernel"].mean,
-                  rtol=1e-4, atol=1e-5)
-    _assert_close(outs["xla"].var, outs["kernel"].var, rtol=1e-3, atol=1e-5)
+    for aux_loss in (True, False):
+        outs = {}
+        for impl in ("xla", "kernel"):
+            ctx = Context(mode=Mode.PFP, impl=impl)
+            outs[impl], aux = moe.moe_apply(params, x, ctx, num_experts=4,
+                                            top_k=2, capacity_factor=1.25,
+                                            aux_loss=aux_loss)
+            assert (float(aux["loss"]) == 0.0) == (not aux_loss)
+        _assert_close(outs["xla"].mean, outs["kernel"].mean,
+                      rtol=1e-4, atol=1e-5)
+        _assert_close(outs["xla"].var, outs["kernel"].var,
+                      rtol=1e-3, atol=1e-5)
 
 
 def test_moe_kernel_impl_reaches_batched_pallas():
@@ -245,28 +253,31 @@ def test_moe_pfp_moments_vs_monte_carlo():
                           num_shared=1, gated=True, sigma_init=0.1)
     params = dict(params, router={"w": params["router"]["w"]["mu"]})
     x = jax.random.normal(jax.random.fold_in(key, 1), (1, s, d), jnp.float32)
-    kw = dict(num_experts=n_e, top_k=2, capacity_factor=2.0, aux_loss=False)
-
-    pfp_out, _ = moe.moe_apply(
-        params, GaussianTensor(x, jnp.square(x), SRM),
-        Context(mode=Mode.PFP), **kw)
-
     n_mc = 4000
-
-    def one(k):
-        out, _ = moe.moe_apply(params, x, Context(mode=Mode.SVI, key=k),
-                               **kw)
-        return out
-
-    samples = jax.lax.map(
-        jax.jit(one), jax.random.split(jax.random.fold_in(key, 2), n_mc))
-    mc_mean = np.asarray(jnp.mean(samples, axis=0))
-    mc_var = np.asarray(jnp.var(samples, axis=0))
     band = 10.0 / np.sqrt(n_mc)
-    np.testing.assert_allclose(np.asarray(pfp_out.mean), mc_mean,
-                               atol=band * np.sqrt(mc_var.max() + 1e-6))
-    np.testing.assert_allclose(np.asarray(pfp_out.var), mc_var,
-                               rtol=0.3, atol=band * mc_var.max())
+    # both paths: training (capacity 2.0 keeps every assignment) and the
+    # dropless inference path, whose sampled weights run the grouped op's
+    # xla chain at zero weight variance
+    for aux_loss in (True, False):
+        kw = dict(num_experts=n_e, top_k=2, capacity_factor=2.0,
+                  aux_loss=aux_loss)
+        pfp_out, _ = moe.moe_apply(
+            params, GaussianTensor(x, jnp.square(x), SRM),
+            Context(mode=Mode.PFP), **kw)
+
+        def one(k, kw=kw):
+            out, _ = moe.moe_apply(params, x, Context(mode=Mode.SVI, key=k),
+                                   **kw)
+            return out
+
+        samples = jax.lax.map(
+            jax.jit(one), jax.random.split(jax.random.fold_in(key, 2), n_mc))
+        mc_mean = np.asarray(jnp.mean(samples, axis=0))
+        mc_var = np.asarray(jnp.var(samples, axis=0))
+        np.testing.assert_allclose(np.asarray(pfp_out.mean), mc_mean,
+                                   atol=band * np.sqrt(mc_var.max() + 1e-6))
+        np.testing.assert_allclose(np.asarray(pfp_out.var), mc_var,
+                                   rtol=0.3, atol=band * mc_var.max())
 
 
 # ---------------------------------------------------------------------------
@@ -304,9 +315,119 @@ def test_drop_accounting_matches_independent_replay(capacity_factor,
                              n_e=n_e)
     _, aux = moe.moe_apply(params, x, Context(mode=Mode.PFP),
                            num_experts=n_e, top_k=top_k,
-                           capacity_factor=capacity_factor, aux_loss=False)
+                           capacity_factor=capacity_factor, aux_loss=True)
     assert float(aux["moe_assignments"]) == s * top_k
     want = _expected_drops(params, x.mean, num_experts=n_e, top_k=top_k,
                            capacity_factor=capacity_factor)
     assert float(aux["moe_dropped"]) == want
     assert (want > 0) == overflow
+
+
+# ---------------------------------------------------------------------------
+# The dropless serving path: grouped kernel, expert share, no drops
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("e,k,n,dtype", [
+    (3, 40, 48, jnp.float32),
+    (3, 40, 48, jnp.bfloat16),
+    # deepseek's down projection: 11 K blocks of 128, two N blocks
+    (2, 1408, 2048, jnp.bfloat16),
+], ids=["f32", "bf16", "deep"])
+def test_grouped_kernel_matches_oracle(e, k, n, dtype):
+    bm, tiles = 16, 4
+    kx, kw = jax.random.split(jax.random.fold_in(KEY, 20))
+    mu_x = jax.random.normal(kx, (bm * tiles, k), jnp.float32)
+    mu_w = jax.random.normal(kw, (e, k, n), jnp.float32) * k ** -0.5
+    srm_x, srm_w = mu_x ** 2 + 0.3, mu_w ** 2 + 0.01
+    # three live tiles (experts 2, 0, 0 — one expert over two tiles),
+    # then a skipped one that must come out zero
+    tile_expert = jnp.asarray([e - 1, 0, 0, 0], jnp.int32)
+    num_tiles = jnp.asarray(3, jnp.int32)
+    args = (mu_x.astype(dtype), srm_x.astype(dtype), mu_w, srm_w,
+            tile_expert, num_tiles)
+    want = ops.pfp_dense_grouped(*args, block_m=bm, impl="xla")
+    got = ops.pfp_dense_grouped(*args, block_m=bm, impl="kernel")
+    # The means differ in summation order only (bf16 products are exact
+    # in fp32). So do fp32 variances; with bf16 rows the kernel rounds the
+    # squares x_mu^2, w_mu^2 to bf16, as the dense kernel does, where the
+    # oracle squares in fp32, and the SRM form's cancellation magnifies
+    # that to about 1.5% of the largest variance.
+    var_tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    _assert_close(got[0], want[0], rtol=0.0,
+                  atol=1e-5 * float(jnp.max(jnp.abs(want[0]))))
+    _assert_close(got[1], want[1], rtol=0.0,
+                  atol=var_tol * float(jnp.max(jnp.abs(want[1]))))
+    assert not np.asarray(got[0][3 * bm:]).any()
+    assert not np.asarray(got[1][3 * bm:]).any()
+
+
+def _routed_fixture(key, *, d=16, ff=8, n_e=16, s=24, sigma=1e-2):
+    params = moe.moe_init(key, d_model=d, d_ff=ff, num_experts=n_e,
+                          num_shared=2, gated=True, sigma_init=sigma)
+    mu = jax.random.normal(jax.random.fold_in(key, 1), (1, s, d), jnp.float32)
+    return params, GaussianTensor(mu, mu ** 2 + 0.1, SRM)
+
+
+@pytest.mark.parametrize("impl", ["xla", "kernel"])
+def test_expert_shares_add_up_to_the_uncut_layer(impl):
+    """Four chips' shares of 4 experts each (the router 16 wide, top-6,
+    un-normalised gates), summed with the shared experts counted once,
+    are the uncut layer: mean and variance."""
+    from repro.nn.mlp import mlp_apply
+
+    params, x = _routed_fixture(jax.random.fold_in(KEY, 21))
+    ctx = Context(mode=Mode.PFP, impl=impl)
+    kw = dict(top_k=6, norm_topk_prob=False, aux_loss=False,
+              routed_experts=16)
+    whole, whole_aux = moe.moe_apply(params, x, ctx, num_experts=16, **kw)
+    mean = mlp_apply(params["shared"], x, ctx).mean
+    var = mlp_apply(params["shared"], x, ctx).var
+    held = 0.0
+    for r in range(4):
+        share = {"router": params["router"],
+                 "experts": jax.tree_util.tree_map(
+                     lambda a, r=r: a[4 * r:4 * r + 4], params["experts"])}
+        out, aux = moe.moe_apply(share, x, ctx, num_experts=4,
+                                 expert_offset=4 * r, **kw)
+        mean, var = mean + out.mean, var + out.var
+        held += float(aux["moe_held"])
+        assert float(aux["moe_dropped"]) == 0
+    _assert_close(mean, whole.mean, rtol=0.0, atol=1e-5)
+    _assert_close(var, whole.var, rtol=0.0, atol=1e-6)
+    assert held == float(whole_aux["moe_held"]) == 24 * 6
+    assert float(whole_aux["moe_assignments"]) == 24 * 6
+
+
+def test_dropless_routes_every_real_token_and_no_padding():
+    """Every token's first choice is expert 3 (24 tokens: capacity 1.25
+    would keep 11 of them), and the last 5 rows are padding: the dropless
+    path computes every real token's 6 assignments, gives the padding
+    rows none (their routed part is zero), and reads 0 dropped."""
+    from repro.nn.mlp import mlp_apply
+
+    params, x = _routed_fixture(jax.random.fold_in(KEY, 22))
+    mu = x.mean.at[..., 0].set(3.0)
+    x = GaussianTensor(mu, mu ** 2 + 0.1, SRM)
+    w = params["router"]["w"]["mu"].at[0, 3].set(20.0)
+    params = dict(params, router={"w": dict(params["router"]["w"], mu=w)})
+    valid = jnp.arange(24)[None, :] < 19
+    ctx = Context(mode=Mode.PFP, impl="kernel")
+    kw = dict(num_experts=16, top_k=6, norm_topk_prob=False)
+    out, aux = moe.moe_apply(params, x, ctx, aux_loss=False, valid=valid,
+                             **kw)
+    # the capacity (training) path drops most of expert 3's tokens ...
+    capped, capped_aux = moe.moe_apply(params, x, ctx, capacity_factor=1.25,
+                                       aux_loss=True, **kw)
+    assert float(capped_aux["moe_dropped"]) > 0
+    # ... ample capacity drops none: the per-token routed result
+    want, want_aux = moe.moe_apply(params, x, ctx, capacity_factor=100.0,
+                                   aux_loss=True, **kw)
+    assert float(want_aux["moe_dropped"]) == 0
+    _assert_close(out.mean[:, :19], want.mean[:, :19], rtol=0.0, atol=1e-5)
+    _assert_close(out.var[:, :19], want.var[:, :19], rtol=0.0, atol=1e-6)
+    shared = mlp_apply(params["shared"], x, ctx)
+    _assert_close(out.mean[:, 19:], shared.mean[:, 19:], rtol=0.0, atol=1e-6)
+    _assert_close(out.var[:, 19:], shared.var[:, 19:], rtol=0.0, atol=1e-6)
+    assert float(aux["moe_dropped"]) == 0
+    assert float(aux["moe_pad_routed"]) == 0
+    assert float(aux["moe_held"]) == float(aux["moe_assignments"]) == 19 * 6
+    assert float(aux["moe_max_load"]) == 19

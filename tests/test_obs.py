@@ -712,3 +712,71 @@ class TestFleetIntegration:
         assert sum(r["event"] == "handoff" for r in recs) == 4
         for rec in recs:
             assert validate(rec, TRACE_EVENT_SCHEMA) == []
+
+
+# ---------------------------------------------------------------------------
+# MoE routing counters: reduced on the device, read in the pass's sync
+# ---------------------------------------------------------------------------
+class TestMoeCounters:
+    @pytest.fixture(scope="class")
+    def moe_spans(self, tmp_path_factory):
+        """A reduced deepseek-moe (8 held of 16 routed experts, top-6) on
+        a paged engine under a profiler session."""
+        cfg = dataclasses.replace(reduced_config("deepseek-moe-16b"),
+                                  num_experts=8, routed_experts=16,
+                                  expert_offset=8, top_k=6, sigma_init=1e-3)
+        params = svi_to_pfp(lm.init_params(cfg, jax.random.PRNGKey(0)))
+        return _profiled(tmp_path_factory.mktemp("moe"), lambda: _serve(
+            cfg, params, _trace_reqs(), page_size=4))
+
+    def test_registry_counts_dropless_routing(self, moe_spans):
+        (eng, s), _ = moe_spans
+        # every real row a pass computes routes to 6 of 16 experts, 8 of
+        # them held here
+        assert s["moe_assignments"] > 0 and s["moe_assignments"] % 6 == 0
+        assert 0 < s["moe_held_assignments"] < s["moe_assignments"]
+        assert s["moe_dropped_assignments"] == 0
+        assert s["moe_padding_routed"] == 0
+        assert s["moe_drop_rate"] == 0
+        assert 0 < s["moe_max_expert_load"] <= s["moe_held_assignments"]
+
+    def test_pass_spans_carry_the_counters(self, moe_spans):
+        (eng, s), spans = moe_spans
+        # A prefill round's counters stay on the device: the route's next
+        # pfp.sync reads them (moe_prefill_*); a decode pass's come back
+        # in one transfer after it (moe_* on pfp.decode_step). No prefill
+        # round waits for the device.
+        for sp in _named(spans, "prefill_round"):
+            assert not any(k.startswith("moe_") for k in sp["args"])
+        reads = [(sp["args"], "moe_prefill_") for sp in _named(spans, "sync")
+                 if "moe_prefill_held" in sp["args"]]
+        decodes = _named(spans, "decode_step")
+        assert reads and decodes
+        reads += [(sp["args"], "moe_") for sp in decodes]
+        for a, p in reads:
+            assert a[p + "dropped"] == 0 and a[p + "pad_routed"] == 0
+            assert 0 <= a[p + "max_load"] <= a[p + "held"] <= \
+                a[p + "assignments"]
+            assert a[p + "touched"] <= a[p + "held"]
+        assert sum(a[p + "held"] for a, p in reads) == \
+            s["moe_held_assignments"]
+        assert sum(a[p + "assignments"] for a, p in reads) == \
+            s["moe_assignments"]
+        assert max(a[p + "max_load"] for a, p in reads) == \
+            s["moe_max_expert_load"]
+        assert sum(a[p + "touched"] for a, p in reads) == \
+            s["moe_experts_touched"]
+        for key, arg in (("moe_decode_held_assignments", "moe_held"),
+                         ("moe_decode_experts_touched", "moe_touched")):
+            assert s[key] == sum(sp["args"][arg] for sp in decodes)
+        # a decode pass routes one row per active slot: padding rows of
+        # idle slots get nothing
+        for sp in decodes:
+            assert sp["args"]["moe_assignments"] == 6 * sp["args"]["active"]
+
+    def test_dense_passes_carry_no_counters(self, paged_spans):
+        _, s, spans = paged_spans
+        for sp in (_named(spans, "prefill_round") + _named(spans, "sync")
+                   + _named(spans, "decode_step")):
+            assert not any(k.startswith("moe_") for k in sp["args"])
+        assert s["moe_assignments"] == 0

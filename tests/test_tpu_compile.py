@@ -28,7 +28,9 @@ from repro.kernels.pfp_attention import (pfp_attention_cache_pallas,
 from repro.kernels.pfp_dense import pfp_dense_pallas
 from repro.kernels.pfp_fused import pfp_norm_dense_act_pallas
 from repro.kernels.pfp_maxpool import pfp_maxpool2d_pallas
-from repro.kernels.pfp_moe import pfp_dense_batched_pallas
+from repro.kernels.pfp_moe import (pfp_dense_batched_pallas,
+                                   pfp_dense_grouped_pallas)
+from repro.nn.moe import row_tile
 from repro.kernels.pfp_norms import pfp_rmsnorm_pallas
 from repro.tuning import search
 
@@ -182,6 +184,24 @@ def test_dense_batched_moe_widths(one_chip, which):
                                                          **blocks),
              one_chip, ((e, c, k), F32), ((e, c, k), F32),
              ((e, k, np_), F32), ((e, k, np_), F32))
+
+
+@pytest.mark.parametrize("tokens", [16, 4096])
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["up", "down"])
+def test_dense_grouped_moe_widths(one_chip, tokens, k, n):
+    """deepseek-moe-16b's grouped expert GEMM at one chip's share (16 held
+    experts, top-6): bf16 rows in the worst-case buffer nn/moe.py lays
+    out, fp32 expert weights read as stored, at decode (16 tokens) and a
+    prefill round (4,096 rows)."""
+    held, top_k = 16, 6
+    bm = row_tile(tokens * top_k)
+    m = _round_up(tokens * top_k + held * (bm - 1), bm)
+    bk, bn = ops.grouped_blocks(k, n, 4)
+    _compile(lambda a, b, w, s, te, nt: pfp_dense_grouped_pallas(
+        a, b, w, s, te, nt, block_m=bm, block_n=bn, block_k=bk),
+        one_chip, ((m, k), BF16), ((m, k), BF16), ((held, k, n), F32),
+        ((held, k, n), F32), ((m // bm,), I32), ((1,), I32))
 
 
 def test_fused_norm_dense_act(one_chip):
