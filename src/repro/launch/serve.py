@@ -450,7 +450,7 @@ def serving_parts(args, cfg, params, mesh, tracer=None) -> ServingParts:
         return trace
 
     def build_engine(speculate_k):
-        return Engine(
+        engine = Engine(
             cfg, params,
             # bf16 activations, mirroring the decode_* dry-run programs
             # (serving/decode.py) whose executed version this driver is
@@ -464,6 +464,9 @@ def serving_parts(args, cfg, params, mesh, tracer=None) -> ServingParts:
                          prefix_retention_pages=args.prefix_retention,
                          speculate_k=speculate_k),
             router=router, scheduler=scheduler, mesh=mesh, tracer=tracer)
+        # every prefill width compiled now, before any request is timed
+        engine.warm_prefill()
+        return engine
 
     return ServingParts(build_engine, make_trace, router, sched_cfg, max_len)
 

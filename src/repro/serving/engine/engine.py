@@ -428,13 +428,15 @@ class Engine:
         return (jnp.where(active[:, None], mean, lm_mean),
                 jnp.where(active[:, None], var, lm_var), new_states, aux)
 
-    def _batch_chunk_step(self, params, inputs, states, out_idx, done,
+    def _batch_chunk_step(self, params, inputs, states, rows, out_idx, done,
                           lm_mean, lm_var):
         """One batched multi-slot prefill round over the page pool:
-        (B, C) window tokens in, per-slot logit (mean, var) rows gathered
-        at each slot's own last-real-token index, merged into the logit
-        buffers only where ``done`` (prefill completed this round). The
-        4th output is the MoE aux dict (routing counters; zeros on dense
+        (w, C) window tokens in, one row per planned slot (``rows``: the
+        slot of each; pad rows carry the out-of-range slot count), each
+        row's logit (mean, var) gathered at its own last-real-token index
+        and scattered into its slot's row of the (slots, V) logit buffers
+        only where ``done`` (prefill completed this round). The 4th
+        output is the MoE aux dict (routing counters; zeros on dense
         families)."""
         logits, aux, new_states = lm.decode_step_with_aux(
             params, self.cfg, inputs, states, self._ctx())
@@ -443,8 +445,9 @@ class Engine:
             mean.astype(jnp.float32), out_idx[:, None, None], axis=1)[:, 0]
         var = jnp.take_along_axis(
             var.astype(jnp.float32), out_idx[:, None, None], axis=1)[:, 0]
-        return (jnp.where(done[:, None], mean, lm_mean),
-                jnp.where(done[:, None], var, lm_var), new_states, aux)
+        dest = jnp.where(done, rows, lm_mean.shape[0])
+        return (lm_mean.at[dest].set(mean, mode="drop"),
+                lm_var.at[dest].set(var, mode="drop"), new_states, aux)
 
     def _draft_steps(self, params, head, positions, states, table):
         """K-1 mean-only (zero-variance) draft decode steps over the shared
@@ -706,9 +709,10 @@ class Engine:
         plan = self.scheduler.plan_prefill(self._prefill_pending())
         c = self.scheduler.config.prefill_chunk
         for slot, n in plan:
-            with span("prefill_round", rows=n,
-                      width=c if self._static_chunks else n):
+            width = c if self._static_chunks else n
+            with span("prefill_round", rows=n, slots=1, width=width):
                 self._prefill_chunk(slot, n)
+            self.metrics.on_prefill_round(width)
         return len(plan)
 
     def _prefill_chunk(self, slot: int, n: int) -> None:
@@ -765,18 +769,75 @@ class Engine:
     def _prefill_paged(self) -> int:
         """Batched multi-slot prefill over the shared page pool: every
         round of the scheduler's plan (at most one chunk per slot) runs as
-        ONE lockstep pass at the full slot-batch width — a single compiled
-        shape regardless of how many slots are prefilling. Unplanned rows
-        carry cache_len 0, so their writes land on the trash page and
-        their outputs are discarded."""
-        width = self.config.slots * self.scheduler.config.prefill_chunk
+        ONE lockstep pass over just its planned slots, padded to the
+        power of two :meth:`_round_width` gives — at most log2(slots) + 1
+        compiled shapes, all warmed by :meth:`warm_prefill`."""
+        c = self.scheduler.config.prefill_chunk
         rounds = 0
         for rnd in self.scheduler.plan_prefill_rounds(self._prefill_pending()):
             with span("prefill_round") as sp:
-                rows = self._prefill_round(rnd)
-                sp.set_metadata(rows=rows, width=width if rows else 0)
-            rounds += rows > 0
+                rows, k = self._prefill_round(rnd)
+                width = self._round_width(k) * c if k else 0
+                sp.set_metadata(rows=rows, slots=k, width=width)
+            if k:
+                self.metrics.on_prefill_round(width)
+                rounds += 1
         return rounds
+
+    def _round_width(self, k: int) -> int:
+        """Batch rows of a prefill round carrying ``k`` slots: the least
+        power of two >= k, at most the slot count."""
+        return min(1 << (k - 1).bit_length(), self.config.slots)
+
+    def _round_widths(self) -> List[int]:
+        """Every width :meth:`_round_width` can give (the ladder)."""
+        return sorted({self._round_width(k)
+                       for k in range(1, self.config.slots + 1)})
+
+    def warm_prefill(self) -> None:
+        """Compile the prefill round at every width of the ladder, one
+        pass each on pad rows only: cache_len 0 sends every write to the
+        trash page, and ``done`` false leaves every logit row as it was,
+        so the pool's pages 1 and up and the logit buffers come out
+        bit-identical. The outputs are kept, so each later pass sees its
+        arguments placed as a served round does; the first width runs
+        again last, as a fresh pool and fresh logit buffers are placed
+        otherwise than a pass's outputs. Contiguous engines prefill at one
+        shape and need none of this."""
+        if not self.paged:
+            return
+        widths = self._round_widths()
+        for w in widths + widths[:1]:
+            self._launch_round(self._pad_round(w))
+        jax.block_until_ready(self._lm_mean)
+
+    def _pad_round(self, w: int) -> dict:
+        """Host inputs of a ``w``-row prefill round, every row a pad row:
+        slot index ``slots`` (no logit row), cache_len 0 and a zero table
+        row (writes reach only the trash page), ``done`` false."""
+        c = self.scheduler.config.prefill_chunk
+        return {"slots": np.full(w, self.config.slots, np.int32),
+                "tokens": np.zeros((w, c), np.int32),
+                "positions": np.tile(np.arange(c, dtype=np.int32), (w, 1)),
+                "cache_len": np.zeros(w, np.int32),
+                "write_start": np.zeros(w, np.int32),
+                "out_idx": np.zeros(w, np.int32),
+                "done": np.zeros(w, bool)}
+
+    def _launch_round(self, r: dict):
+        """One prefill pass over the host inputs ``r``
+        (:meth:`_pad_round`'s keys); updates the pool and the logit
+        buffers and returns the pass's MoE aux dict."""
+        inputs = {k: jnp.asarray(r[k]) for k in
+                  ("tokens", "positions", "cache_len", "write_start")}
+        inputs["page_table"] = self.pool.device_table(r["slots"])
+        self._lm_mean, self._lm_var, self.pool.states, aux = \
+            self._batch_chunk_fn(self.params, inputs, self.pool.states,
+                                 jnp.asarray(r["slots"]),
+                                 jnp.asarray(r["out_idx"]),
+                                 jnp.asarray(r["done"]),
+                                 self._lm_mean, self._lm_var)
+        return aux
 
     def _count_moe(self, counters, *, decode: bool) -> dict:
         """Passes' MoE routing counters, read from the device, into the
@@ -808,19 +869,15 @@ class Engine:
         return [np.asarray(a) for a in got]
 
     def _prefill_round(self, rnd):
-        """One round of the plan (``(slot, n)`` chunks) as one pass at the
-        slot-batch width; returns the prompt tokens it carried (0 = every
-        planned slot was preempted, and nothing ran). An MoE pass's
-        routing counters stay on the device until the route's next sync
-        reads them (:meth:`_sync`)."""
-        b = self.config.slots
+        """One round of the plan (``(slot, n)`` chunks) as one pass over
+        its planned slots, at the width :meth:`_round_width` gives; pad
+        rows carry slot index ``slots``, cache_len 0 and a zero table row,
+        so their writes reach only the trash page and their logits no
+        buffer row. Returns (prompt tokens carried, slots carried); (0, 0)
+        = every planned slot was preempted, and nothing ran. An MoE
+        pass's routing counters stay on the device until the route's next
+        sync reads them (:meth:`_sync`)."""
         c = self.scheduler.config.prefill_chunk
-        tokens = np.zeros((b, c), np.int32)
-        positions = np.tile(np.arange(c, dtype=np.int32), (b, 1))
-        cache_len = np.zeros(b, np.int32)
-        write_start = np.zeros(b, np.int32)
-        out_idx = np.zeros(b, np.int32)
-        done = np.zeros(b, bool)
         planned = []
         for slot, n in rnd:
             sl = self._slots[slot]
@@ -833,71 +890,56 @@ class Engine:
                 # request back to the queue (it keeps its progress)
                 self._preempt(slot)
                 continue
+            planned.append((slot, n, end))
+        # A planned slot may have been preempted by a LATER slot's
+        # _make_room in the same round: drop it and keep its logit rows
+        # untouched.
+        planned = [p for p in planned if self._slots[p[0]] is not None]
+        if not planned:
+            return 0, 0
+        r = self._pad_round(self._round_width(len(planned)))
+        for i, (slot, n, end) in enumerate(planned):
+            sl = self._slots[slot]
             lo = max(0, end - c)
             window = sl.prefill_tokens[lo:end]
-            tokens[slot, :len(window)] = window
-            positions[slot] = lo + np.arange(c, dtype=np.int32)
-            cache_len[slot] = end
+            r["slots"][i] = slot
+            r["tokens"][i, :len(window)] = window
+            r["positions"][i] = lo + np.arange(c, dtype=np.int32)
+            r["cache_len"][i] = end
             # The window may re-feed tokens below the shared-prefix
             # boundary — their writes are redirected to the trash page
             # (the shared pages already hold the identical rows).
-            write_start[slot] = sl.write_start
-            out_idx[slot] = len(window) - 1
-            done[slot] = end == len(sl.prefill_tokens)
-            planned.append((slot, n, end))
-        # A planned slot may have been preempted by a LATER slot's
-        # _make_room in the same round: drop it (its table row is
-        # already zeroed, so even its staged write would only reach
-        # the trash page) and keep its logit rows untouched.
-        dropped = [p for p in planned if self._slots[p[0]] is None]
-        for slot, _, _ in dropped:
-            cache_len[slot] = 0
-            done[slot] = False
-        planned = [p for p in planned if self._slots[p[0]] is not None]
-        if not planned:
-            return 0
+            r["write_start"][i] = sl.write_start
+            r["out_idx"][i] = len(window) - 1
+            r["done"][i] = end == len(sl.prefill_tokens)
         pre_states = self.pool.states  # escalation-replay snapshot
         #            (copy-on-write duplicates are already in it: every
         #            _ensure_pages above ran before this reference)
-        table = self.pool.device_table()
-        inputs = {
-            "tokens": jnp.asarray(tokens),
-            "positions": jnp.asarray(positions),
-            "cache_len": jnp.asarray(cache_len),
-            "write_start": jnp.asarray(write_start),
-            "page_table": table,
-        }
-        self._lm_mean, self._lm_var, self.pool.states, aux = \
-            self._batch_chunk_fn(self.params, inputs, self.pool.states,
-                                 jnp.asarray(out_idx),
-                                 jnp.asarray(done),
-                                 self._lm_mean, self._lm_var)
+        aux = self._launch_round(r)
         if self.cfg.family == "moe":
             self._moe_pending.append(aux)
         rows = sum(n for _, n, _ in planned)
         if self._tracer is not None:
             self._tracer.emit(self._step_idx, "prefill_round",
                               slots=len(planned), tokens=rows)
-        for slot, n, end in planned:
+        for i, (slot, n, end) in enumerate(planned):
             sl = self._slots[slot]
             sl.prefill_pos = end
             self.pool.positions[slot] = end
             self.metrics.on_prefill(n)
-            if done[slot]:
+            if r["done"][i]:
                 if sl.request.prefill_only:
                     self._finish(slot, "prefill", float(self._step_idx))
                     continue
                 sl.phase = "decode"
                 sl.last_input = int(sl.prefill_tokens[-1])
-                row = {
-                    "tokens": inputs["tokens"][slot:slot + 1],
-                    "positions": inputs["positions"][slot:slot + 1],
-                    "cache_len": inputs["cache_len"][slot:slot + 1],
-                    "write_start": inputs["write_start"][slot:slot + 1],
-                    "page_table": table[slot:slot + 1],
-                }
-                sl.replay = (pre_states, row, int(out_idx[slot]))
-        return rows
+                # built on the host: slicing the device inputs would
+                # compile one slice program per round width
+                row = {k: jnp.asarray(r[k][i:i + 1]) for k in
+                       ("tokens", "positions", "cache_len", "write_start")}
+                row["page_table"] = self.pool.device_table(r["slots"][i:i + 1])
+                sl.replay = (pre_states, row, int(r["out_idx"][i]))
+        return rows, len(planned)
 
     def _route_current(self, decode_slots):
         """Route every listed slot's CURRENT logits: one keyed uncertainty

@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import List, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from repro.obs.registry import MetricsRegistry, Stopwatch, percentile
 from repro.obs.uncertainty import UncertaintyTelemetry
@@ -58,6 +58,7 @@ _COUNTERS = {
     "escalations": "SVI second-opinion passes taken",
     "tokens_generated": "tokens served",
     "prefill_tokens": "prompt tokens prefilled",
+    "prefill_rows_run": "batch rows prefill passes computed (real + pad)",
     "steps": "engine steps",
     # paged-pool telemetry (stays zero on the contiguous layout)
     "preemptions": "slots evicted mid-flight under page pressure",
@@ -113,6 +114,7 @@ class EngineMetrics:
         self.page_trace: List[Tuple[int, ...]] = []
         self.escalation_batches: List[int] = []  # slots per batched SVI pass
         self.svi_pass_trace: List[int] = []      # SVI passes per engine step
+        self.prefill_round_widths: Dict[int, int] = {}  # rows -> passes
         self._svi_passes_prev = 0
         self._admit_times = {}     # uid -> (arrival_step, admit_step, wall_t0)
 
@@ -153,6 +155,13 @@ class EngineMetrics:
 
     def on_prefill(self, tokens: int) -> None:
         self._c["prefill_tokens"].inc(tokens)
+
+    def on_prefill_round(self, width: int) -> None:
+        """One prefill pass that computed ``width`` batch rows (its real
+        prompt tokens arrive through :meth:`on_prefill`)."""
+        self._c["prefill_rows_run"].inc(width)
+        self.prefill_round_widths[width] = \
+            self.prefill_round_widths.get(width, 0) + 1
 
     def on_token(self, n: int = 1) -> None:
         self._c["tokens_generated"].inc(n)
@@ -294,6 +303,12 @@ class EngineMetrics:
                 c["tokens_generated"], 1),
             "tokens_generated": c["tokens_generated"],
             "prefill_tokens": c["prefill_tokens"],
+            "prefill_rows_run": c["prefill_rows_run"],
+            # real prompt rows over the rows the prefill passes computed
+            "prefill_row_share": c["prefill_tokens"] / max(
+                c["prefill_rows_run"], 1),
+            "prefill_round_widths": dict(sorted(
+                self.prefill_round_widths.items())),
             "steps": c["steps"],
             "elapsed_s": elapsed,
             "throughput_tok_s": c["tokens_generated"] / max(elapsed, 1e-9),

@@ -490,13 +490,19 @@ class PagedDecodeStatePool:
     # -- device views -------------------------------------------------------
     def device_table(self, slots: Optional[np.ndarray] = None):
         """The page table as a device int32 array — (num_slots, P), or the
-        selected rows when ``slots`` is given (e.g. a replay's batch).
-        The full table is cached between mutations (alloc/evict/defrag),
-        so steady-state decode pays no per-step host-to-device upload."""
+        selected rows when ``slots`` is given (e.g. a replay's batch, or a
+        prefill round's, where a pad row's index ``num_slots`` reads a zero
+        row: every entry the trash page). The full table is cached between
+        mutations (alloc/evict/defrag), so steady-state decode pays no
+        per-step host-to-device upload."""
         import jax.numpy as jnp
 
         if slots is not None:
-            return jnp.asarray(self.page_table[slots], jnp.int32)
+            slots = np.asarray(slots)
+            real = slots < self.num_slots
+            rows = np.zeros((len(slots), self.page_table.shape[1]), np.int32)
+            rows[real] = self.page_table[slots[real]]
+            return jnp.asarray(rows)
         if self._device_table is None:
             # A copy: on the CPU backend jnp.asarray may alias the numpy
             # buffer, which the host edits in place while programs that

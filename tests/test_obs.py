@@ -50,18 +50,20 @@ def lm_setup():
 
 
 def _engine(cfg, params, *, tracer=None, page_size=None, prefix_sharing=False,
-            mi_continue=1e9, mi_abstain=2e9, speculate_k=0):
+            mi_continue=1e9, mi_abstain=2e9, speculate_k=0, slots=3,
+            prefill_budget=6):
     router = UncertaintyRouter(cfg, RouterConfig(mi_continue=mi_continue,
                                                  mi_abstain=mi_abstain))
     return Engine(cfg, params,
-                  EngineConfig(slots=3, max_len=MAX_LEN,
+                  EngineConfig(slots=slots, max_len=MAX_LEN,
                                num_uncertainty_samples=8, seed=0,
                                page_size=page_size,
                                prefix_sharing=prefix_sharing,
                                speculate_k=speculate_k),
                   router=router,
                   scheduler=RequestScheduler(
-                      SchedulerConfig(prefill_chunk=3, prefill_budget=6),
+                      SchedulerConfig(prefill_chunk=3,
+                                      prefill_budget=prefill_budget),
                       max_len=MAX_LEN),
                   tracer=tracer)
 
@@ -333,6 +335,22 @@ def paged_spans(lm_setup, tmp_path_factory):
     return eng, s, spans
 
 
+def _check_round_widths(rounds, s, width_of):
+    """Each prefill round's span: ``width`` the rows it ran for its
+    ``slots`` (``width_of``), its real ``rows`` within them; the engine's
+    summary sums the same rows and widths."""
+    assert rounds
+    for r in rounds:
+        a = r["args"]
+        assert a["width"] == width_of[a["slots"]], a
+        assert 0 < a["rows"] <= a["width"]
+    assert sum(r["args"]["rows"] for r in rounds) == s["prefill_tokens"]
+    assert s["prefill_rows_run"] == sum(r["args"]["width"] for r in rounds)
+    assert s["prefill_row_share"] == \
+        s["prefill_tokens"] / s["prefill_rows_run"]
+    assert sum(s["prefill_round_widths"].values()) == len(rounds)
+
+
 class TestSpans:
     def test_unknown_span_refused(self):
         with pytest.raises(ValueError):
@@ -357,16 +375,30 @@ class TestSpans:
     def test_prefill_round_rows_and_width(self, paged_spans):
         eng, s, spans = paged_spans
         rounds = _named(spans, "prefill_round")
-        # every round computes the whole slot batch at the chunk width
-        # (3 slots x 3 tokens) and carries at most one chunk per slot
-        assert rounds and all(r["args"]["width"] == 9 for r in rounds)
-        assert all(0 < r["args"]["rows"] <= 9 for r in rounds)
-        assert sum(r["args"]["rows"] for r in rounds) == s["prefill_tokens"]
+        # a round computes its planned slots, padded to a power of two at
+        # most the 3 slots, at the chunk width of 3, and carries at most
+        # one chunk per slot
+        _check_round_widths(rounds, s, {1: 3, 2: 6, 3: 9})
+        assert {r["args"]["slots"] for r in rounds} >= {1, 2}
         for step in _named(spans, "step"):
             inside = [r for r in rounds if _within(r, step)]
             assert step["args"]["rounds"] == len(inside)
         assert any(st["args"]["rounds"] == 0
                    for st in _named(spans, "step"))
+
+    def test_three_slot_round_runs_four_rows(self, lm_setup, tmp_path):
+        """Three prompts due at once on five slots, with a budget of three
+        chunks: the first round carries three slots and runs four rows."""
+        cfg, params = lm_setup
+        reqs = _trace_reqs(n=3)
+        for r in reqs:
+            r.arrival = 0.0
+        (eng, s), spans = _profiled(tmp_path, lambda: _serve(
+            cfg, params, reqs, page_size=4, slots=5, prefill_budget=9))
+        rounds = _named(spans, "prefill_round")
+        _check_round_widths(rounds, s, {1: 3, 2: 6, 3: 12})
+        assert rounds[0]["args"]["slots"] == 3
+        assert s["prefill_round_widths"][12] >= 1
 
     def test_itl_tokens_count_inter_token_gaps(self, paged_spans):
         eng, s, spans = paged_spans

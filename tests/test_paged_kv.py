@@ -290,6 +290,199 @@ def test_preempted_request_outlives_admission_deadline(lm_setup):
     assert all(len(r.generated) == 4 for r in eng.finished)
 
 
+# ---------------------------------------------------------------------------
+# Prefill rounds sized to their planned slots
+# ---------------------------------------------------------------------------
+def _checked_rounds(eng):
+    """Wrap ``eng``'s prefill round: each call must leave every page its
+    surviving slots do not own (the trash page aside) and the logit row
+    of every slot it did not finish bit-identical. Returns the list it
+    fills with (slots carried, batch rows run, slots decoding before)."""
+    run = eng._prefill_round
+    rounds = []
+
+    def checked(rnd):
+        pool0 = jax.device_get(eng.pool.states)
+        logits0 = [np.asarray(a) for a in eng.logit_buffers]
+        was = {s: eng._slots[s].phase for s, _ in rnd
+               if eng._slots[s] is not None}
+        decoding = eng.decoding
+        rows, k = run(rnd)
+        alive = [s for s, _ in rnd if eng._slots[s] is not None]
+        done = {s for s in alive
+                if was.get(s) == "prefill" and eng._slots[s].phase == "decode"}
+        owned = {p for s in alive for p in eng.pool.slot_pages[s]}
+        keep = [p for p in range(1, eng.pool.num_pages) if p not in owned]
+        for a, b in zip(jax.tree_util.tree_leaves(pool0),
+                        jax.tree_util.tree_leaves(
+                            jax.device_get(eng.pool.states))):
+            axis = a.ndim - 4           # (pages, kv heads, page rows, dh)
+            assert np.array_equal(np.take(a, keep, axis),
+                                  np.take(b, keep, axis))
+        others = [s for s in range(eng.config.slots) if s not in done]
+        for a, b in zip(logits0, eng.logit_buffers):
+            assert np.array_equal(a[others], np.asarray(b)[others])
+        rounds.append((k, eng._round_width(k) if k else 0, decoding))
+        return rows, k
+
+    eng._prefill_round = checked
+    return rounds
+
+
+def test_bucketed_prefill_round_touches_only_its_slots(lm_setup):
+    """Rounds run at the power of two of their planned slots: three slots
+    at width 4 (one pad row), one slot alone at width 1 while the others
+    decode. No round writes a page its slots do not own or a logit row it
+    did not finish, and the tokens are those of the same traffic served
+    one request at a time."""
+    cfg, params = lm_setup
+    eng = _engine(cfg, params, slots=4, max_len=16, page_size=2)
+    rounds = _checked_rounds(eng)
+    reqs = [Request(uid=u, prompt=np.full(4, 3 + u, np.int32),
+                    max_new_tokens=6) for u in range(3)]
+    for r in reqs:
+        eng.submit(r)
+    while eng.prefilling or len(eng.scheduler):
+        eng.step()
+    late = Request(uid=3, prompt=np.full(5, 6, np.int32), max_new_tokens=2)
+    eng.submit(late)
+    eng.run_until_idle(300)
+    eng.pool.check_invariants()
+    assert (2, 2, 0) in rounds and (3, 4, 0) in rounds
+    assert any(k == 1 and w == 1 and dec == 3 for k, w, dec in rounds)
+    s = eng.metrics.summary()
+    assert s["prefill_rows_run"] == 3 * sum(w for _, w, _ in rounds)
+    alone = {}
+    for r in reqs + [late]:
+        one = _engine(cfg, params, slots=4, max_len=16, page_size=2)
+        one.submit(Request(uid=r.uid, prompt=r.prompt,
+                           max_new_tokens=r.max_new_tokens))
+        one.run_until_idle(300)
+        alone[r.uid] = list(one.finished[0].generated)
+    assert {r.uid: list(r.generated) for r in eng.finished} == alone
+
+
+def test_bucketed_round_drops_a_slot_preempted_by_a_later_one(lm_setup):
+    """A round whose later (senior) slot finds no pages preempts the
+    junior slot planned before it: the junior is dropped from the round,
+    which runs the senior alone at width 1 and leaves the junior's logit
+    row and former pages untouched; both requests still finish."""
+    cfg, params = lm_setup
+    eng = _engine(cfg, params, slots=3, max_len=6, page_size=2,
+                  page_budget=3, reserve_pages=False)
+    for uid in (0, 1):
+        eng.submit(Request(uid=uid, prompt=np.full(4, 3 + uid, np.int32),
+                           max_new_tokens=1))
+    eng._admit(0.0)
+    senior, junior = (eng.pool.owner.index(u) for u in (0, 1))
+    rounds = _checked_rounds(eng)
+    assert eng._prefill_round([(junior, 3), (senior, 3)]) == (3, 1)
+    assert eng._slots[junior] is None and eng.metrics.preemptions == 1
+    assert rounds == [(1, 1, 0)]
+    eng.run_until_idle(300)
+    eng.pool.check_invariants()
+    assert sorted(r.uid for r in eng.finished) == [0, 1]
+    assert all(len(r.generated) == 1 for r in eng.finished)
+
+
+def _built_engine(cfg, params):
+    """An engine as ``launch/serve.py``'s ``build_engine`` makes it (with
+    its warm-up of every prefill width), on four slots; and its mesh."""
+    from repro.launch import serve
+    from repro.launch.mesh import make_mesh
+
+    args = serve.parse_args(["--impl", "xla", "--page-size", "2",
+                             "--batch", "4", "--prompt-len", "8",
+                             "--tokens", "3", "--prefill-chunk", "3"])
+    mesh = make_mesh((1, 1), ("data", "model"))
+    with mesh:
+        return serve.serving_parts(args, cfg, params, mesh).build_engine(0), \
+            mesh
+
+
+def _serve_four(eng, mesh):
+    reqs = [Request(uid=u, prompt=np.full(4, 3 + u, np.int32),
+                    max_new_tokens=3) for u in range(4)]
+    with mesh:
+        for r in reqs:
+            eng.submit(r)
+        eng.run_until_idle(300)
+        eng.submit(Request(uid=9, prompt=np.full(3, 9, np.int32),
+                           max_new_tokens=2))
+        eng.run_until_idle(300)
+    return {r.uid: (list(r.generated), [float(m) for m in r.mi_trace])
+            for r in eng.finished}
+
+
+def test_warm_prefill_leaves_pool_and_logits_bit_identical(lm_setup):
+    """The pad-only warm pass, run again mid-serving: the pool's pages 1
+    and up and the logit buffers come out bit-identical, and the served
+    tokens and MI are those of an engine that never ran it again."""
+    cfg, params = lm_setup
+    want = _serve_four(*_built_engine(cfg, params))
+    eng, mesh = _built_engine(cfg, params)
+    with mesh:
+        for u in range(4):
+            eng.submit(Request(uid=u, prompt=np.full(4, 3 + u, np.int32),
+                               max_new_tokens=3))
+        eng.step()
+        eng.step()
+        assert eng.decoding
+        pool0 = jax.device_get(eng.pool.states)
+        logits0 = [np.asarray(a) for a in eng.logit_buffers]
+        eng.warm_prefill()
+        for a, b in zip(jax.tree_util.tree_leaves(pool0),
+                        jax.tree_util.tree_leaves(
+                            jax.device_get(eng.pool.states))):
+            idx = [slice(None)] * a.ndim
+            idx[a.ndim - 4] = slice(1, None)     # every page but the trash
+            assert np.array_equal(a[tuple(idx)], b[tuple(idx)])
+        for a, b in zip(logits0, eng.logit_buffers):
+            assert np.array_equal(a, np.asarray(b))
+        eng.run_until_idle(300)
+    eng.submit(Request(uid=9, prompt=np.full(3, 9, np.int32),
+                       max_new_tokens=2))
+    with mesh:
+        eng.run_until_idle(300)
+    got = {r.uid: (list(r.generated), [float(m) for m in r.mi_trace])
+           for r in eng.finished}
+    assert got == want
+
+
+def test_warm_prefill_compiles_every_round_width(lm_setup):
+    """After ``build_engine``'s warm-up, rounds at every width of the
+    ladder (1, 2 and 4 rows on four slots) compile nothing."""
+    from repro.serving.engine import engine as engine_mod
+
+    cfg, params = lm_setup
+    engine_mod.clear_shared_pass_cache()
+    eng, mesh = _built_engine(cfg, params)
+    compiles = [0]
+
+    def listen(event, duration, **_):
+        if event.startswith("/jax/core/compile/") and \
+                "backend_compile" in event:
+            compiles[0] += 1
+
+    run = eng._batch_chunk_fn
+    per_width = {}
+
+    def counted(params, inputs, *rest):
+        before = compiles[0]
+        out = run(params, inputs, *rest)
+        w = inputs["tokens"].shape[0]
+        per_width[w] = per_width.get(w, 0) + compiles[0] - before
+        return out
+
+    eng._batch_chunk_fn = counted
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        _serve_four(eng, mesh)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    assert per_width == {1: 0, 2: 0, 4: 0}
+
+
 def test_scheduler_page_budget_blocks_head(lm_setup):
     """Page admission blocks the queue head rather than skipping it, so
     page pressure cannot invert priority order."""
